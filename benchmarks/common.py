@@ -16,6 +16,7 @@ import numpy as np
 from repro.configs.registry import serving_config
 from repro.core.pipeline import build_step_scorer
 from repro.core.scorer import init_scorer
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.init import init_params
 from repro.training.checkpoint import load_pytree, save_pytree
 from repro.training.trainer import TrainConfig, train_lm
@@ -86,4 +87,5 @@ def load_artifacts() -> Tuple[dict, dict, dict]:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     build_artifacts()

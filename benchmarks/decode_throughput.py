@@ -38,6 +38,7 @@ from repro.configs.registry import serving_config
 from repro.core.pruning import make_policy
 from repro.core.trace import TraceStatus
 from repro.data.tokenizer import get_tokenizer
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.init import init_params
 from repro.serving import Engine, EngineConfig, SamplingParams
 
@@ -157,6 +158,7 @@ def run(verbose: bool = False) -> dict:
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--out", default=os.path.join(
         os.path.dirname(__file__), "..", "BENCH_decode.json"))

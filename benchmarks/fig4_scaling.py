@@ -3,6 +3,7 @@ N in {1, 8, 16} for SC and STEP (paper uses {1, 16, 32, 64})."""
 from __future__ import annotations
 
 from benchmarks.common import load_artifacts
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving import EngineConfig, SamplingParams, evaluate_method, \
     make_problems
 
@@ -36,6 +37,7 @@ def run(verbose: bool = False):
 
 
 def main():
+    enable_compile_cache()
     rows = run()
     print("fig4_scaling: n, method, accuracy, avg_latency_s")
     for r in rows:

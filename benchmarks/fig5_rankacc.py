@@ -13,6 +13,7 @@ from repro.core.pipeline import sample_traces
 from repro.core.scorer import rank_accuracy, scorer_score
 from repro.data.arithmetic import gen_problem
 from repro.data.tokenizer import get_tokenizer
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import forward_full
 
 import jax.numpy as jnp
@@ -78,6 +79,7 @@ def run(verbose: bool = False):
 
 
 def main():
+    enable_compile_cache()
     rows = run()
     print("fig5_rankacc: prefix_frac, rankacc_scorer, rankacc_confidence")
     for r in rows:

@@ -14,6 +14,7 @@ import jax.numpy as jnp
 from repro.configs.registry import ASSIGNED_ARCHS, get_config, \
     serving_config
 from repro.core.scorer import SCORER_HIDDEN, init_scorer, scorer_score
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.init import count_params, init_params, padded_vocab
 
 # paper setting: t ~ 1e2 tokens per reasoning step (App. D); the synthetic
@@ -68,6 +69,7 @@ def run(verbose: bool = False):
 
 
 def main():
+    enable_compile_cache()
     rows = run()
     print("overhead: arch, kind, scorer_flops_ratio")
     for r in rows:

@@ -45,6 +45,7 @@ import jax.numpy as jnp
 
 from repro.kernels import ops as kops
 from repro.kernels import ref
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.layers import paged_attention_decode
 
 # Serving-shaped op config: big pool, short live prefix. Page size 64
@@ -165,6 +166,7 @@ def run(verbose: bool = False) -> dict:
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--out", default=os.path.join(
         os.path.dirname(__file__), "..", "BENCH_paged_kernel.json"))

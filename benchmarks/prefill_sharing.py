@@ -25,6 +25,7 @@ from repro.configs.registry import serving_config
 from repro.core.pruning import make_policy
 from repro.data.arithmetic import gen_problem, make_prompt
 from repro.data.tokenizer import get_tokenizer
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.init import init_params
 from repro.serving import Engine, EngineConfig, SamplingParams
 
@@ -83,6 +84,7 @@ def run(verbose: bool = False):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--out", default=os.path.join(
         os.path.dirname(__file__), "..", "BENCH_prefill_sharing.json"))

@@ -12,6 +12,8 @@ import argparse
 import time
 import traceback
 
+from repro.launch.compile_cache import enable_compile_cache
+
 BENCHES = [
     ("table1_main", "Table 1: acc/tokens/latency across methods"),
     ("table2_voting", "Table 2: voting strategies"),
@@ -25,6 +27,7 @@ BENCHES = [
 
 
 def main() -> int:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None,
                     help="comma-separated benchmark names")

@@ -54,6 +54,7 @@ from repro.core.pruning import make_policy
 from repro.core.trace import TraceStatus
 from repro.data.tokenizer import get_tokenizer
 from repro.data.arithmetic import make_prompt
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.init import init_params
 from repro.serving import (SLO, CacheStats, Engine, EngineConfig, FaultPlan,
                            Request, SamplingParams, TenantScheduler,
@@ -540,6 +541,7 @@ def run_faults(verbose: bool = False) -> dict:
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--multiturn", action="store_true",
                     help="run the prefix-cache conversation workload "

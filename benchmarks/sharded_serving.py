@@ -23,14 +23,14 @@ from __future__ import annotations
 
 import os
 
-# must happen before jax initializes: simulate 4 host devices unless the
-# caller already forced a device count
+# must happen before jax initializes: on a CPU host, simulate 4 devices
+# unless the caller already forced a device count (an accelerator host
+# uses its real devices)
 if "--xla_force_host_platform_device_count" not in os.environ.get(
         "XLA_FLAGS", ""):
     os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
                                + " --xla_force_host_platform_device_count=4"
                                ).strip()
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import argparse  # noqa: E402  (env must be set before jax imports)
 import dataclasses  # noqa: E402
@@ -44,6 +44,7 @@ from repro.configs.registry import serving_config  # noqa: E402
 from repro.core.pruning import make_policy  # noqa: E402
 from repro.core.trace import TraceStatus  # noqa: E402
 from repro.data.tokenizer import get_tokenizer  # noqa: E402
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 from repro.launch.mesh import make_host_mesh  # noqa: E402
 from repro.models.init import init_params  # noqa: E402
 from repro.serving import Engine, EngineConfig, SamplingParams  # noqa: E402
@@ -57,8 +58,8 @@ CAPACITY = 128
 DECODE_HORIZON = 4
 SEED = 1234
 # init seed chosen so the random-init model's greedy generations run to
-# the token cap under partitionable-threefry init (the flag is flipped
-# before init in run()); early-EOS seeds leave too few decode ticks
+# the token cap under partitionable-threefry init (JAX's default);
+# early-EOS seeds leave too few decode ticks
 PARAMS_SEED = 0
 
 
@@ -103,11 +104,6 @@ def run(verbose: bool = False) -> dict:
             f"{jax.device_count()}; run with XLA_FLAGS="
             f"--xla_force_host_platform_device_count=4")
     cfg = bench_config()
-    # both engines must sample the same threefry implementation; the
-    # mesh engine flips this anyway — flip it before the single-device
-    # baseline so engine build order can't matter (greedy today, but
-    # don't let a future temperature>0 variant diverge for RNG reasons)
-    jax.config.update("jax_threefry_partitionable", True)
     params = init_params(cfg, jax.random.PRNGKey(PARAMS_SEED))
     tok = get_tokenizer()
     ecfg = EngineConfig(
@@ -160,6 +156,7 @@ def run(verbose: bool = False) -> dict:
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--out", default=os.path.join(
         os.path.dirname(__file__), "..", "BENCH_sharded.json"))

@@ -4,6 +4,7 @@ main result, laptop scale)."""
 from __future__ import annotations
 
 from benchmarks.common import load_artifacts
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving import EngineConfig, SamplingParams, evaluate_method, \
     make_problems
 
@@ -43,6 +44,7 @@ def run(verbose: bool = False):
 
 
 def main():
+    enable_compile_cache()
     rows = run()
     print("table1_main: method, accuracy, avg_tokens, avg_latency_s, "
           "wait_s, pruned, preemptions")

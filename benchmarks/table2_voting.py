@@ -14,6 +14,7 @@ from repro.core.scorer import scorer_score
 from repro.core.voting import majority_vote, weighted_vote
 from repro.data.arithmetic import gen_problem
 from repro.data.tokenizer import get_tokenizer
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import forward_full
 
 import jax.numpy as jnp
@@ -70,6 +71,7 @@ def run(verbose: bool = False):
 
 
 def main():
+    enable_compile_cache()
     rows = run()
     print("table2_voting: voting, accuracy")
     for r in rows:

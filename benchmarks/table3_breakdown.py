@@ -6,6 +6,7 @@ trigger drives WAIT to exactly zero (no preemption queue ever forms)."""
 from __future__ import annotations
 
 from benchmarks.common import load_artifacts
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving import EngineConfig, SamplingParams, evaluate_method, \
     make_problems
 
@@ -40,6 +41,7 @@ def run(verbose: bool = False):
 
 
 def main():
+    enable_compile_cache()
     rows = run()
     print("table3_breakdown: method, wait_s, decode_s, prefill_s, "
           "preemptions")

@@ -29,6 +29,7 @@ from repro.core.scorer import rank_accuracy
 from repro.core.trace import TraceStatus
 from repro.data.arithmetic import make_prompt
 from repro.data.tokenizer import get_tokenizer
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import kv_quant
 from repro.serving import Engine, EngineConfig, SamplingParams, \
     evaluate_method, make_problems
@@ -88,9 +89,8 @@ def run_kv_quant(verbose: bool = False):
     problems = make_problems(KVQ_PROBLEMS, seed=67, n_steps=KVQ_N_STEPS)
     budget = KVQ_F32_BLOCKS * kv_quant.pool_block_bytes(cfg, "f32")
 
-    dtypes = ["f32", "bf16", "int8"]
-    if kv_quant.fp8_dtype() is not None:
-        dtypes.append("fp8")  # informational; gates cover int8 only
+    dtypes = ["f32", "bf16", "int8", "fp8"]  # fp8 informational: gates
+    # cover int8 only
 
     def serve_leg(dt, nb):
         ecfg = EngineConfig(
@@ -197,6 +197,7 @@ def run_kv_quant(verbose: bool = False):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--kv-quant", action="store_true",
                     help="run the fixed-HBM kv_dtype sweep instead of "

@@ -34,9 +34,16 @@ def init_scorer(rng: jax.Array, d_model: int,
 
 
 def scorer_logits(params: dict, h: jax.Array) -> jax.Array:
-    """h [..., D] -> pre-sigmoid logits [...]."""
-    z = jax.nn.relu(h.astype(jnp.float32) @ params["w1"] + params["b1"])
-    return (z @ params["w2"] + params["b2"])[..., 0]
+    """h [..., D] -> pre-sigmoid logits [...].
+
+    f32 matmuls at full precision: a TPU's default would round their
+    inputs to bf16, and the fused ``kernels/step_score`` kernel, which
+    serves the same scores on a one-chip engine, does not.
+    """
+    hi = jax.lax.Precision.HIGHEST
+    z = jax.nn.relu(jnp.matmul(h.astype(jnp.float32), params["w1"],
+                               precision=hi) + params["b1"])
+    return (jnp.matmul(z, params["w2"], precision=hi) + params["b2"])[..., 0]
 
 
 def scorer_score(params: dict, h: jax.Array) -> jax.Array:

@@ -59,7 +59,6 @@ def paged_attention_sharded(mesh, q, k_pool, v_pool, block_tables,
     call is bit-identical to the unsharded kernel, no collectives.
     Quantized pools add ``k_scale``/``v_scale`` [NB, page, KVH], sharded
     with the pool's KV heads on "model"."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     pool = P(None, None, "model", None)
@@ -75,9 +74,9 @@ def paged_attention_sharded(mesh, q, k_pool, v_pool, block_tables,
         return paged_attention(q_, kp, vp, bt, lens, scale=scale,
                                k_scale=ks, v_scale=vs)
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh, in_specs=tuple(in_specs),
-        out_specs=P("data", "model", None), check_rep=False,
+        out_specs=P("data", "model", None), check_vma=False,
     )(*operands)
 
 
@@ -91,7 +90,6 @@ def paged_attention_prefill_sharded(mesh, q, k_pool, v_pool, block_tables,
     the batch-1 operands replicate over "data" and every data shard
     computes the same tile. Quantized pools add ``k_scale``/``v_scale``
     [NB, page, KVH] sharded with the KV heads on "model"."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     head = P(None, None, "model", None)
@@ -110,9 +108,9 @@ def paged_attention_prefill_sharded(mesh, q, k_pool, v_pool, block_tables,
                                        scale=scale, window=window,
                                        k_scale=ks, v_scale=vs)
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh, in_specs=tuple(in_specs),
-        out_specs=head, check_rep=False,
+        out_specs=head, check_vma=False,
     )(*operands)
 
 

@@ -20,27 +20,39 @@ TPU adaptation of vLLM's GPU PagedAttention:
   * the block table and per-sequence lengths are SCALAR-PREFETCHED
     (SMEM) so the kernel can compute data-dependent block indices before
     the body runs — the TPU-idiomatic replacement for pointer-chasing;
-  * K/V pools stay in HBM (``memory_space=ANY``); each grid step loads
-    one [page, hd] tile for one kv head via dynamic slicing;
-  * grid = (batch, kv_heads, num_pages [+ 1 own-chunk step]); the page
-    dimension is the sequential one carrying online-softmax state in
-    VMEM scratch. Pages holding no visible slots are skipped
-    (``pl.when``), so a chunk near the front of a long pool touches
-    only its live prefix — the dense path pays for every slot;
+  * K/V pools stay in HBM (``memory_space=ANY``) and reach VMEM only by
+    DMA: one grid step per sequence walks that sequence's live pages,
+    copying each WHOLE page ``[page, KVH, hd]`` (plus its scale rows on
+    a quantized pool) into a double buffer while the previous page is
+    being computed on. Whole pages, because a DMA of one head's
+    ``[page, hd]`` tile slices the pool's second-minor (KVH) dimension
+    below the chip's (8, 128) tiling, which the compiler refuses; each
+    page is therefore read from HBM once per sequence, not once per
+    (sequence, kv head);
+  * the KV heads are an unrolled (static) loop inside the page step:
+    a static head index into the VMEM page is what the compiler can
+    prove aligned, where the grid's dynamic head index is refused;
+  * only pages holding visible slots are visited (the page loop runs
+    over ``[first_live, last_live]``), so a chunk near the front of a
+    long pool touches only its live prefix — the dense path pays for
+    every slot;
   * GQA: all G = H // KVH query heads of one kv head are processed
     together, flattened with the chunk dim into a [C*G, hd] tile
     (C*G*hd columns feed the MXU at once).
 
 Numerics contract (pinned by tests against the dense path):
   * f32 accumulation throughout (scores, softmax, PV);
+  * quantized pages are dequantized inside the page loop (cast to f32,
+    times the slot's scale), the same math the dense fallback applies;
   * empty cache (``cache_len == 0`` and no visible own tokens) emits
     ZEROS via the ``safe_l`` guard — the convention the dense paths
     now share (a bare softmax over all -1e30 scores would average
     garbage KV instead).
 
-VMEM working set per step: page*hd (K) + page*hd (V) + C*G*page
-(scores) + C*G*hd (acc) floats — a few hundred KB at the serving tile
-sizes, far under the 16 MB budget.
+VMEM working set per grid step: 2 x 2 pages (K and V, double-buffered)
++ the [KVH, C*G, hd] query/output tiles + f32 softmax state (m, l, acc)
+for every kv head — about 6 MB for a 64-token chunk at 32/8 heads and
+hd = 128, under the 16 MB scoped budget.
 """
 from __future__ import annotations
 
@@ -52,33 +64,32 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax >= 0.5 renamed TPUCompilerParams -> CompilerParams; support both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
-
 NEG_INF = -1e30
 
 
 def _mq_paged_kernel(*refs, scale: float, page_size: int, num_pages: int,
                      groups: int, window: Optional[int], has_own: bool,
                      has_scales: bool):
-    """Shared body. ``refs`` layout (scalar prefetch first):
+    """Shared body, one grid step per sequence ``b``. ``refs`` layout
+    (scalar prefetch first, scratch last):
 
       decode : bt, lens, q, k_pool, v_pool, [k_scale, v_scale,]
-               o, m, l, acc
+               o, k_buf, v_buf, [ks_buf, vs_buf,] sem, m, l, acc
       prefill: bt, lens, nvalid, q, k_pool, v_pool,
-               [k_scale, v_scale,] own_k, own_v, o, m, l, acc
+               [k_scale, v_scale,] own_k, own_v,
+               o, k_buf, v_buf, [ks_buf, vs_buf,] sem, m, l, acc
 
     ``lens[b]`` = number of valid pooled slots. For prefill (no
     wraparound: slot == position) this doubles as the chunk's start
     position, so query c sits at absolute position ``lens[b] + c``.
 
     ``has_scales`` marks a quantized (int8/fp8) pool: ``k_scale``/
-    ``v_scale`` [NB, page, KVH] f32 live in HBM next to the pools
-    (``memory_space=ANY``) and each page tile is dequantized right
-    inside the online-softmax loop — cast to f32, multiply by its
-    per-(slot, kv-head) scale column — the identical math the dense
-    fallback applies to its gathered pages.
+    ``v_scale`` arrive as lane-dense [NB, 1, KVH * page] f32 rows in HBM
+    (``_mq_paged_call`` relayouts the pool's [NB, page, KVH]) and ride
+    the same page DMA; each page is dequantized right inside the
+    online-softmax loop — cast to f32, multiply by its per-(slot,
+    kv-head) scale column — the identical math the dense fallback
+    applies to its gathered pages.
     """
     refs = list(refs)
     bt_ref, lens_ref = refs[0], refs[1]
@@ -89,105 +100,117 @@ def _mq_paged_kernel(*refs, scale: float, page_size: int, num_pages: int,
         i += 1
     q_ref, k_pool_ref, v_pool_ref = refs[i:i + 3]
     i += 3
-    ks_ref = vs_ref = None
+    hbm = [k_pool_ref, v_pool_ref]
     if has_scales:
-        ks_ref, vs_ref = refs[i], refs[i + 1]
+        hbm += refs[i:i + 2]
         i += 2
     if has_own:
         own_k_ref, own_v_ref = refs[i], refs[i + 1]
         i += 2
-    o_ref, m_s, l_s, acc_s = refs[i:i + 4]
+    o_ref = refs[i]
+    bufs = refs[i + 1:i + 1 + len(hbm)]
+    sem, m_s, l_s, acc_s = refs[i + 1 + len(hbm):]
+    k_buf, v_buf = bufs[0], bufs[1]
+    kvh = q_ref.shape[1]
     b = pl.program_id(0)
-    h = pl.program_id(1)
-    p = pl.program_id(2)
 
-    @pl.when(p == 0)
-    def _init():
-        m_s[...] = jnp.full_like(m_s, NEG_INF)
-        l_s[...] = jnp.zeros_like(l_s)
-        acc_s[...] = jnp.zeros_like(acc_s)
+    m_s[...] = jnp.full_like(m_s, NEG_INF)
+    l_s[...] = jnp.zeros_like(l_s)
+    acc_s[...] = jnp.zeros_like(acc_s)
 
     cache_len = lens_ref[b]
 
-    def online_update(s, mask):
-        """Fold one masked [C*G, S_blk] score tile into the softmax state."""
+    def online_update(h, s, mask, v):
+        """Fold one masked [C*G, S_blk] score tile of kv head ``h`` and
+        its [S_blk, hd] values into that head's softmax state."""
         s = jnp.where(mask, s, NEG_INF)
-        m_prev = m_s[...]                      # [C*G, 1]
-        l_prev = l_s[...]
+        m_prev = m_s[h]                        # [C*G, 1]
+        l_prev = l_s[h]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         pexp = jnp.where(mask, jnp.exp(s - m_new), 0.0)
         alpha = jnp.exp(m_prev - m_new)
-        return m_new, alpha * l_prev + jnp.sum(pexp, axis=-1,
-                                               keepdims=True), pexp, alpha
-
-    # ---- pooled-prefix pages -------------------------------------------
-    page_start = p * page_size
-    live = page_start < cache_len
-    if has_own:
-        live &= p < num_pages
-        if window is not None:
-            # the loosest query (chunk-local c = 0, position cache_len)
-            # sees slots > cache_len - window; pages entirely left of
-            # that are dead for every query in the chunk
-            live &= page_start + page_size > cache_len - window
-
-    @pl.when(live)
-    def _pool_page():
-        block_id = bt_ref[b, p]
-        k = k_pool_ref[block_id, pl.ds(0, page_size), h, :]
-        v = v_pool_ref[block_id, pl.ds(0, page_size), h, :]
-        k = k.astype(jnp.float32)              # [page, hd]
-        v = v.astype(jnp.float32)
-        if has_scales:                         # in-loop dequantization
-            k = k * ks_ref[block_id, pl.ds(0, page_size), h][:, None]
-            v = v * vs_ref[block_id, pl.ds(0, page_size), h][:, None]
-        q = q_ref[0, 0].astype(jnp.float32)    # [C*G, hd]
-
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # [C*G, page]
-        slot = page_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        mask = slot < cache_len
-        if has_own:
-            c = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) // groups
-            mask &= c < nvalid_ref[b]          # padded queries emit zeros
-            if window is not None:
-                mask &= slot > (cache_len + c - window)
-        m_new, l_new, pexp, alpha = online_update(s, mask)
-        acc_s[...] = acc_s[...] * alpha + jax.lax.dot_general(
+        acc_s[h] = acc_s[h] * alpha + jax.lax.dot_general(
             pexp, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        m_s[...] = m_new
-        l_s[...] = l_new
+        m_s[h] = m_new
+        l_s[h] = alpha * l_prev + jnp.sum(pexp, axis=-1, keepdims=True)
 
-    # ---- the chunk's own exact KV (final grid step, prefill only) ------
-    if has_own:
-        @pl.when(p == num_pages)
-        def _own_chunk():
-            k = own_k_ref[0, 0].astype(jnp.float32)   # [C, hd]
-            v = own_v_ref[0, 0].astype(jnp.float32)
-            q = q_ref[0, 0].astype(jnp.float32)       # [C*G, hd]
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale  # [C*G, C]
-            c = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) // groups
-            j = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            nv = nvalid_ref[b]
-            mask = (j <= c) & (j < nv) & (c < nv)     # causal + no pad
+    def scores(h, k):
+        q = q_ref[0, h].astype(jnp.float32)    # [C*G, hd]
+        return jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
+
+    # ---- pooled-prefix pages: [lo, hi) are the pages with a visible slot
+    hi = jnp.minimum((cache_len + page_size - 1) // page_size, num_pages)
+    lo = 0
+    if has_own and window is not None:
+        # the loosest query (chunk-local c = 0, position cache_len) sees
+        # slots > cache_len - window; pages entirely left of that are
+        # dead for every query in the chunk
+        lo = jnp.maximum(cache_len - window, 0) // page_size
+
+    def page_copies(p, slot):
+        block_id = bt_ref[b, p]
+        return [pltpu.make_async_copy(src.at[block_id], buf.at[slot],
+                                      sem.at[slot])
+                for src, buf in zip(hbm, bufs)]
+
+    @pl.when(lo < hi)
+    def _first_fetch():
+        for cp in page_copies(lo, lo % 2):
+            cp.start()
+
+    def page_step(p, carry):
+        slot = p % 2
+
+        @pl.when(p + 1 < hi)
+        def _prefetch_next():
+            for cp in page_copies(p + 1, 1 - slot):
+                cp.start()
+
+        for cp in page_copies(p, slot):
+            cp.wait()
+
+        page_start = p * page_size
+        shape = (q_ref.shape[2], page_size)
+        slot_pos = page_start + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        mask = slot_pos < cache_len
+        if has_own:
+            c = jax.lax.broadcasted_iota(jnp.int32, shape, 0) // groups
+            mask &= c < nvalid_ref[b]          # padded queries emit zeros
             if window is not None:
-                mask &= j > (c - window)
-            m_new, l_new, pexp, alpha = online_update(s, mask)
-            acc_s[...] = acc_s[...] * alpha + jax.lax.dot_general(
-                pexp, v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            m_s[...] = m_new
-            l_s[...] = l_new
+                mask &= slot_pos > (cache_len + c - window)
+        for h in range(kvh):
+            k = k_buf[slot, :, h, :].astype(jnp.float32)   # [page, hd]
+            v = v_buf[slot, :, h, :].astype(jnp.float32)
+            if has_scales:                     # in-loop dequantization
+                cols = slice(h * page_size, (h + 1) * page_size)
+                k = k * bufs[2][slot][:, cols].T           # [page, 1]
+                v = v * bufs[3][slot][:, cols].T
+            online_update(h, scores(h, k), mask, v)
+        return carry
 
-    @pl.when(p == num_pages + int(has_own) - 1)
-    def _finalize():
-        l = l_s[...]
-        safe_l = jnp.where(l == 0.0, 1.0, l)   # fully-masked rows -> zeros
-        o_ref[0, 0] = (acc_s[...] / safe_l).astype(o_ref.dtype)
+    jax.lax.fori_loop(lo, hi, page_step, 0)
+
+    # ---- the chunk's own exact KV (prefill only) -----------------------
+    if has_own:
+        C = own_k_ref.shape[2]
+        shape = (q_ref.shape[2], C)
+        c = jax.lax.broadcasted_iota(jnp.int32, shape, 0) // groups
+        j = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        nv = nvalid_ref[b]
+        mask = (j <= c) & (j < nv) & (c < nv)  # causal + no pad
+        if window is not None:
+            mask &= j > (c - window)
+        for h in range(kvh):
+            k = own_k_ref[0, h].astype(jnp.float32)   # [C, hd]
+            v = own_v_ref[0, h].astype(jnp.float32)
+            online_update(h, scores(h, k), mask, v)
+
+    l = l_s[...]
+    safe_l = jnp.where(l == 0.0, 1.0, l)       # fully-masked rows -> zeros
+    o_ref[0] = (acc_s[...] / safe_l).astype(o_ref.dtype)
 
 
 def _mq_paged_call(qf, k_pool, v_pool, block_tables, lens, nvalid,
@@ -209,50 +232,42 @@ def _mq_paged_call(qf, k_pool, v_pool, block_tables, lens, nvalid,
         groups=groups, window=window, has_own=has_own,
         has_scales=has_scales)
 
-    in_specs = [
-        pl.BlockSpec((1, 1, CG, hd), lambda b, h, p, *_: (b, h, 0, 0)),
-        pl.BlockSpec(memory_space=pltpu.ANY),
-        pl.BlockSpec(memory_space=pltpu.ANY),
-    ]
-    operands = [block_tables, lens]
-    if has_own:
-        operands.append(nvalid)
+    def per_seq(shape):
+        return pl.BlockSpec((1, *shape), lambda b, *_: (b, 0, 0, 0))
+
+    hbm = [k_pool, v_pool]
+    if has_scales:
+        # [NB, page, KVH] -> [NB, 1, KVH * page]: one lane-dense row per
+        # block. A KVH-wide minor dim is padded to 128 lanes in HBM, and
+        # a DMA cannot slice a block out of that padded layout.
+        hbm += [s.transpose(0, 2, 1).reshape(s.shape[0], 1, -1)
+                for s in (k_scale, v_scale)]
+    operands = [block_tables, lens] + ([nvalid] if has_own else [])
     num_prefetch = len(operands)
-    if has_scales:
-        # per-slot dequant scales [NB, page, KVH]: block-addressed like
-        # the pools, so they stay in HBM and each grid step dynamically
-        # slices its page's scale column alongside the page tile
-        in_specs += [pl.BlockSpec(memory_space=pltpu.ANY),
-                     pl.BlockSpec(memory_space=pltpu.ANY)]
-    if has_own:
-        in_specs += [
-            pl.BlockSpec((1, 1, C, hd), lambda b, h, p, *_: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, C, hd), lambda b, h, p, *_: (b, h, 0, 0)),
-        ]
-    operands += [qf, k_pool, v_pool]
-    if has_scales:
-        operands += [k_scale, v_scale]
+    operands += [qf] + hbm
+    in_specs = [per_seq((KVH, CG, hd))]
+    in_specs += [pl.BlockSpec(memory_space=pl.ANY)] * len(hbm)
     if has_own:
         operands += [own_k, own_v]
+        in_specs += [per_seq((KVH, C, hd))] * 2
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=num_prefetch,
-        grid=(B, KVH, bp + int(has_own)),
+        grid=(B,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, CG, hd),
-                               lambda b, h, p, *_: (b, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((CG, 1), jnp.float32),
-            pltpu.VMEM((CG, 1), jnp.float32),
-            pltpu.VMEM((CG, hd), jnp.float32),
-        ],
+        out_specs=per_seq((KVH, CG, hd)),
+        scratch_shapes=[pltpu.VMEM((2, *x.shape[1:]), x.dtype) for x in hbm]
+        + [pltpu.SemaphoreType.DMA((2,)),
+           pltpu.VMEM((KVH, CG, 1), jnp.float32),
+           pltpu.VMEM((KVH, CG, 1), jnp.float32),
+           pltpu.VMEM((KVH, CG, hd), jnp.float32)],
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KVH, CG, hd), qf.dtype),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
         interpret=interpret,
     )(*operands)
 

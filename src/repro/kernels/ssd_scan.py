@@ -32,10 +32,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax >= 0.5 renamed TPUCompilerParams -> CompilerParams; support both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
-
 
 def _ssd_kernel(x_ref, dt_ref, A_ref, B_ref, C_ref, y_ref, h_ref, h_scratch,
                 *, chunk: int, heads: int, num_chunks: int):
@@ -153,7 +149,7 @@ def ssd_scan(x: jax.Array, dt: jax.Array, A: jax.Array,
             jax.ShapeDtypeStruct((B * HG, G, P, N), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((G, P, N), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(xg, dtg, Ag, Bg, Cg)

@@ -20,10 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax >= 0.5 renamed TPUCompilerParams -> CompilerParams; support both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
-
 DEFAULT_BLK_B = 128
 
 
@@ -64,7 +60,7 @@ def step_score(hidden: jax.Array, w1: jax.Array, b1: jax.Array,
         ],
         out_specs=pl.BlockSpec((blk_b, 1), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((h.shape[0], 1), jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(h, w1, b1, w2, b2)

@@ -12,19 +12,23 @@ N-device CPU mesh, which is what the multi-device CI lane and the
 sharded-serving tests run on.
 
 Defined as functions (never module-level constants) so importing this
-module touches no jax device state.
+module touches no jax device state. Axes are ``Auto``: the steps place
+their data with sharding constraints that the compiler propagates, which
+JAX's default ``Explicit`` axes would refuse (e.g. the embedding gather
+from a vocab-sharded table).
 """
 from __future__ import annotations
 
 from typing import Optional, Tuple
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def resolve_host_mesh_shape(data: Optional[int] = None,
@@ -76,7 +80,8 @@ def make_host_mesh(data: Optional[int] = None, model: Optional[int] = None):
     ``resolve_host_mesh_shape``).
     """
     data, model = resolve_host_mesh_shape(data, model)
-    return jax.make_mesh((data, model), ("data", "model"))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def data_axes(mesh) -> tuple:

@@ -23,6 +23,7 @@ from __future__ import annotations
 import argparse
 from typing import Optional
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving import (SLO, EngineConfig, SamplingParams,
                            TenantScheduler, evaluate_method,
                            evaluate_method_batched, make_problems,
@@ -48,6 +49,7 @@ def parse_mesh(spec: Optional[str]):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--method", default="step",
                     choices=["cot", "sc", "slimsc", "deepconf", "step"])

@@ -13,7 +13,7 @@ kv_heads, head_dim]``) can be stored in four dtypes, selected by
   ``v_scale`` of shape ``[layers, blocks, block_size, kv_heads]``.
   Dequantization is ``q.astype(f32) * scale``; the scale is
   ``absmax / qmax`` over the token's ``head_dim`` vector. ``fp8`` uses
-  ``float8_e4m3fn`` and is gated on the installed jax exposing it.
+  ``float8_e4m3fn``.
 
 The scale granularity is per SLOT, not per page, and that choice is
 load-bearing: each cached token quantizes independently from its own
@@ -50,11 +50,6 @@ _QMAX_INT8 = 127.0
 _QMAX_FP8 = 448.0
 
 
-def fp8_dtype():
-    """The fp8 storage dtype, or ``None`` when this jax lacks float8."""
-    return getattr(jnp, "float8_e4m3fn", None)
-
-
 def kv_pool_dtype(kv_dtype: str):
     """Map a ``kv_dtype`` setting to the pool storage jnp dtype."""
     if kv_dtype == "f32":
@@ -64,11 +59,7 @@ def kv_pool_dtype(kv_dtype: str):
     if kv_dtype == "int8":
         return jnp.int8
     if kv_dtype == "fp8":
-        dt = fp8_dtype()
-        if dt is None:
-            raise NotImplementedError(
-                "kv_dtype='fp8' needs a jax build exposing float8_e4m3fn")
-        return dt
+        return jnp.float8_e4m3fn
     raise ValueError(
         f"unknown kv_dtype {kv_dtype!r}; expected one of {KV_DTYPES}")
 
@@ -141,9 +132,6 @@ def resolve_kv_dtype(setting: str, cfg: ModelConfig,
     if setting not in KV_DTYPES:
         raise ValueError(
             f"unknown kv_dtype {setting!r}; expected one of {KV_DTYPES}")
-    if setting == "fp8" and fp8_dtype() is None:
-        raise NotImplementedError(
-            "kv_dtype='fp8' needs a jax build exposing float8_e4m3fn")
     if is_quantized(setting) and not chunk_supported:
         raise NotImplementedError(
             f"kv_dtype={setting!r} is only supported for dense GQA "

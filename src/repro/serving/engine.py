@@ -111,6 +111,23 @@ from repro.serving.scheduler import (SLO, ReqState, SchedulerCore,
                                      SchedulingPolicy, SharedPrefix,
                                      default_scheduler)
 
+
+def _step_jit_kw() -> dict:
+    """``jax.jit`` options shared by every engine step.
+
+    On a TPU the steps round bf16 values where the program says so. By
+    default XLA may keep a fused bf16 intermediate in f32 ("excess
+    precision"), and it fuses a one-chip program differently from a mesh
+    program whose all-gathers materialize bf16 activations: the two then
+    round differently, and a mesh engine's greedy tokens leave the
+    one-chip engine's at the first near-tie. On the CPU backend the same
+    option moves the two programs apart instead, so it is a TPU setting.
+    """
+    if jax.default_backend() != "tpu":
+        return {}
+    return {"compiler_options": {"xla_allow_excess_precision": False}}
+
+
 # Back-compat aliases: these lived here before the scheduler split.
 _SharedPrefix = SharedPrefix
 _ReqState = ReqState
@@ -516,18 +533,6 @@ class Engine:
             raise NotImplementedError(
                 "mesh serving's exactness layout does not cover "
                 "MLA/MoE yet; run these archs on a single device")
-        # Non-partitionable threefry (the jax<0.5 default) generates
-        # DIFFERENT random bits once the logits array is sharded, so
-        # temperature sampling on the mesh would silently diverge from
-        # the single-device engine. The partitionable implementation is
-        # sharding-invariant by construction. NOTE: this is a
-        # process-global flag — engines (and any other sampling code)
-        # created after this point consume partitionable key streams,
-        # which is exactly what makes a later single-device engine
-        # comparable to this one (tests pin mesh-vs-single token
-        # identity under it), but it does mean constructing a mesh
-        # engine changes fixed-seed streams for the rest of the process.
-        jax.config.update("jax_threefry_partitionable", True)
         shapes = jax.tree.map(
             lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), self.params)
         pspecs = serving_param_specs(self.cfg, mesh, shapes)
@@ -550,6 +555,7 @@ class Engine:
         has_scorer = self.scorer_params is not None
         sp = ecfg.sampling
         ss = self._ss  # NamedSharding bundle (None on a 1-device engine)
+        step_kw = _step_jit_kw()
 
         V = cfg.vocab_size  # mask vocab padding out of the sampler
         eos_id = self.tok.eos_id
@@ -592,7 +598,7 @@ class Engine:
             per-request sampling path); the scalar variant bakes the
             engine-global ``SamplingParams`` into the graph and is the
             only one built for batches with no overrides."""
-            jit_kw = {}
+            jit_kw = dict(step_kw)
             if ss is not None:
                 # pin the round-trip layouts: per-lane [B, K] bursts and
                 # next-tick state stay data-sharded, pools keep the
@@ -660,7 +666,7 @@ class Engine:
         pf_kv = None if ss is None else self._prefill_kv_specs
         pf_act = None if ss is None else ss["prefill_act"]
 
-        @jax.jit
+        @partial(jax.jit, **step_kw)
         def prefill(params, tokens):
             out = forward_full(params, cfg, tokens, return_kv=True,
                                kv_specs=pf_kv, act_spec=pf_act,
@@ -682,7 +688,7 @@ class Engine:
         pool_keys = ("kv_pool",) if cfg.use_mla else ("k_pool", "v_pool")
         if kv_quant.is_quantized(ecfg.kv_dtype):
             pool_keys += ("k_scale", "v_scale")
-        wkv_kw = {}
+        wkv_kw = dict(step_kw)
         if ss is not None:
             wkv_kw["out_shardings"] = {
                 **{k: ss["pools"][k] for k in pool_keys},
@@ -696,7 +702,7 @@ class Engine:
         self._write_kv = write_kv
 
         if self._chunk_supported:
-            cp_kw = {}
+            cp_kw = dict(step_kw)
             if ss is not None:
                 # chunk jobs run one prompt at a time (batch 1): the
                 # logits can't batch-shard, but the pools must come out
@@ -727,7 +733,9 @@ class Engine:
 
         # COW block copy: pool[:, dst] = pool[:, src], one jitted instance
         # for all block pairs (src/dst are traced scalars).
-        cb_kw = {} if ss is None else {"out_shardings": ss["pools"]}
+        cb_kw = dict(step_kw)
+        if ss is not None:
+            cb_kw["out_shardings"] = ss["pools"]
         self._copy_block = jax.jit(partial(copy_kv_block, cfg),
                                    donate_argnums=(0,), **cb_kw)
 

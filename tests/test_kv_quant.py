@@ -57,9 +57,7 @@ def test_quantize_roundtrip_bounded(x, use_int8):
     """Per-element round-trip error stays under the scale-derived bound:
     ~scale/2 (+ bf16-scale-grid slack) for int8, ~2^-4 relative for
     fp8's 3-bit mantissa. Zero vectors stay exactly zero at scale 1."""
-    if not use_int8 and kv_quant.fp8_dtype() is None:
-        return  # this jax lacks float8; int8 half still runs
-    qdtype = jnp.int8 if use_int8 else kv_quant.fp8_dtype()
+    qdtype = jnp.int8 if use_int8 else jnp.float8_e4m3fn
     q, scale = kv_quant.quantize_pages(jnp.asarray(x), qdtype)
     rt = np.asarray(kv_quant.dequantize_pages(q, scale))
     absmax = np.max(np.abs(x), axis=-1, keepdims=True)
@@ -113,11 +111,7 @@ def test_resolve_kv_dtype_gating():
         kv_quant.resolve_kv_dtype("int8", cfg, False)
     with pytest.raises(ValueError, match="kv_dtype"):
         kv_quant.resolve_kv_dtype("int4", cfg, True)
-    if kv_quant.fp8_dtype() is None:
-        with pytest.raises(NotImplementedError, match="float8"):
-            kv_quant.resolve_kv_dtype("fp8", cfg, True)
-    else:
-        assert kv_quant.resolve_kv_dtype("fp8", cfg, True) == "fp8"
+    assert kv_quant.resolve_kv_dtype("fp8", cfg, True) == "fp8"
 
 
 def test_pool_block_bytes_ordering():
@@ -263,11 +257,7 @@ def test_engine_bf16_f32_identical(setup):
     assert runs["bf16"] == runs["f32"]
 
 
-@pytest.mark.parametrize("kv_dtype", [
-    "int8",
-    pytest.param("fp8", marks=pytest.mark.skipif(
-        kv_quant.fp8_dtype() is None, reason="no float8 in this jax")),
-])
+@pytest.mark.parametrize("kv_dtype", ["int8", "fp8"])
 def test_engine_quantized_bounded_drift(setup, kv_dtype):
     """Quantized pools: the engine still serves end-to-end (greedy decode,
     chunked prefill, scorer, pruning bookkeeping) and its step scores

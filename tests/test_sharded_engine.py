@@ -15,8 +15,8 @@ docs/ENGINE.md "Sharded serving"):
   * ``serving_param_specs``: only column-parallel weights shard over
     "model", so no contraction ever crosses a shard boundary — every
     collective is an all-gather, never a float reduction;
-  * partitionable threefry (flipped on by mesh engines), whose random
-    bits are invariant to how the sampled-over array is sharded.
+  * partitionable threefry (JAX's default), whose random bits are
+    invariant to how the sampled-over array is sharded.
 """
 import dataclasses
 
@@ -75,14 +75,6 @@ def test_make_host_mesh_matches_device_count():
 # serving sharding rules (AbstractMesh / single-device)
 # ---------------------------------------------------------------------------
 
-def _abstract_mesh(sizes, names):
-    import inspect
-    params = inspect.signature(AbstractMesh.__init__).parameters
-    if "shape_tuple" in params:
-        return AbstractMesh(tuple(zip(names, sizes)))
-    return AbstractMesh(tuple(sizes), tuple(names))
-
-
 def _param_shapes(cfg):
     return jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0)))
 
@@ -91,7 +83,7 @@ def test_serving_param_specs_exactness_layout():
     """Column-parallel weights shard over model; everything touched by a
     contraction or a norm reduction stays replicated."""
     cfg = serving_config()
-    mesh = _abstract_mesh((2, 2), ("data", "model"))
+    mesh = AbstractMesh((2, 2), ("data", "model"))
     specs = shd.serving_param_specs(cfg, mesh, _param_shapes(cfg))
     lyr = specs["layers"]
     assert lyr["attn"]["wq"][-1] == "model"
@@ -109,12 +101,12 @@ def test_serving_param_specs_exactness_layout():
 
 def test_serving_cache_specs_paged_pool_layout():
     cfg = serving_config()  # num_kv_heads=2: divides model=2
-    mesh = _abstract_mesh((2, 2), ("data", "model"))
+    mesh = AbstractMesh((2, 2), ("data", "model"))
     specs = shd.serving_cache_specs(cfg, mesh)
     assert specs["k_pool"] == P(None, None, None, "model", None)
     assert specs["v_pool"] == P(None, None, None, "model", None)
     # heads that don't divide the model axis: replicate, never shard hd
-    mesh16 = _abstract_mesh((2, 16), ("data", "model"))
+    mesh16 = AbstractMesh((2, 16), ("data", "model"))
     specs = shd.serving_cache_specs(cfg, mesh16)
     assert specs["k_pool"] == P(None, None, None, None, None)
 
@@ -139,10 +131,6 @@ _STATE: dict = {}
 
 def _setup():
     if "cfg" not in _STATE:
-        # both engines of every comparison must sample from the same
-        # threefry implementation; mesh engines flip this flag anyway,
-        # flip it eagerly so engine build order can't matter
-        jax.config.update("jax_threefry_partitionable", True)
         cfg = serving_config()
         _STATE["cfg"] = cfg
         _STATE["params"] = init_params(cfg, jax.random.PRNGKey(0))

@@ -12,22 +12,12 @@ from repro.launch import shardings as shd
 from repro.models.init import init_params
 
 
-def _abstract_mesh(sizes, names):
-    """jax <= 0.4.x takes ((name, size), ...); jax >= 0.5 takes
-    (sizes, names). Build whichever the installed jax expects."""
-    import inspect
-    params = inspect.signature(AbstractMesh.__init__).parameters
-    if "shape_tuple" in params:
-        return AbstractMesh(tuple(zip(names, sizes)))
-    return AbstractMesh(tuple(sizes), tuple(names))
-
-
 def mesh1():
-    return _abstract_mesh((16, 16), ("data", "model"))
+    return AbstractMesh((16, 16), ("data", "model"))
 
 
 def mesh2():
-    return _abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+    return AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def _shapes(arch):
