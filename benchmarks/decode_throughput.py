@@ -98,7 +98,7 @@ def run(verbose: bool = False) -> dict:
         wall = float("inf")
         for _ in range(5):
             requests = _requests(tok)
-            fallbacks_before = engine.horizon_fallbacks
+            fallbacks_before = engine.counters.horizon_fallbacks
             jax.block_until_ready(params)  # nothing in flight before t0
             t0 = time.perf_counter()
             results = engine.serve_batch(requests)
@@ -124,7 +124,8 @@ def run(verbose: bool = False) -> dict:
             "tok_per_s": tokens / wall,
             # per-replay count (the schedule is deterministic, so every
             # replay falls back identically)
-            "horizon_fallbacks": engine.horizon_fallbacks - fallbacks_before,
+            "horizon_fallbacks": (engine.counters.horizon_fallbacks
+                                  - fallbacks_before),
         }
         if verbose:
             print(f"decode_horizon={K}: {tokens} tokens in {wall:.2f}s "

@@ -74,31 +74,21 @@ def make_prompts(n, seed):
                        add_bos=True)[:PROMPT_LEN] for i in range(n)]
 
 
-class CompileCounter:
-    """Counts backend compilations and persistent-cache hits/misses
-    through JAX's monitoring events (one per process: listeners cannot
-    be removed)."""
+def count_cache_events() -> dict:
+    """Persistent compile-cache hits and misses of this process, through
+    JAX's monitoring events; the compiles a serve waits for are the
+    engine's own ``counters``."""
+    import jax.monitoring as mon
+    seen = {"cache_hits": 0, "cache_misses": 0}
 
-    def __init__(self):
-        import jax.monitoring as mon
-        self.compiles = self.hits = self.misses = 0
+    def on_event(name, **_):
+        if name == "/jax/compilation_cache/cache_hits":
+            seen["cache_hits"] += 1
+        elif name == "/jax/compilation_cache/cache_misses":
+            seen["cache_misses"] += 1
 
-        def on_event(name, **_):
-            if name == "/jax/compilation_cache/cache_hits":
-                self.hits += 1
-            elif name == "/jax/compilation_cache/cache_misses":
-                self.misses += 1
-
-        def on_duration(name, _secs, **_):
-            if name == "/jax/core/compile/backend_compile_duration":
-                self.compiles += 1
-
-        mon.register_event_listener(on_event)
-        mon.register_event_duration_secs_listener(on_duration)
-
-    def line(self):
-        return (f"compilations={self.compiles} cache_hits={self.hits} "
-                f"cache_misses={self.misses}")
+    mon.register_event_listener(on_event)
+    return seen
 
 
 def check_close(name, got, want):
@@ -219,8 +209,8 @@ def peak_bytes():
     return (jax.devices()[0].memory_stats() or {}).get("peak_bytes_in_use")
 
 
-def run_phase(name, eng, cfg, requests, want_new, counter):
-    compiles0 = counter.compiles
+def run_phase(name, eng, cfg, requests, want_new):
+    compiles0, compile_s0 = eng.counters.compiles, eng.counters.compile_s
     t0 = time.perf_counter()
     results = eng.serve_batch(requests)
     wall = time.perf_counter() - t0
@@ -242,7 +232,8 @@ def run_phase(name, eng, cfg, requests, want_new, counter):
           f"num_preemptions={sum(r.num_preemptions for r in results)} "
           f"prefix_hit_rate={cached / prompt!r} wall_s={wall!r} "
           f"tokens_per_s={tokens / wall!r} "
-          f"compilations={counter.compiles - compiles0} "
+          f"compilations={eng.counters.compiles - compiles0} "
+          f"compile_s={eng.counters.compile_s - compile_s0!r} "
           f"degraded_to_dense={eng.fault_stats.degraded_to_dense} "
           f"peak_blocks={max(r.peak_blocks_used for r in results)} "
           f"free_blocks={eng.block_mgr.free_blocks} "
@@ -252,7 +243,7 @@ def run_phase(name, eng, cfg, requests, want_new, counter):
     return results
 
 
-def one_chip(cfg, params, scorer, seed, counter):
+def one_chip(cfg, params, scorer, seed):
     from repro.core.pruning import make_policy
     from repro.serving import Request
 
@@ -265,25 +256,23 @@ def one_chip(cfg, params, scorer, seed, counter):
                         n_traces=N_TRACES, policy=make_policy(policy), **kw)
                 for i, p in enumerate(ids)]
 
-    run_phase("a", eng, cfg, reqs(prompts[0:2], 0, "sc"), MAX_NEW, counter)
+    run_phase("a", eng, cfg, reqs(prompts[0:2], 0, "sc"), MAX_NEW)
     res = run_phase("b", eng, cfg,
                     reqs(prompts[2:4], 10, "step",
-                         max_new_tokens=PRESSURE_NEW), PRESSURE_NEW,
-                    counter)
+                         max_new_tokens=PRESSURE_NEW), PRESSURE_NEW)
     if sum(r.num_pruned for r in res) == 0:
         raise AssertionError("phase b: STEP never pruned")
     if sum(r.num_preemptions for r in res):
         raise AssertionError("phase b: STEP preempted a trace")
-    run_phase("c_cold", eng, cfg, reqs(prompts[4:5], 20, "step"), MAX_NEW,
-              counter)
+    run_phase("c_cold", eng, cfg, reqs(prompts[4:5], 20, "step"), MAX_NEW)
     res = run_phase("c_warm", eng, cfg, reqs(prompts[4:5], 21, "step"),
-                    MAX_NEW, counter)
+                    MAX_NEW)
     if res[0].metrics.cached_tokens == 0:
         raise AssertionError("phase c: the repeated prompt missed the "
                              "prefix cache")
 
 
-def four_chips(cfg, params, scorer, seed, counter):
+def four_chips(cfg, params, scorer, seed):
     """Greedy requests through a one-chip engine and a (data=2, model=2)
     mesh engine; reports the first diverging token, if any."""
     from repro.core.pruning import make_policy
@@ -300,7 +289,7 @@ def four_chips(cfg, params, scorer, seed, counter):
         reqs = [Request(request_id=i, prompt_tokens=p, n_traces=4,
                         policy=make_policy("step"))
                 for i, p in enumerate(prompts)]
-        res = run_phase(name, eng, cfg, reqs, MAX_NEW, counter)
+        res = run_phase(name, eng, cfg, reqs, MAX_NEW)
         outputs[name] = [(r.request_id, t.trace_id, t.output_tokens,
                           t.token_confidences, t.step_scores)
                          for r in res for t in r.traces]
@@ -346,7 +335,7 @@ def main(argv=None) -> int:
         return 1
 
     print(f"compile_cache_dir={enable_compile_cache()}")
-    counter = CompileCounter()
+    cache_events = count_cache_events()
     print(f"platform={devices[0].platform} "
           f"device_kind={devices[0].device_kind} count={len(devices)}")
 
@@ -364,10 +353,10 @@ def main(argv=None) -> int:
           f"init_s={time.perf_counter() - t0!r}")
 
     if args.four_chips:
-        four_chips(cfg, params, scorer, args.seed, counter)
+        four_chips(cfg, params, scorer, args.seed)
     else:
-        one_chip(cfg, params, scorer, args.seed, counter)
-    print(counter.line())
+        one_chip(cfg, params, scorer, args.seed)
+    print(" ".join(f"{k}={v}" for k, v in cache_events.items()))
     print(json.dumps({"ok": True, "device": {
         "platform": devices[0].platform, "kind": devices[0].device_kind,
         "count": len(devices)}}))

@@ -7,7 +7,8 @@ from repro.serving.faults import (DeviceStepFault, FatalFaultError,
                                   FaultPlan, FaultSpec, FaultStats,
                                   RecoveryConfig)
 from repro.serving.kv_manager import BlockManager, Reservation
-from repro.serving.metrics import (RequestMetrics, percentiles, summarize,
+from repro.serving.metrics import (RequestMetrics, ServeCounters,
+                                   percentiles, span, summarize,
                                    summarize_by_tenant)
 from repro.serving.prefix_cache import CacheStats, PrefixCache
 from repro.serving.queue import RequestQueue
@@ -29,7 +30,8 @@ __all__ = [
     "make_problems", "poisson_arrivals",
     "BlockManager", "Reservation", "RequestQueue",
     "PrefixCache", "CacheStats",
-    "RequestMetrics", "percentiles", "summarize", "summarize_by_tenant",
+    "RequestMetrics", "ServeCounters", "span", "percentiles", "summarize",
+    "summarize_by_tenant",
     "SamplingParams", "sample_tokens", "sample_tokens_lanes",
     "SLO", "SchedulerCore", "SchedulingPolicy", "FIFOPolicy",
     "TenantScheduler", "DeficitRoundRobin", "TokenBudget",

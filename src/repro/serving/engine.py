@@ -102,7 +102,7 @@ from repro.models.model import (copy_kv_block, forward_full,
                                 write_prefill_kv)
 from repro.serving.faults import (FaultPlan, FaultStats, RecoveryConfig)
 from repro.serving.kv_manager import BlockManager
-from repro.serving.metrics import RequestMetrics
+from repro.serving.metrics import RequestMetrics, ServeCounters, counting
 from repro.serving.prefix_cache import PrefixCache
 from repro.serving.sampling import (SamplingParams, sample_logits,
                                     sample_logits_lanes, sample_tokens,
@@ -477,9 +477,9 @@ class Engine:
         # unsupported explicit-True combinations — never wrong tokens)
         self.use_kernel = resolve_use_kernel(ecfg.use_kernel, cfg, mesh)
         assert ecfg.decode_horizon >= 1, "decode_horizon must be >= 1"
-        # ticks where admission pressure forced the horizon down to 1
-        # (observable for tests/benchmarks)
-        self.horizon_fallbacks = 0
+        # serve-loop counters (lanes, memory-full outcomes, work,
+        # compiles), cumulative over the engine's life
+        self.counters = ServeCounters()
         # fault tolerance: the injection plan (re-armed per serve so the
         # same perturbation replays), the recovery policy knobs, and the
         # cumulative ledger of injections/recoveries
@@ -494,8 +494,6 @@ class Engine:
         # request ids flagged by Engine.cancel, consumed by the
         # scheduler core's cancellation sweep each pump iteration
         self._cancel_requests: set = set()
-        # tail of the last serve_batch's scheduler event stream
-        self.last_event_log: list = []
         self._ss = None  # serving step shardings (mesh engines only)
         if mesh is not None:
             self._place_on_mesh()
@@ -988,19 +986,16 @@ class Engine:
 
         if self.fault_plan is not None:
             self.fault_plan.reset()  # replay the identical plan per serve
-        core = SchedulerCore(self, states, t_start, on_complete,
-                             sched=self.scheduler)
-        try:
-            peak_blocks = core.run()
-        except BaseException:
-            # mid-serve crash: drain everything the run still held so
-            # the pool is clean and the engine reusable, then re-raise
-            core.emergency_drain()
-            self.last_event_log = list(core.event_log)
-            raise
-        # tail of the event stream (bounded deque), for observability
-        # and the event-ordering tests
-        self.last_event_log = list(core.event_log)
+        with counting(self.counters):
+            core = SchedulerCore(self, states, t_start, on_complete,
+                                 sched=self.scheduler)
+            try:
+                peak_blocks = core.run()
+            except BaseException:
+                # mid-serve crash: drain everything the run still held
+                # so the pool is clean and the engine reusable, re-raise
+                core.emergency_drain()
+                raise
 
         t_end = time.perf_counter()
         results = []

@@ -18,13 +18,26 @@ serving system reports:
 
 ``summarize`` folds a set of ``RequestMetrics`` into the percentile
 table the load benchmark writes to ``BENCH_serving.json``.
+
+The serve loop's own measurement lives here too:
+
+  * ``span(name, **stats)`` — a region of the scheduler (``sched.*``),
+    written into the JAX profiler's trace when one is active, so that it
+    shares the clock of the device's ops; about a microsecond otherwise.
+  * ``ServeCounters`` — cumulative counters over an engine's life
+    (``Engine.counters``): decode dispatches and their empty lanes by
+    cause, memory-full outcomes, prefill and decode work, and the
+    compiles the serve loop waited for, by the span they fell in.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
-from typing import Dict, Optional, Sequence
+from typing import Dict, Iterator, List, Optional, Sequence
 
+import jax.monitoring
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 
 @dataclasses.dataclass
@@ -200,3 +213,135 @@ def _mean(xs: Sequence[float]) -> Optional[float]:
     """Mean, or ``None`` for an empty input (same NaN-avoidance
     rationale as ``percentiles``)."""
     return sum(xs) / len(xs) if xs else None
+
+
+# ---------------------------------------------------------------------------
+# scheduler spans and serve counters
+# ---------------------------------------------------------------------------
+
+OUTSIDE = "(outside)"     # the phase of a compile outside every span
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+_open_spans: List[str] = []   # names of the open spans, innermost last
+_counting: Optional["ServeCounters"] = None   # the serve loop running now
+_listening = False
+
+
+class _Span:
+    __slots__ = ("name", "_annotation")
+
+    def __init__(self, name: str, stats: dict):
+        self.name = name
+        self._annotation = TraceAnnotation(name, **stats)
+
+    def __enter__(self) -> "_Span":
+        _open_spans.append(self.name)
+        self._annotation.__enter__()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self._annotation.__exit__(*exc)
+        _open_spans.pop()
+
+
+def span(name: str, **stats) -> _Span:
+    """Context manager for one region of the serve loop. With a profiler
+    trace active it lands on the host plane as an event ``name`` whose
+    event stats are ``stats``; the innermost open span also names the
+    phase that a compile inside it is charged to. The serve loop is
+    single-threaded, so one module-level stack holds the open spans."""
+    return _Span(name, stats)
+
+
+@dataclasses.dataclass
+class ServeCounters:
+    """Serve-loop counters, cumulative over an engine's life.
+
+    Per decode dispatch (``rounds``): the decode slots (``lane_rounds``)
+    are either running a trace (``lanes_running``) or empty, and a
+    round's empty slots all go to one cause — ``lanes_empty_memory`` when
+    in that round an admission, a prefill chunk or write-block assurance
+    was refused blocks; else ``lanes_empty_prefill`` when a prompt was
+    still in prefill chunks; else ``lanes_empty_other`` (nothing waiting,
+    or the token budget). So ``lanes_running`` plus the three causes is
+    ``lane_rounds``.
+
+    ``memory_full`` counts the scheduler's memory-full decisions, each
+    with one outcome: a prefix-cache block evicted, a trace pruned, a
+    lone trace truncated, idle prefixes or other prefill jobs reclaimed,
+    a trace preempted, or nothing freed (``memory_waits``).
+
+    ``prefill_tokens`` counts every prompt token a prefill program
+    computed, chunked (``prefill_chunks`` calls) or one-shot;
+    ``decode_tokens`` every token a decode call emitted to a trace.
+
+    ``compiles``/``compile_s`` are the backend compiles that ran while
+    the engine served, and ``compiles_by_phase`` maps the innermost span
+    open at each to ``[count, seconds]`` (``OUTSIDE`` for none)."""
+
+    rounds: int = 0
+    lane_rounds: int = 0
+    lanes_running: int = 0
+    lanes_empty_memory: int = 0
+    lanes_empty_prefill: int = 0
+    lanes_empty_other: int = 0
+    memory_full: int = 0
+    evictions: int = 0
+    prunes: int = 0
+    truncations: int = 0
+    reclaims: int = 0
+    preemptions: int = 0
+    memory_waits: int = 0
+    horizon_fallbacks: int = 0
+    prefill_chunks: int = 0
+    prefill_tokens: int = 0
+    decode_tokens: int = 0
+    compiles: int = 0
+    compile_s: float = 0.0
+    compiles_by_phase: Dict[str, list] = dataclasses.field(
+        default_factory=dict)
+
+    def note_dispatch(self, lanes: int, running: int, cause: str) -> None:
+        """One decode call over ``lanes`` slots, ``running`` of them in
+        use; ``cause`` ("memory" | "prefill" | "other") takes the rest."""
+        self.rounds += 1
+        self.lane_rounds += lanes
+        self.lanes_running += running
+        name = f"lanes_empty_{cause}"
+        setattr(self, name, getattr(self, name) + lanes - running)
+
+    def note_memory_full(self, outcome: str) -> None:
+        self.memory_full += 1
+        setattr(self, outcome, getattr(self, outcome) + 1)
+
+    def note_compile(self, phase: str, secs: float) -> None:
+        self.compiles += 1
+        self.compile_s += secs
+        entry = self.compiles_by_phase.setdefault(phase, [0, 0.0])
+        entry[0] += 1
+        entry[1] += secs
+
+    def snapshot(self) -> dict:
+        return dataclasses.asdict(self)
+
+
+def _on_duration(event: str, secs: float, **_) -> None:
+    if event == COMPILE_EVENT and _counting is not None:
+        _counting.note_compile(_open_spans[-1] if _open_spans else OUTSIDE,
+                               secs)
+
+
+@contextlib.contextmanager
+def counting(counters: ServeCounters) -> Iterator[ServeCounters]:
+    """Charge the backend compiles that run inside the block to
+    ``counters``. The JAX monitoring listener behind it is process-wide,
+    registered on first use and never again."""
+    global _counting, _listening
+    if not _listening:
+        jax.monitoring.register_event_duration_secs_listener(_on_duration)
+        _listening = True
+    prev, _counting = _counting, counters
+    try:
+        yield counters
+    finally:
+        _counting = prev

@@ -34,6 +34,21 @@ handler cascade executes the exact operation sequence of the old tick
 loop, so the event core is token/score/prune-identical to it under a
 fixed RNG (pinned in tests/test_scheduler.py).
 
+Measurement
+-----------
+
+The core writes its phases into the JAX profiler's trace as ``sched.*``
+spans (``metrics.span``): ``sched.round`` (one ``BudgetReplenish``)
+holds ``sched.admit`` (the admission wave: ``sched.prefill`` per prefill
+device call, ``sched.first_tokens`` per wave's first-token sampling),
+``sched.assure`` (write-block assurance and the horizon choice),
+``sched.decode`` (the uploads and the decode call's dispatch) and
+``sched.sync`` (the host blocked on its results); ``sched.memory_full``
+sits inside ``admit`` or ``assure``; ``sched.fold`` is one
+``BurstDone``. No span is opened per lane or per token. The engine's
+``ServeCounters`` (``Engine.counters``) are updated where the work
+happens.
+
 Scheduling policies
 -------------------
 
@@ -85,6 +100,7 @@ from repro.core.pruning import AdmissionPressure, DeepConfPolicy
 from repro.core.trace import Trace, TraceStatus
 from repro.data.arithmetic import extract_answer
 from repro.serving.faults import DeviceStepFault, FatalFaultError
+from repro.serving.metrics import span
 from repro.serving.queue import RequestQueue
 
 if TYPE_CHECKING:  # engine imports scheduler; never the reverse at runtime
@@ -683,11 +699,14 @@ class SchedulerCore:
         self.plan = eng.fault_plan
         self.recovery = eng.recovery
         self.stats = eng.fault_stats
+        self.counters = eng.counters
+        # this round refused blocks to an admission, a prefill chunk or
+        # write-block assurance (its empty lanes are memory's)
+        self._refused = False
         self._alloc_stalls = 0    # consecutive allocator-stalled rounds
         self._fanout_shed = False  # persistent-alloc shed rung taken
 
         self.events: deque = deque()
-        self.event_log: deque = deque(maxlen=4096)
 
     # -- policy-facing views ------------------------------------------------
     def tenant_of(self, request_id: int) -> str:
@@ -722,7 +741,6 @@ class SchedulerCore:
         self.events.append(event)
 
     def _notify(self, event: Event) -> None:
-        self.event_log.append(event)
         self.sched.on_event(event)
 
     def _now_rel(self) -> float:
@@ -817,8 +835,13 @@ class SchedulerCore:
         self._tokens_done += ev.chunk_tokens
 
     def _on_budget_replenish(self, ev: BudgetReplenish) -> None:
+        with span("sched.round", tick=ev.tick):
+            self._round(ev)
+
+    def _round(self, ev: BudgetReplenish) -> None:
         """One scheduling round: gates -> pressure -> admission wave ->
         write-block assurance -> decode dispatch."""
+        self._refused = False
         if self.plan is not None and self.plan.alloc_blocked(ev.tick):
             # injected allocator outage: STALL the whole round (no
             # admission, no decode) instead of reaching the memory-
@@ -845,7 +868,8 @@ class SchedulerCore:
                 st.policy.observe_pressure(pressure)
 
         budget = self.sched.tick_budget(self)
-        progressed = self.try_admit(budget)
+        with span("sched.admit"):
+            progressed = self.try_admit(budget)
         if not self.running:
             if not (self.waiting or self.jobs or self.pending):
                 return
@@ -975,7 +999,21 @@ class SchedulerCore:
 
     def handle_memory_full(self, needy: Optional[Trace], rid: int,
                            at_admission: bool = False) -> bool:
-        """Pool has no free block. Returns True if progress was made.
+        """Pool has no free block. Returns True if progress was made
+        (see ``_relieve_memory``); a refusal marks the round's empty
+        lanes as memory's."""
+        with span("sched.memory_full", request_id=rid):
+            outcome = self._relieve_memory(needy, rid, at_admission)
+        self.counters.note_memory_full(outcome)
+        if outcome == "memory_waits":
+            self._refused = True
+            return False
+        return True
+
+    def _relieve_memory(self, needy: Optional[Trace], rid: int,
+                        at_admission: bool) -> str:
+        """Free memory for ``rid``; returns the outcome, a
+        ``ServeCounters`` field: "memory_waits" when nothing was freed.
 
         STEP: the needy request's policy prunes its lowest-scored
         running trace, freeing its blocks — the waiting queue never
@@ -993,7 +1031,7 @@ class SchedulerCore:
         # (the cache can only ADD free-able memory, never displace a
         # trace that would have run with the cache off).
         if self.pcache is not None and self.pcache.evict(1):
-            return True
+            return "evictions"
         st = self.by_req[rid]
         own_running = [t for t in self.running if t.request_id == rid]
         victim = st.policy.on_memory_full(own_running,
@@ -1002,24 +1040,24 @@ class SchedulerCore:
             if len(own_running) <= 1 and needy is victim:
                 # sole survivor: finish (truncate) instead of self-prune
                 self.finish(victim)
-                return True
+                return "truncations"
             self.release(victim, TraceStatus.PRUNED)
-            return True
+            return "prunes"
         if self.reclaim_idle_prefix(skip_rid=rid):
-            return True
+            return "reclaims"
         if at_admission or not self.running:
-            return False  # baseline: queue the arrival, keep decoding
+            return "memory_waits"  # baseline: queue, keep decoding
         if self.abort_other_jobs(skip_rid=rid):
-            return True
+            return "reclaims"
         victim = self.sched.preempt_victim(self, needy)
         if victim is None:
             # lone trace cannot be preempted to help itself: truncate
             self.finish(needy)
-            return True
+            return "truncations"
         self.release(victim, TraceStatus.PREEMPTED)
         victim.runnable_since = time.perf_counter()
         self.waiting.append(victim)
-        return True
+        return "preemptions"
 
     def finish(self, trace: Trace) -> None:
         text = self.tok.decode(trace.output_tokens)
@@ -1328,20 +1366,24 @@ class SchedulerCore:
                 if not self.handle_memory_full(None, st.request_id,
                                                at_admission=True):
                     return "memory"
-            t_pf = time.perf_counter()
-            toks = np.zeros((1, C), np.int32)
-            toks[0, :c] = job.tokens[job.pos : job.pos + c]
-            pos_arr = job.pos + np.arange(C, dtype=np.int32)[None, :]
-            valid = (np.arange(C, dtype=np.int32)[None, :] < c)
-            logits, self.cache = self.device_call(
-                lambda: eng._chunk_prefill(
-                    eng.params, self.cache, jnp.asarray(toks),
-                    jnp.asarray(pos_arr), jnp.asarray(valid),
-                    jnp.asarray(job.row[None, :], jnp.int32)))
-            job.last_logits = logits[:, c - 1]
-            job.pos += c
-            budget.spend(c, tenant=tenant)
-            st.prefill_s += time.perf_counter() - t_pf
+            with span("sched.prefill", request_id=st.request_id,
+                      tokens=c):
+                t_pf = time.perf_counter()
+                toks = np.zeros((1, C), np.int32)
+                toks[0, :c] = job.tokens[job.pos : job.pos + c]
+                pos_arr = job.pos + np.arange(C, dtype=np.int32)[None, :]
+                valid = (np.arange(C, dtype=np.int32)[None, :] < c)
+                logits, self.cache = self.device_call(
+                    lambda: eng._chunk_prefill(
+                        eng.params, self.cache, jnp.asarray(toks),
+                        jnp.asarray(pos_arr), jnp.asarray(valid),
+                        jnp.asarray(job.row[None, :], jnp.int32)))
+                job.last_logits = logits[:, c - 1]
+                job.pos += c
+                budget.spend(c, tenant=tenant)
+                st.prefill_s += time.perf_counter() - t_pf
+            self.counters.prefill_chunks += 1
+            self.counters.prefill_tokens += c
             self.emit(ChunkDone(t=self._now_rel(),
                                 request_id=st.request_id,
                                 pos=job.pos, total=L, chunk_tokens=c))
@@ -1387,22 +1429,25 @@ class SchedulerCore:
         self.note_peak()
         row = np.zeros((eng.blocks_per_seq,), np.int32)
         row[:len(blocks)] = blocks
-        t_pf = time.perf_counter()
-        ids_arr = jnp.asarray(
-            np.array(trace.prompt_tokens, np.int32)[None, :])
-        try:
-            logits, kvs = self.device_call(
-                lambda: eng._prefill(eng.params, ids_arr))
-        except BaseException:
-            self.mgr.free(blocks)   # the local holding must not leak
-            raise
-        attn_kvs, slot_state = eng._split_prefill_kvs(kvs)
-        self.cache = eng._write_prefix_kv(self.cache, attn_kvs, row,
-                                          seq_len)
-        st.prefix = SharedPrefix(blocks=blocks, seq_len=seq_len,
-                                 last_logits=logits[:, -1],
-                                 slot_state=slot_state)
-        st.prefill_s += time.perf_counter() - t_pf
+        with span("sched.prefill", request_id=st.request_id,
+                  tokens=seq_len):
+            t_pf = time.perf_counter()
+            ids_arr = jnp.asarray(
+                np.array(trace.prompt_tokens, np.int32)[None, :])
+            try:
+                logits, kvs = self.device_call(
+                    lambda: eng._prefill(eng.params, ids_arr))
+            except BaseException:
+                self.mgr.free(blocks)   # the local holding must not leak
+                raise
+            attn_kvs, slot_state = eng._split_prefill_kvs(kvs)
+            self.cache = eng._write_prefix_kv(self.cache, attn_kvs, row,
+                                              seq_len)
+            st.prefix = SharedPrefix(blocks=blocks, seq_len=seq_len,
+                                     last_logits=logits[:, -1],
+                                     slot_state=slot_state)
+            st.prefill_s += time.perf_counter() - t_pf
+        self.counters.prefill_tokens += seq_len
         self._tokens_done += seq_len
         return True
 
@@ -1457,25 +1502,29 @@ class SchedulerCore:
         row = np.zeros((eng.blocks_per_seq,), np.int32)
         row[:len(blocks)] = blocks
         self.block_tables[slot] = row
-        t_pf = time.perf_counter()
-        ids_arr = jnp.asarray(np.array(ids, np.int32)[None, :])
-        # trace.blocks/slot are already registered, so a fatal abort
-        # from here releases them through the normal trace path
-        logits, kvs = self.device_call(
-            lambda: eng._prefill(eng.params, ids_arr))
-        cache_new = eng._write_prefill(self.cache, kvs, slot, row, len(ids))
-        # next token continues from the last prefill logit
-        self.positions[slot] = len(ids)
-        self.dirty["block_tables"] = self.dirty["positions"] = True
-        self.dirty["tokens"] = True
-        self._set_slot_sampling(slot, st)
-        nt, conf = eng.sample_host(logits[:, -1], st.sampling)
-        self.cur_tokens[slot] = int(nt[0])
-        trace.output_tokens.append(int(nt[0]))
-        trace.token_confidences.append(float(conf[0]))
-        st.note_first_token()
-        self.cache = cache_new
-        st.prefill_s += time.perf_counter() - t_pf
+        with span("sched.prefill", request_id=st.request_id,
+                  tokens=len(ids)):
+            t_pf = time.perf_counter()
+            ids_arr = jnp.asarray(np.array(ids, np.int32)[None, :])
+            # trace.blocks/slot are already registered, so a fatal abort
+            # from here releases them through the normal trace path
+            logits, kvs = self.device_call(
+                lambda: eng._prefill(eng.params, ids_arr))
+            cache_new = eng._write_prefill(self.cache, kvs, slot, row,
+                                           len(ids))
+            # next token continues from the last prefill logit
+            self.positions[slot] = len(ids)
+            self.dirty["block_tables"] = self.dirty["positions"] = True
+            self.dirty["tokens"] = True
+            self._set_slot_sampling(slot, st)
+            nt, conf = eng.sample_host(logits[:, -1], st.sampling)
+            self.cur_tokens[slot] = int(nt[0])
+            trace.output_tokens.append(int(nt[0]))
+            trace.token_confidences.append(float(conf[0]))
+            st.note_first_token()
+            self.cache = cache_new
+            st.prefill_s += time.perf_counter() - t_pf
+        self.counters.prefill_tokens += len(ids)
         self._tokens_done += len(ids)
 
     def _set_slot_sampling(self, slot: int, st: ReqState) -> None:
@@ -1493,16 +1542,17 @@ class SchedulerCore:
         live = [t for t in wave if t.status == TraceStatus.RUNNING]
         if not live:
             return
-        logits = jnp.concatenate(
-            [self.by_req[t.request_id].prefix.last_logits for t in live],
-            axis=0)  # [m, Vp]
-        if self.mixed_sampling:
-            sps = [self.by_req[t.request_id].sampling for t in live]
-            nt, conf = self.eng.sample_host_lanes(logits, sps)
-        else:
-            nt, conf = self.eng.sample_host(logits, self.ecfg.sampling)
-        nt = np.asarray(nt).tolist()
-        conf = np.asarray(conf).tolist()
+        with span("sched.first_tokens", wave=len(live)):
+            logits = jnp.concatenate(
+                [self.by_req[t.request_id].prefix.last_logits
+                 for t in live], axis=0)  # [m, Vp]
+            if self.mixed_sampling:
+                sps = [self.by_req[t.request_id].sampling for t in live]
+                nt, conf = self.eng.sample_host_lanes(logits, sps)
+            else:
+                nt, conf = self.eng.sample_host(logits, self.ecfg.sampling)
+            nt = np.asarray(nt).tolist()
+            conf = np.asarray(conf).tolist()
         self.dirty["tokens"] = True
         for i, trace in enumerate(live):
             self.cur_tokens[trace.batch_slot] = nt[i]
@@ -1662,6 +1712,7 @@ class SchedulerCore:
                                                    at_admission=True):
                         break
                     if not self.evict_for(need):
+                        self._refused = True
                         break
                     continue
                 budget.spend(ids_len + self.K_cfg, tenant=tenant)
@@ -1677,6 +1728,55 @@ class SchedulerCore:
         call, then a ``BurstDone`` event carrying the host-synced
         results."""
         eng = self.eng
+        with span("sched.assure"):
+            if not self._assure_write_blocks():
+                return
+            K_tick, limits = self._choose_horizon()
+
+        # one fixed-shape fused decode call: K_tick iterations of
+        # decode + on-device sampling + step-boundary score capture
+        n_by_req: Dict[int, int] = {}
+        for t in self.running:
+            n_by_req[t.request_id] = n_by_req.get(t.request_id, 0) + 1
+        t_dec = time.perf_counter()
+        with span("sched.decode", lanes=len(self.running), horizon=K_tick):
+            out = self._launch_decode(K_tick, limits)
+        (toks_d, confs_d, scores_d, tv_d, sv_d, fin_tok, fin_pos,
+         self.cache, eng._rng) = out
+        # single host sync per round; .tolist() batches the per-trace
+        # float()/int() conversions of the old per-token loop
+        with span("sched.sync"):
+            toks_h, confs_h, scores_h, tv_h, sv_h, ft_h, fp_h = (
+                x.tolist() for x in jax.device_get(
+                    (toks_d, confs_d, scores_d, tv_d, sv_d,
+                     fin_tok, fin_pos)))
+        if self.plan is not None:
+            # NaN injection poisons the victim lane's HOST-synced
+            # confidences only — device state is untouched, so the
+            # other lanes are trivially unperturbed. The quarantine
+            # path in _on_burst_done detects and terminates the lane.
+            lanes = sorted((t.batch_slot, t.request_id)
+                           for t in self.running)
+            for slot in self.plan.nan_victims(ev.tick, lanes):
+                confs_h[slot] = [float("nan")] * len(confs_h[slot])
+        self.dev["tokens"], self.dev["positions"] = fin_tok, fin_pos
+        self.cur_tokens[:] = ft_h
+        self.positions[:] = fp_h
+        dt = time.perf_counter() - t_dec
+        tot = sum(n_by_req.values())
+        for rid, n in n_by_req.items():
+            self.by_req[rid].decode_s += dt * n / tot
+
+        # the round's empty lanes go to one cause, counted with the fold
+        empty_cause = ("memory" if self._refused
+                       else "prefill" if self.jobs else "other")
+        self._burst = (toks_h, confs_h, scores_h, tv_h, sv_h, empty_cause)
+        self.emit(BurstDone(t=self._now_rel(), tick=ev.tick,
+                            n_lanes=len(self.running), tokens=0))
+
+    def _assure_write_blocks(self) -> bool:
+        """Make every running trace the exclusive owner of its next
+        write block; returns whether any trace still runs."""
         # ensure every running trace exclusively owns the block its
         # next token's KV will be written into: allocate fresh blocks
         # at the growth frontier, copy-on-write still-shared (prompt)
@@ -1702,9 +1802,10 @@ class SchedulerCore:
             if trace.status != TraceStatus.RUNNING or not progress:
                 continue
             self.claim_write_block(trace, bidx)
-        if not self.running:
-            return
+        return bool(self.running)
 
+    def _choose_horizon(self) -> Tuple[int, np.ndarray]:
+        """This round's decode horizon and each lane's token limit."""
         # decode horizon: how many tokens may this round fuse?
         K_cfg = self.K_cfg
         K_tick = K_cfg
@@ -1724,25 +1825,24 @@ class SchedulerCore:
                     {bidx for _, bidx in self.frontier_walk(trace, K_cfg)
                      if not self.owns_write_block(trace, bidx)})
             if needed_new and not self.evict_for(needed_new + 1):
-                eng.horizon_fallbacks += 1
+                self.counters.horizon_fallbacks += 1
                 K_tick = 1
-        if eng.force_horizon1:
+        if self.eng.force_horizon1:
             # persistent-fault degrade rung: every burst runs at K=1
             # (token-identical by the K==1 equivalence pin)
             K_tick = 1
 
-        B = self.B
-        limits = np.zeros((B,), np.int32)
+        limits = np.zeros((self.B,), np.int32)
         for trace in self.running:
             limits[trace.batch_slot] = (
                 1 if K_tick == 1 else self.extend_frontier(trace, K_tick))
+        return K_tick, limits
 
-        # one fixed-shape fused decode call: K_tick iterations of
-        # decode + on-device sampling + step-boundary score capture
-        n_by_req: Dict[int, int] = {}
-        for t in self.running:
-            n_by_req[t.request_id] = n_by_req.get(t.request_id, 0) + 1
-        t_dec = time.perf_counter()
+    def _launch_decode(self, K_tick: int, limits: np.ndarray) -> tuple:
+        """Upload the dirty decode-state arrays and run the fused decode
+        call under the fault plan; returns its device outputs."""
+        eng = self.eng
+        K_cfg = self.K_cfg
         ss = eng._ss
         for name, arr in (("tokens", self.cur_tokens),
                           ("positions", self.positions),
@@ -1779,40 +1879,18 @@ class SchedulerCore:
                       self.dev["block_tables"], eng._rng,
                       eng.scorer_params, *extra)
 
-        (toks_d, confs_d, scores_d, tv_d, sv_d, fin_tok, fin_pos,
-         self.cache, eng._rng) = self.device_call(decode_thunk)
-        # single host sync per round; .tolist() batches the per-trace
-        # float()/int() conversions of the old per-token loop
-        toks_h, confs_h, scores_h, tv_h, sv_h, ft_h, fp_h = (
-            x.tolist() for x in jax.device_get(
-                (toks_d, confs_d, scores_d, tv_d, sv_d,
-                 fin_tok, fin_pos)))
-        if self.plan is not None:
-            # NaN injection poisons the victim lane's HOST-synced
-            # confidences only — device state is untouched, so the
-            # other lanes are trivially unperturbed. The quarantine
-            # path in _on_burst_done detects and terminates the lane.
-            lanes = sorted((t.batch_slot, t.request_id)
-                           for t in self.running)
-            for slot in self.plan.nan_victims(ev.tick, lanes):
-                confs_h[slot] = [float("nan")] * len(confs_h[slot])
-        self.dev["tokens"], self.dev["positions"] = fin_tok, fin_pos
-        self.cur_tokens[:] = ft_h
-        self.positions[:] = fp_h
-        dt = time.perf_counter() - t_dec
-        tot = sum(n_by_req.values())
-        for rid, n in n_by_req.items():
-            self.by_req[rid].decode_s += dt * n / tot
-
-        self._burst = (toks_h, confs_h, scores_h, tv_h, sv_h)
-        self.emit(BurstDone(t=self._now_rel(), tick=ev.tick,
-                            n_lanes=len(self.running), tokens=0))
+        return self.device_call(decode_thunk)
 
     def _on_burst_done(self, ev: BurstDone) -> None:
+        with span("sched.fold"):
+            self._fold_burst(ev)
+
+    def _fold_burst(self, ev: BurstDone) -> None:
         """Fold the synced burst into traces: outputs, scores, EOS /
         max-new-token finishes, then the signal-triggered termination
         sweep (DeepConf / Slim-SC / STEP proactive pruning)."""
-        toks_h, confs_h, scores_h, tv_h, sv_h = self._burst
+        toks_h, confs_h, scores_h, tv_h, sv_h, empty_cause = self._burst
+        self.counters.note_dispatch(self.B, ev.n_lanes, empty_cause)
         emitted = 0
         quarantined = False
         for trace in list(self.running):
@@ -1858,6 +1936,7 @@ class SchedulerCore:
                 self.finish(trace)
         ev.tokens = emitted
         self._tokens_done += emitted
+        self.counters.decode_tokens += emitted
         if quarantined:
             self.audit()
 

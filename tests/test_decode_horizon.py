@@ -228,7 +228,7 @@ def test_horizon_pressure_fallback(setup):
                  policy, scorer_params=scorer)
     res = eng.serve_batch([Request(request_id=0, prompt_tokens=prompts[0],
                                    n_traces=8, policy=policy)])[0]
-    assert eng.horizon_fallbacks > 0
+    assert eng.counters.horizon_fallbacks > 0
     assert res.wait_s == 0.0 and res.num_preemptions == 0
     assert eng.pool_drained()
     eng.block_mgr.check_invariants()

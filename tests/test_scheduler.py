@@ -410,15 +410,26 @@ def test_uniform_override_matches_engine_default(setup):
 # event stream
 # ---------------------------------------------------------------------------
 
+class _Recorder(FIFOPolicy):
+    """A FIFO policy that records every event the core dispatches."""
+
+    def __init__(self):
+        self.log = []
+
+    def on_event(self, event):
+        self.log.append(event)
+
+
 def test_event_stream_contract(setup):
-    """serve_batch leaves the dispatched event tail on the engine:
+    """A policy's ``on_event`` sees the dispatched event stream:
     arrivals precede everything for their request, one Completion per
     request, timestamps non-decreasing."""
     cfg, params, prompts = setup
+    rec = _Recorder()
     eng = Engine(params, cfg, _ecfg(chunk=4, budget=16),
-                 make_policy("sc"))
+                 make_policy("sc"), scheduler=rec)
     results = eng.serve_batch(_reqs(prompts, n=2))
-    log = eng.last_event_log
+    log = rec.log
     assert log and isinstance(log[0], Arrival)
     times = [ev.t for ev in log]
     assert times == sorted(times)
