@@ -106,7 +106,7 @@ from repro.serving.metrics import RequestMetrics, ServeCounters, counting
 from repro.serving.prefix_cache import PrefixCache
 from repro.serving.sampling import (SamplingParams, sample_logits,
                                     sample_logits_lanes, sample_tokens,
-                                    sample_tokens_lanes)
+                                    sample_tokens_lanes, sampler_path)
 from repro.serving.scheduler import (SLO, ReqState, SchedulerCore,
                                      SchedulingPolicy, SharedPrefix,
                                      default_scheduler)
@@ -363,7 +363,8 @@ class Request:
     existing callers are untouched. A batch where every request inherits
     the engine sampling runs the scalar decode path unchanged; any
     override flips that serve call onto the lane-wise sampling path
-    (identical math per lane — see ``sampling.sample_logits_lanes``).
+    (the same kept set per lane, up to the rounding of the top-p sum —
+    see ``sampling.sample_logits_lanes``).
 
     Multi-tenant serving (consumed by ``scheduler.TenantScheduler``;
     inert under the default FIFO policy): ``tenant`` names the fair-share
@@ -759,6 +760,9 @@ class Engine:
         pre-refactor tick loop did — the identity pins depend on this
         key-consumption order)."""
         self._rng, k = jax.random.split(self._rng)
+        self.counters.note_sampled(
+            sampler_path(sp, logits.shape[-1], lanewise=False),
+            logits.shape[0])
         return sample_tokens(k, logits, temperature=sp.temperature,
                              top_k=sp.top_k, top_p=sp.top_p)
 
@@ -769,6 +773,7 @@ class Engine:
         temps = jnp.asarray([s.temperature for s in sps], jnp.float32)
         topks = jnp.asarray([s.top_k for s in sps], jnp.int32)
         topps = jnp.asarray([s.top_p for s in sps], jnp.float32)
+        self.counters.note_sampled("sort", logits.shape[0])
         return sample_tokens_lanes(k, logits, temps, topks, topps)
 
     # ------------------------------------------------------------------

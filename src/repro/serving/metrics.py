@@ -275,6 +275,12 @@ class ServeCounters:
     computed, chunked (``prefill_chunks`` calls) or one-shot;
     ``decode_tokens`` every token a decode call emitted to a trace.
 
+    ``sampled_select`` / ``sampled_sort`` count the sampled rows (every
+    emitted decode token and first-token row) whose top-k/top-p filter
+    ran the exact selection or a full sort of the row
+    (``sampling.sampler_path``); greedy and unfiltered rows count in
+    neither.
+
     ``compiles``/``compile_s`` are the backend compiles that ran while
     the engine served, and ``compiles_by_phase`` maps the innermost span
     open at each to ``[count, seconds]`` (``OUTSIDE`` for none)."""
@@ -296,6 +302,8 @@ class ServeCounters:
     prefill_chunks: int = 0
     prefill_tokens: int = 0
     decode_tokens: int = 0
+    sampled_select: int = 0
+    sampled_sort: int = 0
     compiles: int = 0
     compile_s: float = 0.0
     compiles_by_phase: Dict[str, list] = dataclasses.field(
@@ -313,6 +321,13 @@ class ServeCounters:
     def note_memory_full(self, outcome: str) -> None:
         self.memory_full += 1
         setattr(self, outcome, getattr(self, outcome) + 1)
+
+    def note_sampled(self, path: str, rows: int) -> None:
+        """``rows`` sampled by a filter of ``path`` ("select" | "sort" |
+        "none", as ``sampling.sampler_path`` names it)."""
+        if path != "none":
+            name = f"sampled_{path}"
+            setattr(self, name, getattr(self, name) + rows)
 
     def note_compile(self, phase: str, secs: float) -> None:
         self.compiles += 1

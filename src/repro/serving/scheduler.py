@@ -99,9 +99,11 @@ import numpy as np
 from repro.core.pruning import AdmissionPressure, DeepConfPolicy
 from repro.core.trace import Trace, TraceStatus
 from repro.data.arithmetic import extract_answer
+from repro.models.init import padded_vocab
 from repro.serving.faults import DeviceStepFault, FatalFaultError
 from repro.serving.metrics import span
 from repro.serving.queue import RequestQueue
+from repro.serving.sampling import sampler_path
 
 if TYPE_CHECKING:  # engine imports scheduler; never the reverse at runtime
     from repro.serving.engine import Engine, Request
@@ -675,6 +677,10 @@ class SchedulerCore:
         # overrides the engine-global SamplingParams
         sp = ecfg.sampling
         self.mixed_sampling = any(st.sampling != sp for st in states)
+        # the filter the decode program's sampler runs (counted per
+        # emitted token)
+        self.decode_sampler = sampler_path(sp, padded_vocab(eng.cfg),
+                                           self.mixed_sampling)
         self.samp = {
             "temperature": np.full((B,), sp.temperature, np.float32),
             "top_k": np.full((B,), sp.top_k, np.int32),
@@ -1937,6 +1943,7 @@ class SchedulerCore:
         ev.tokens = emitted
         self._tokens_done += emitted
         self.counters.decode_tokens += emitted
+        self.counters.note_sampled(self.decode_sampler, emitted)
         if quarantined:
             self.audit()
 

@@ -4,6 +4,7 @@ graph nests; ``Engine.counters`` against the benchmark's Observer over
 one window under pool pressure; a compile charged to the span it fell
 in; the span reduction on a hand-made trace; and the program names that
 the trace reduction keys on."""
+import dataclasses
 import functools
 
 import jax
@@ -179,6 +180,31 @@ def test_a_new_wave_size_compiles_under_first_tokens():
     assert c.compiles == sum(v[0] for v in c.compiles_by_phase.values())
     assert c.compile_s == pytest.approx(
         sum(v[1] for v in c.compiles_by_phase.values()))
+
+
+@pytest.mark.parametrize("mode, path", [
+    ("top_k", "select"), ("top_p_only", "sort"), ("lanewise", "sort")])
+def test_sampled_rows_count_by_filter(mode, path):
+    """Every sampled row (a decode token or a first token) counts under
+    the filter its sampler ran: the mix's top-k sampling selects; top-p
+    alone, and a batch that samples lane-wise because one request
+    overrides the engine's sampling, sort."""
+    sampling = ({"temperature": 0.6, "top_k": 0, "top_p": 0.95}
+                if mode == "top_p_only" else None)
+    eng = engine(num_blocks=64, sampling=sampling)
+    reqs = requests(n=3, max_new=12)
+    if mode == "lanewise":
+        reqs[0] = dataclasses.replace(reqs[0], sampling=SamplingParams(
+            temperature=0.7, top_k=5, top_p=0.9, max_new_tokens=12))
+    results = eng.serve_batch(reqs)
+    c = eng.counters
+    traces = [t for r in results for t in r.traces]
+    rows = sum(len(t.output_tokens) for t in traces)
+    first_rows = sum(bool(t.output_tokens) for t in traces)
+    assert c.preemptions == 0 and c.decode_tokens > 0
+    assert getattr(c, f"sampled_{path}") == rows \
+        == c.decode_tokens + first_rows
+    assert c.sampled_select + c.sampled_sort == rows
 
 
 # ---------------------------------------------------------------------------

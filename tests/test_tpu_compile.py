@@ -5,7 +5,8 @@ compiler refuses: unaligned slices, loads from HBM refs, more VMEM than a
 kernel may use. These tests compile each kernel ahead of time against a
 described ``v5e:2x2`` topology — no chip is needed, nothing runs — and
 check that the program holds the Mosaic kernel (``tpu_custom_call``)
-rather than a fallback.
+rather than a fallback. The decode program's sampler compiles the same
+way, to check that no sort over the whole vocabulary comes back.
 
 The topology is described inside a module-scoped fixture, never at
 import: only one process may load the TPU library at a time, and a
@@ -22,6 +23,8 @@ from repro.configs.qwen3_4b_thinking import full_config
 from repro.core.scorer import SCORER_HIDDEN
 from repro.kernels import paged_attention as pa
 from repro.kernels import step_score as ss
+from repro.models.init import padded_vocab
+from repro.serving.sampling import sample_logits
 
 CFG = full_config()
 H, KVH, HD, PAGE = CFG.num_heads, CFG.num_kv_heads, CFG.head_dim, 16
@@ -103,3 +106,20 @@ def test_kernel_compiles_for_v5e(one_chip, lower, pool_dtype):
 
     compiled = lower(sds, pool_dtype).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_sampler_sorts_no_vocabulary_row(one_chip):
+    """The engine's top-k/top-p sampler at 128 lanes of Qwen3-4B's padded
+    vocabulary: the compiler may turn a ``lax.top_k`` back into a sort
+    of its whole input, so no ``sort`` may take a vocabulary-wide
+    operand."""
+    vp = padded_vocab(CFG)
+    fn = jax.jit(lambda k, x: sample_logits(k, x, temperature=0.6,
+                                            top_k=20, top_p=0.95))
+    text = fn.lower(
+        jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip),
+        jax.ShapeDtypeStruct((128, vp), jnp.bfloat16, sharding=one_chip),
+    ).compile().as_text()
+    sorts = [ln for ln in text.splitlines() if " sort(" in ln]
+    assert sorts, "the selection's small sorts are gone: check the pattern"
+    assert not [ln for ln in sorts if f",{vp}]" in ln.split(" sort(")[0]]
