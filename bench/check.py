@@ -2,9 +2,10 @@
 
 Once the window has closed, a sample of the served traces, drawn from the
 seed and holding the longest and the most scored, is run through the plain reference
-(``bench/reference.py``) as one sequence each: its prompt followed by the
-tokens the engine served. Two numbers are compared, each against its
-limit in ``bench/limits/<cell>.json``:
+of the configuration's family (``bench/families/<family>/reference.py``)
+as one sequence each: its prompt followed by the tokens the engine
+served. Two numbers are compared, each against its limit in
+``bench/limits/<cell>.json``:
 
 - ``logprob_err``: over every served token of the sample (the first comes
   from the prefill's last position, the rest from decode), the widest gap
@@ -29,8 +30,6 @@ from typing import List
 import jax.numpy as jnp
 import numpy as np
 
-from bench import reference
-
 NUMBERS = ("logprob_err", "score_err")
 
 
@@ -54,10 +53,11 @@ def pick(served: List, rng: np.random.Generator, n: int) -> List:
     return chosen
 
 
-def _pad(xs: List[int]) -> np.ndarray:
-    """Right-pad to a power of two (at least two blocks), so that the
-    reference compiles a few programs, not one per length."""
-    n = 2 * reference.BLOCK
+def _pad(xs: List[int], block: int) -> np.ndarray:
+    """Right-pad to a power of two (at least two of the reference's
+    blocks), so that the reference compiles a few programs, not one per
+    length."""
+    n = 2 * block
     while n < len(xs):
         n *= 2
     out = np.zeros((n,), np.int32)
@@ -65,16 +65,18 @@ def _pad(xs: List[int]) -> np.ndarray:
     return out
 
 
-def _run(params, scorer, inputs, targets, mt, low) -> dict:
+def _run(reference, params, scorer, inputs, targets, mt, low) -> dict:
     out = reference.forward(params, scorer, jnp.asarray(inputs),
                             jnp.asarray(targets[None]), m=mt, low=low)
     return {k: np.asarray(v, np.float64) for k, v in out.items()}
 
 
-def compare(params, scorer, m: dict, sample: List, step_id: int,
-            scores_expected: bool, control: bool = False) -> dict:
-    """The numbers for ``sample`` (from ``pick``). With ``control`` the
-    reference below the stated precision stands in for the engine."""
+def compare(reference, params, scorer, m: dict, sample: List,
+            step_id: int, scores_expected: bool,
+            control: bool = False) -> dict:
+    """The numbers for ``sample`` (from ``pick``), against ``reference``,
+    the family's reference module. With ``control`` the reference below
+    the stated precision stands in for the engine."""
     mt = tuple(sorted(m.items()))
     worst = {k: 0.0 for k in NUMBERS}
     counts = {k: 0 for k in NUMBERS}
@@ -82,13 +84,14 @@ def compare(params, scorer, m: dict, sample: List, step_id: int,
         out = list(trace.output_tokens)
         seq = list(trace.prompt_tokens) + out
         P, n = len(trace.prompt_tokens), len(out)
-        inputs, targets = _pad(seq[:-1]), _pad(seq[1:])
+        inputs = _pad(seq[:-1], reference.BLOCK)
+        targets = _pad(seq[1:], reference.BLOCK)
         at = slice(P - 1, P - 1 + n)
         scored = [P + j for j in range(n - 1) if out[j] == step_id]
-        ref = _run(params, scorer, inputs, targets, mt, False)
+        ref = _run(reference, params, scorer, inputs, targets, mt, False)
         ref_logp = ref["tgt"][0][at] - ref["lse"][at]
         if control:
-            low = _run(params, scorer, inputs, targets, mt, True)
+            low = _run(reference, params, scorer, inputs, targets, mt, True)
             logp = low["tgt"][0][at] - low["lse"][at]
             scores = low["score"][scored]
         else:

@@ -17,6 +17,9 @@ from __future__ import annotations
 
 import math
 
+# the kernels the trace reduction times per program
+KERNELS = ("paged_attention", "paged_attention_prefill")
+
 
 def matmul_params(m: dict) -> int:
     """Weights a token multiplies by, output head over the real vocab."""

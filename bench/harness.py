@@ -3,10 +3,21 @@
 Everything that belongs to a configuration, a traffic mix, a metric or a
 cell's limits is found by name in its own file:
 
-    bench/configs/<config>.json   sizes, engine pool, step-row scale
+    bench/configs/<config>.json   sizes, engine pool, step-row scale, and
+                                  the architecture family it belongs to
     bench/traffic/<traffic>.json  the mix and the engine settings it pins
     bench/metrics/<metric>.py     read(ctx) -> number or None
     bench/limits/<cell>.json      the limits of the correctness check
+    bench/families/<family>/      what is particular to an architecture:
+        weights.py    make_weights(m, key, step_id, step_row_scale)
+                      -> (params, scorer), jitted with all but ``key``
+                      static
+        reference.py  forward(params, scorer, tokens, targets, *, m,
+                      low=False) -> {"lse", "tgt", "score"}, and BLOCK,
+                      the length ``tokens`` is a multiple of
+        cost.py       the FLOP and byte counts the metric readers take
+                      from ``ctx.cost``, and KERNELS, the kernel op names
+                      the trace reduction times per program
 
 A run: check the device, make the configuration's weights on the device,
 build the engine through its public API, warm the shapes the cell's
@@ -27,6 +38,7 @@ import shutil
 import sys
 import tempfile
 import time
+import types
 from concurrent.futures import ThreadPoolExecutor
 from pathlib import Path
 from typing import Optional
@@ -73,15 +85,42 @@ def load_cell(name: str) -> dict:
             "end_to_end": e2e, "per_layer": layer}
 
 
+def _load_module(name: str, path: Path):
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def load_reader(metric: str):
     path = ROOT / "bench" / "metrics" / f"{metric}.py"
     if not path.is_file():
         raise Refused(f"no reader bench/metrics/{metric}.py")
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{metric}",
-                                                  path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _load_module(f"bench_metric_{metric}", path).read
+
+
+FAMILY_MODULES = ("weights", "reference", "cost")
+
+
+def load_family(config: dict) -> types.SimpleNamespace:
+    """The three modules of the configuration's architecture family,
+    ``bench/families/<family>/``, as ``.weights``, ``.reference`` and
+    ``.cost``."""
+    if "family" not in config:
+        raise Refused(f"configuration {config['name']!r} names no family: "
+                      f"its file needs \"family\", a directory under "
+                      f"bench/families/")
+    rel = Path("bench") / "families" / config["family"]
+    if not (ROOT / rel).is_dir():
+        raise Refused(f"no family directory {rel}/")
+    mods = {}
+    for part in FAMILY_MODULES:
+        path = ROOT / rel / f"{part}.py"
+        if not path.is_file():
+            raise Refused(f"missing {rel / part}.py")
+        mods[part] = _load_module(f"bench_family_{config['family']}_{part}",
+                                  path)
+    return types.SimpleNamespace(**mods)
 
 
 # ---------------------------------------------------------------------------
@@ -156,11 +195,13 @@ def warm_host_shapes(eng, mix: dict, samp) -> None:
     prefill logits before it, one of each per wave size. A wave holds
     the traces of one request, or of several whose prefills finished in
     the same round, and under a full pool traces are admitted one by one
-    as memory frees: every size up to two requests' traces, and every
-    multiple of one request's traces up to the decode slots. (All 128
-    sizes took over 300 s to compile on a TPU v5e, beyond a run's
-    time.) A TPU compile of even a small program takes seconds, so they
-    compile side by side in threads."""
+    as memory frees: every size up to four requests' traces (waves of
+    two or three requests and part of another, 36 to 56 traces, come in
+    a window, and a size not warmed compiles there, about 8 s on a TPU
+    v5e), and every multiple of one request's traces up to the decode
+    slots. (All 128 sizes took over 300 s to compile on a TPU v5e,
+    beyond a run's time.) A TPU compile of even a small program takes
+    seconds, so they compile side by side in threads."""
     import jax
     import jax.numpy as jnp
     from repro.serving.sampling import sample_tokens
@@ -174,7 +215,7 @@ def warm_host_shapes(eng, mix: dict, samp) -> None:
                              top_k=samp.top_k, top_p=samp.top_p)
 
     n, top = mix["n_traces"], mix["engine"]["max_batch"]
-    sizes = sorted(set(range(1, 2 * n + 1)) | set(range(n, top + 1, n)))
+    sizes = sorted(set(range(1, 4 * n + 1)) | set(range(n, top + 1, n)))
     with ThreadPoolExecutor(WARM_THREADS) as pool:
         outs = list(pool.map(wave, sizes))
     jax.block_until_ready(outs)
@@ -236,19 +277,23 @@ class Context:
     weight_bytes: int
     page: int
     trace: Optional[dict]
+    cost: Optional[types.ModuleType] = None   # the family's cost module
 
 
 def run(workload: str, seed: int, seconds: float, trace: bool, *,
         t_process: float, require_chip: bool = True, control: bool = False,
         files: Optional[dict] = None) -> dict:
     """One run; returns the result line's object. ``files`` (tests) gives
-    the cell's files directly instead of reading them by name. With
+    the cell's files directly instead of reading them by name, and may
+    give the family's modules under ``"family"`` (``load_family``'s
+    namespace) in place of the configuration's own. With
     ``control`` the correctness check's control (the reference below the
     stated precision in the engine's place) is judged as well, on the same
     served tokens and limits, and its verdict printed on standard error."""
     check_environment()
     spec = files if files is not None else load_cell(workload)
     cell, config, mix = spec["cell"], spec["config"], spec["mix"]
+    family = spec["family"] if "family" in spec else load_family(config)
     peaks = check_devices(cell["chips"]) if require_chip else {
         "bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
 
@@ -260,7 +305,6 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
 
     from bench import check, traffic
     from bench.observer import Observer
-    from bench.weights import make_weights, weight_bytes
     from repro.core.pruning import make_policy
     from repro.data.tokenizer import get_tokenizer
     from repro.serving import Engine, EngineConfig, Request, SamplingParams
@@ -274,7 +318,8 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
     # same model and does the same work. The seed draws the traffic (its
     # prompts and their order), the engine's sampling and the check's sample.
     s_engine, s_traffic, s_check = derive_seeds(seed, 3)
-    params, scorer = jax.jit(make_weights, static_argnums=(0, 2, 3))(
+    params, scorer = jax.jit(family.weights.make_weights,
+                             static_argnums=(0, 2, 3))(
         tuple(sorted(m.items())), jax.random.PRNGKey(config["weights_seed"]),
         tok.step_id, config["step_row_scale"])
     jax.block_until_ready((params, scorer))
@@ -350,7 +395,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
     attempted = len(due)
     failed = sum(1 for md in due if md.status == "failed")
     served = [t for r in results for t in r.traces if t.num_tokens > 0]
-    wb = weight_bytes(params)
+    wb = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(params))
     eng_state = {"requests": len(results),
                  "requests_left": sum(1 for r in results
                                       if r.metrics.admitted_s is None),
@@ -364,14 +409,14 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
     rng = np.random.default_rng(s_check)
     sample = check.pick(served, rng, mix["check"]["traces"])
     scores_expected = make_policy(mix["policy"]).uses_scorer
-    numbers = check.compare(params, scorer, m, sample, tok.step_id,
-                            scores_expected)
+    numbers = check.compare(family.reference, params, scorer, m, sample,
+                            tok.step_id, scores_expected)
     limits = spec["limits"]["limits"]
     correct = check.judge(numbers, limits)
     ctrl = None
     if control:
-        ctrl = check.compare(params, scorer, m, sample, tok.step_id,
-                             scores_expected, control=True)
+        ctrl = check.compare(family.reference, params, scorer, m, sample,
+                             tok.step_id, scores_expected, control=True)
         ctrl["correct"] = check.judge(ctrl, limits)
     check_s = time.perf_counter() - t_chk
 
@@ -379,12 +424,14 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
     if trace:
         from bench import trace_reduce
         summary = trace_reduce.reduce_dir(
-            prof_dir, prof["trace_end"] - prof["window_start"])
+            prof_dir, prof["trace_end"] - prof["window_start"],
+            family.cost.KERNELS)
         shutil.rmtree(prof_dir, ignore_errors=True)
 
     ctx = Context(model=m, engine=eng_cfg, peaks=peaks,
                   seconds=seconds, setup_s=setup_s, obs=obs,
-                  weight_bytes=wb, page=cfg.kv_block_size, trace=summary)
+                  weight_bytes=wb, page=cfg.kv_block_size, trace=summary,
+                  cost=family.cost)
     wanted = spec["per_layer"] if trace else spec["end_to_end"]
     metrics = {}
     for mdef in wanted:

@@ -2,7 +2,6 @@
 must move (the weights once per decode iteration, every emitted token's
 live KV pages in every layer) over the decode program's device time per
 call from the trace, over the chip's HBM bandwidth, in percent."""
-from bench import flops
 
 PROGRAM = "jit_batched_decode"
 
@@ -13,8 +12,8 @@ def read(ctx):
     if t is None or not bursts or PROGRAM not in t["modules"]:
         return None
     mod = t["modules"][PROGRAM]
-    per_call = sum(flops.decode_call_bytes(ctx.model, ctx.weight_bytes,
-                                           b.lens, ctx.page, b.steps)
+    per_call = sum(ctx.cost.decode_call_bytes(ctx.model, ctx.weight_bytes,
+                                              b.lens, ctx.page, b.steps)
                    for b in bursts) / len(bursts)
     secs = mod["s"] / mod["count"]
     return 100.0 * per_call / secs / ctx.peaks["hbm_bytes_per_s"]

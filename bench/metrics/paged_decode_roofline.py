@@ -1,10 +1,9 @@
 """Kernels (the traced part of the window): the paged-attention kernel
 inside the decode program. Its least time per run (one layer of one
 decode iteration: the larger of FLOPs over the bf16 peak and live-page
-bytes over HBM bandwidth, bench/flops.py) over its measured time per run
-from the trace, in percent. A decode call of K iterations runs the kernel
-K times per layer."""
-from bench import flops
+bytes over HBM bandwidth, the family's cost module) over its measured
+time per run from the trace, in percent. A decode call of K iterations
+runs the kernel K times per layer."""
 
 KEY = "jit_batched_decode/paged_attention"
 
@@ -17,7 +16,7 @@ def read(ctx):
     k = t["kernels"][KEY]
     least = 0.0
     for b in bursts:
-        f, by = flops.paged_decode_kernel(ctx.model, b.lens, ctx.page)
+        f, by = ctx.cost.paged_decode_kernel(ctx.model, b.lens, ctx.page)
         least += max(f / ctx.peaks["bf16_flops_per_s"],
                      by / ctx.peaks["hbm_bytes_per_s"])
     least /= sum(b.steps for b in bursts)     # one layer of one iteration

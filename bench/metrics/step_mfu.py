@@ -1,13 +1,13 @@
 """Jitted steps: model FLOPs of every token the decode and prefill
-programs computed in the window (by shape and context, bench/flops.py),
-over the window's seconds times the chip's bf16 peak, in percent."""
-from bench import flops
+programs computed in the window (by shape and context, the family's cost
+module), over the window's seconds times the chip's bf16 peak, in
+percent."""
 
 
 def read(ctx):
-    m, obs = ctx.model, ctx.obs
-    total = sum(flops.token_flops(m, n) for b in obs.bursts for n in b.lens)
-    total += sum(flops.prefill_flops(m, start, n)
+    m, obs, cost = ctx.model, ctx.obs, ctx.cost
+    total = sum(cost.token_flops(m, n) for b in obs.bursts for n in b.lens)
+    total += sum(cost.prefill_flops(m, start, n)
                  for _, start, n in obs.chunks)
     if not total:
         return None

@@ -1,9 +1,9 @@
-"""FLOP and byte counts of bench/flops.py against hand counts for one
-Qwen3-4B decode call."""
+"""FLOP and byte counts of bench/families/qwen3/cost.py against hand
+counts for one Qwen3-4B decode call."""
 import json
 from pathlib import Path
 
-from bench import flops
+from bench.families.qwen3 import cost as flops
 
 M = json.loads((Path(__file__).resolve().parents[1] / "configs"
                 / "qwen3-4b-thinking.json").read_text())["model"]

@@ -6,6 +6,7 @@ import types
 
 import pytest
 
+from bench.families.qwen3 import cost
 from bench.metrics import decode_hbm_share, paged_decode_roofline
 from bench.observer import Burst
 from bench.tests import tiny
@@ -27,7 +28,8 @@ def ctx(bursts, kernel_s, module_s):
                           "count": len(bursts)}}}
     obs = types.SimpleNamespace(traced=lambda: (bursts, []))
     return types.SimpleNamespace(trace=trace, obs=obs, model=tiny.TINY_MODEL,
-                                 peaks=PEAKS, page=16, weight_bytes=WEIGHTS)
+                                 peaks=PEAKS, page=16, weight_bytes=WEIGHTS,
+                                 cost=cost)
 
 
 def burst(lens, steps):

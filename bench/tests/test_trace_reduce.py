@@ -75,3 +75,21 @@ def test_hand_trace():
     ops = dict(red["breakdown"]["device_ops"])
     assert ops["fusion.3 bf16[2,2560]"] == pytest.approx(7e-6)
     assert not any(k.startswith("while") for k in ops)
+
+
+def test_reduce_dir_times_the_kernels_it_is_given(tmp_path):
+    """A kernel that is not among ``reduce``'s default names (a family's
+    own, from its ``cost.KERNELS``) is timed per program once
+    ``reduce_dir`` is given its name."""
+    text = HAND.replace("%paged_attention.9", "%latent_attention.9")
+    path = tmp_path / "plugins" / "profile" / "run" / "host.xplane.pb"
+    path.parent.mkdir(parents=True)
+    path.write_bytes(ProfileData.text_proto_to_serialized_xspace(text))
+    plain = trace_reduce.reduce(trace_reduce.load(str(path)), 30e-6)
+    assert "jit_batched_decode/latent_attention" not in plain["kernels"]
+    red = trace_reduce.reduce_dir(str(tmp_path), 30e-6,
+                                  ("latent_attention",
+                                   "paged_attention_prefill"))
+    assert red["kernels"]["jit_batched_decode/latent_attention"] == {
+        "s": pytest.approx(2e-6), "count": 1}
+    assert "jit_chunk_prefill/paged_attention_prefill" in red["kernels"]

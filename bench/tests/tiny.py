@@ -22,7 +22,8 @@ def files(traffic: str = "step-pressure", **engine) -> dict:
     ``engine`` overrides the mix's engine settings."""
     mix = copy.deepcopy(
         json.loads((BENCH / "traffic" / f"{traffic}.json").read_text()))
-    config = {"name": "tiny", "program_config": "repro.configs.qwen3_1_7b",
+    config = {"name": "tiny", "family": "qwen3",
+              "program_config": "repro.configs.qwen3_1_7b",
               "program_overrides": dict(TINY_MODEL),
               "model": dict(TINY_MODEL), "weights_seed": 7,
               "step_row_scale": 2.5,

@@ -13,7 +13,8 @@ missing (null) metric and never as a silent zero:
   its base name is ``name`` without the ``.N``. A Pallas kernel is the
   custom call named after its jitted wrapper (``paged_attention`` for the
   decode face of ``_mq_paged_kernel``, ``paged_attention_prefill`` for
-  the chunked-prefill face);
+  the chunked-prefill face); a run times per program the kernels its
+  configuration's family lists in ``cost.KERNELS``;
 - control-flow ops (``while``, ``conditional``, ``call``) span the ops
   they run and are left out of the ops breakdown.
 
@@ -30,7 +31,7 @@ import glob
 import os
 import re
 from collections import defaultdict
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Sequence
 
 OPS_LINE = "XLA Ops"
 CONTAINERS = ("while", "conditional", "call")
@@ -177,8 +178,11 @@ def attribute_gaps(gaps: List[tuple], host: List[tuple]) -> Dict[str, float]:
     return out
 
 
-def reduce_dir(directory: str, window_s: float) -> Optional[dict]:
+def reduce_dir(directory: str, window_s: float,
+               kernels: Sequence[str]) -> Optional[dict]:
+    """``reduce`` of the newest trace under ``directory``, timing the op
+    base names in ``kernels`` per program."""
     path = find_xplane(directory)
     if path is None:
         return None
-    return reduce(load(path), window_s)
+    return reduce(load(path), window_s, kernels)
