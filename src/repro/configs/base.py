@@ -61,6 +61,17 @@ class ModelConfig:
     # --- attention options -------------------------------------------------
     qk_norm: bool = False
     rope_theta: float = 10000.0
+    # YaRN context extension (DeepSeek-V2's ``rope_scaling`` of type
+    # "yarn"); a factor of 1.0 leaves the plain rotary embedding. The
+    # rotary frequencies blend interpolated and original ones between the
+    # correction dims of beta_fast / beta_slow rotations, and attention
+    # scales its logits by mscale(factor, mscale_all_dim)^2.
+    rope_scaling_factor: float = 1.0
+    rope_original_max_positions: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
     sliding_window: Optional[int] = None  # native SWA (e.g. mixtral)
     # Window applied only for the long_500k decode shape (beyond-paper
     # rolling-buffer variant that makes dense archs sub-quadratic).
@@ -79,9 +90,21 @@ class ModelConfig:
     num_experts_per_tok: int = 0
     num_shared_experts: int = 0
     moe_d_ff: int = 0  # per-expert hidden dim
-    # capacity = cf * T * top_k / E. Production configs use 1.25 (tokens may
-    # drop, Switch-style); smoke configs use a no-drop factor so the
-    # decode==full consistency invariant is exact.
+    # leading layers with a dense MLP of width d_ff before the MoE layers
+    # (DeepSeek's first_k_dense_replace)
+    first_k_dense: int = 0
+    # renormalise the top-k router weights to sum to one (Mixtral); False
+    # keeps the softmax probabilities over all experts (DeepSeek-V2)
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
+    # routed experts held on this chip: experts [0, experts_held) of the
+    # router's num_experts (the chip's expert-parallel share); 0 = all
+    experts_held: int = 0
+    # capacity = cf * T * top_k / E of the capacity dispatch
+    # (``layers.moe_layer``), which only the sharded launch path runs
+    # (GSPMD shards its dispatch over the expert axis). Production configs
+    # use 1.25 (tokens may drop, Switch-style). Serving and single-device
+    # training run the dropless layer (``layers.moe_dropless``).
     moe_capacity_factor: float = 1.25
 
     # --- SSM (Mamba2 / SSD) ---------------------------------------------------
@@ -131,11 +154,26 @@ class ModelConfig:
         return self.num_experts > 0
 
     @property
+    def num_experts_held(self) -> int:
+        return self.experts_held or self.num_experts
+
+    @property
+    def num_moe_layers(self) -> int:
+        return self.num_layers - self.first_k_dense if self.uses_moe else 0
+
+    @property
+    def latent_width(self) -> int:
+        """Width of a latent (MLA) pool entry: the compressed latent and
+        the decoupled rope key, zero-padded to the 128 lanes the chip
+        tiles a minor dimension by (HBM holds a 576-wide row as 640
+        anyway; stored so, a page's DMA is tile-aligned)."""
+        return -(-(self.kv_lora_rank + self.qk_rope_head_dim) // 128) * 128
+
+    @property
     def kv_cache_dims_per_token(self) -> int:
         """Per-layer per-token cache scalar count (drives block bytes)."""
         if self.use_mla:
-            # MLA caches the compressed latent + decoupled rope key.
-            return self.kv_lora_rank + self.qk_rope_head_dim
+            return self.latent_width
         if self.arch_type == "ssm":
             return 0
         return 2 * self.num_kv_heads * self.head_dim

@@ -19,9 +19,13 @@ _ARCH_MODULES: Dict[str, str] = {
     "seamless-m4t-large-v2": "repro.configs.seamless_m4t_large_v2",
     # The paper's own evaluation model (extra, beyond the 10 assigned).
     "qwen3-4b-thinking": "repro.configs.qwen3_4b_thinking",
+    # Served on the chip by the benchmark (extra): latent attention,
+    # shared and routed experts.
+    "deepseek-v2-lite": "repro.configs.deepseek_v2_lite",
 }
 
-ASSIGNED_ARCHS = tuple(k for k in _ARCH_MODULES if k != "qwen3-4b-thinking")
+_EXTRA_ARCHS = ("qwen3-4b-thinking", "deepseek-v2-lite")
+ASSIGNED_ARCHS = tuple(k for k in _ARCH_MODULES if k not in _EXTRA_ARCHS)
 ALL_ARCHS = tuple(_ARCH_MODULES)
 
 

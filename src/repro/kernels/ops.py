@@ -11,6 +11,7 @@ from typing import Optional
 
 import jax
 
+from repro.kernels import expert_ffn as _experts
 from repro.kernels import flash_attention as _flash
 from repro.kernels import paged_attention as _paged
 from repro.kernels import ssd_scan as _ssd
@@ -47,6 +48,19 @@ def paged_attention_prefill(q, k_pool, v_pool, block_tables, prefix_lens,
         q, k_pool, v_pool, block_tables, prefix_lens, num_valid,
         own_k, own_v, scale=scale, window=window,
         k_scale=k_scale, v_scale=v_scale, interpret=_interpret())
+
+
+def paged_latent_attention(q, pool, layer, block_tables, positions, own, *,
+                           scale: float, window: int, out_dim: int):
+    return _paged.paged_latent_attention(
+        q, pool, layer, block_tables, positions, own, scale=scale,
+        window=window, out_dim=out_dim, interpret=_interpret())
+
+
+def expert_ffn(x, w_gate, w_up, w_down, layer, tile_expert, n_real, *,
+               tile: int):
+    return _experts.expert_ffn(x, w_gate, w_up, w_down, layer, tile_expert,
+                               n_real, tile=tile, interpret=_interpret())
 
 
 def paged_attention_sharded(mesh, q, k_pool, v_pool, block_tables,

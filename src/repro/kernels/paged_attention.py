@@ -335,3 +335,148 @@ def paged_attention_prefill(q: jax.Array, k_pool: jax.Array,
                          k_scale=k_scale, v_scale=v_scale)
     return out.reshape(B, KVH, C, G, hd).transpose(0, 2, 1, 3, 4) \
         .reshape(B, C, H, hd)
+
+
+# ---------------------------------------------------------------------------
+# latent face: absorbed MLA decode over the latent pool
+# ---------------------------------------------------------------------------
+
+LATENT_PAGES_PER_STEP = 8     # pages per DMA step (128 tokens at page 16)
+
+
+def _latent_kernel(layer_ref, bt_ref, pos_ref, q_ref, own_ref, pool_ref,
+                   o_ref, buf, sem, m_s, l_s, acc_s, *, scale: float,
+                   page: int, num_pages: int, pps: int, window: int,
+                   out_dim: int):
+    """One grid step per lane ``b``: all H heads of the lane at once.
+
+    The lane's pooled tokens are ``min(pos, window)`` slots (minus the
+    slot the current token will overwrite once the window has rolled);
+    its own entry starts the online softmax, then its live pages arrive
+    ``pps`` at a time, one DMA each into a double buffer, while the
+    previous chunk is computed on. A chunk's scores are one dot of the
+    [H, E] query against the [pps * page, E] entries (E = kv_lora + rope,
+    the rope part included), its values one dot of the probabilities
+    against the same entries; the last E - out_dim columns are dropped at
+    the end."""
+    b = pl.program_id(0)
+    layer = layer_ref[0]
+    pos = pos_ref[b]
+    n = jnp.minimum(pos, window)
+    skip = jnp.where(pos >= window, pos % window, -1)
+    f32 = jnp.float32
+    q = q_ref[0]                                   # [H, E]
+    rows = pps * page
+
+    @pl.when(b == 0)
+    def _clear():
+        # pages past a lane's last one are never fetched into a chunk:
+        # their rows keep earlier pool content (masked, but finite)
+        buf[...] = jnp.zeros_like(buf)
+
+    own = own_ref[0].astype(f32)                   # [1, E]
+    m_s[...] = jnp.sum(q.astype(f32) * own, axis=-1, keepdims=True) * scale
+    l_s[...] = jnp.ones_like(l_s)
+    acc_s[...] = jnp.broadcast_to(own, acc_s.shape)
+
+    hi = jnp.minimum((n + page - 1) // page, num_pages)
+    chunks = (hi + pps - 1) // pps
+
+    def copy(c, j, slot):
+        block_id = bt_ref[b, c * pps + j]
+        return pltpu.make_async_copy(
+            pool_ref.at[layer, block_id], buf.at[slot, pl.ds(j * page, page)],
+            sem.at[slot])
+
+    def fetch(c, slot, op):
+        for j in range(pps):
+            @pl.when(c * pps + j < hi)
+            def _():
+                cp = copy(c, j, slot)
+                cp.start() if op == "start" else cp.wait()
+
+    @pl.when(chunks > 0)
+    def _first():
+        fetch(0, 0, "start")
+
+    def chunk_step(c, carry):
+        slot = c % 2
+
+        @pl.when(c + 1 < chunks)
+        def _next():
+            fetch(c + 1, 1 - slot, "start")
+
+        fetch(c, slot, "wait")
+        k = buf[slot]                                  # [rows, E]
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=f32) * scale        # [H, rows]
+        at = c * rows + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        mask = (at < n) & (at != skip)
+        s = jnp.where(mask, s, NEG_INF)
+        m_prev = m_s[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+        alpha = jnp.exp(m_prev - m_new)
+        acc_s[...] = acc_s[...] * alpha + jax.lax.dot_general(
+            p.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+            preferred_element_type=f32)
+        l_s[...] = alpha * l_s[...] + jnp.sum(p, axis=-1, keepdims=True)
+        m_s[...] = m_new
+        return carry
+
+    jax.lax.fori_loop(0, chunks, chunk_step, 0)
+    o_ref[0] = (acc_s[:, :out_dim] / l_s[...]).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "window", "out_dim",
+                                             "pages_per_step", "interpret"))
+def paged_latent_attention(q: jax.Array, pool: jax.Array, layer: jax.Array,
+                           block_tables: jax.Array, positions: jax.Array,
+                           own: jax.Array, *, scale: float, window: int,
+                           out_dim: int,
+                           pages_per_step: int = LATENT_PAGES_PER_STEP,
+                           interpret: bool = False) -> jax.Array:
+    """Latent decode: q [B, H, E] absorbed queries ([q_nope W_uk | q_rope],
+    E = kv_lora + rope); pool [La, NB, page, E], the whole latent pool in
+    HBM, read at ``layer`` ([] or [1] int32); block_tables [B, bp];
+    positions [B], the current token's position (the pool holds the
+    lane's earlier tokens at slot ``position % window``); own [B, E], the
+    current token's entry. Returns [B, H, out_dim], the probabilities
+    times the entries' first ``out_dim`` columns (the latent)."""
+    B, H, E = q.shape
+    page = pool.shape[2]
+    bp = block_tables.shape[1]
+    pps = max(1, min(pages_per_step, bp))
+    layer = jnp.reshape(layer, (1,)).astype(jnp.int32)
+    kernel = functools.partial(
+        _latent_kernel, scale=scale, page=page, num_pages=bp, pps=pps,
+        window=window, out_dim=out_dim)
+
+    def lane(shape):
+        return pl.BlockSpec((1, *shape), lambda b, *_: (b, 0, 0))
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(B,),
+        in_specs=[lane((H, E)), lane((1, E)),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=lane((H, out_dim)),
+        scratch_shapes=[pltpu.VMEM((2, pps * page, E), pool.dtype),
+                        pltpu.SemaphoreType.DMA((2,)),
+                        pltpu.VMEM((H, 1), jnp.float32),
+                        pltpu.VMEM((H, 1), jnp.float32),
+                        pltpu.VMEM((H, E), jnp.float32)],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, H, out_dim), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            # the scratch buffer is cleared at lane 0 only: lanes run in
+            # order on one core
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="paged_latent_attention",
+    )(layer, block_tables, positions.astype(jnp.int32), q,
+      own[:, None, :].astype(q.dtype), pool)

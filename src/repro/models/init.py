@@ -54,16 +54,20 @@ def _mla_params(key, cfg: ModelConfig, stack=()) -> dict:
     L, QL = cfg.kv_lora_rank, cfg.q_lora_rank
     ks = jax.random.split(key, 6)
     out_scale = 1.0 / math.sqrt(H * vd) / math.sqrt(2 * cfg.num_layers)
-    return {
-        "wq_a": _dense(ks[0], D, *stack, D, QL),
-        "wq_b": _dense(ks[1], QL, *stack, QL, H * (nd + rd)),
+    p = {
         "wkv_a": _dense(ks[2], D, *stack, D, L + rd),
         "wk_b": _dense(ks[3], L, *stack, L, H * nd),
         "wv_b": _dense(ks[4], L, *stack, L, H * vd),
         "wo": _dense(ks[5], H * vd, *stack, H * vd, D, scale=out_scale),
-        "q_a_norm": jnp.ones((*stack, QL), DTYPE),
         "kv_a_norm": jnp.ones((*stack, L), DTYPE),
     }
+    if QL:
+        p["wq_a"] = _dense(ks[0], D, *stack, D, QL)
+        p["wq_b"] = _dense(ks[1], QL, *stack, QL, H * (nd + rd))
+        p["q_a_norm"] = jnp.ones((*stack, QL), DTYPE)
+    else:
+        p["wq"] = _dense(ks[0], D, *stack, D, H * (nd + rd))
+    return p
 
 
 def _mlp_params(key, cfg: ModelConfig, d_ff=None, stack=()) -> dict:
@@ -79,15 +83,18 @@ def _mlp_params(key, cfg: ModelConfig, d_ff=None, stack=()) -> dict:
 
 
 def _moe_params(key, cfg: ModelConfig, stack=()) -> dict:
+    """The router over all ``num_experts``; the routed experts this chip
+    holds (``num_experts_held``)."""
     D, E, F = cfg.d_model, cfg.num_experts, cfg.moe_d_ff
+    Eh = cfg.num_experts_held
     ks = jax.random.split(key, 5)
     down_scale = 1.0 / math.sqrt(F) / math.sqrt(2 * cfg.num_layers)
     p = {
         "router": _dense(ks[0], D, *stack, D, E),
         "experts": {
-            "w_gate": _dense(ks[1], D, *stack, E, D, F),
-            "w_up": _dense(ks[2], D, *stack, E, D, F),
-            "w_down": _dense(ks[3], F, *stack, E, F, D, scale=down_scale),
+            "w_gate": _dense(ks[1], D, *stack, Eh, D, F),
+            "w_up": _dense(ks[2], D, *stack, Eh, D, F),
+            "w_down": _dense(ks[3], F, *stack, Eh, F, D, scale=down_scale),
         },
     }
     if cfg.num_shared_experts:
@@ -119,7 +126,8 @@ def _mamba_params(key, cfg: ModelConfig, stack=()) -> dict:
     }
 
 
-def _decoder_layer(key, cfg: ModelConfig, stack=(), cross=False) -> dict:
+def _decoder_layer(key, cfg: ModelConfig, stack=(), cross=False,
+                   moe=None) -> dict:
     ks = jax.random.split(key, 4)
     if cfg.use_mla:
         attn = _mla_params(ks[0], cfg, stack)
@@ -127,7 +135,7 @@ def _decoder_layer(key, cfg: ModelConfig, stack=(), cross=False) -> dict:
         attn = _attn_params(ks[0], cfg, stack)
     p = {"ln1": jnp.ones((*stack, cfg.d_model), DTYPE), "attn": attn,
          "ln2": jnp.ones((*stack, cfg.d_model), DTYPE)}
-    if cfg.uses_moe:
+    if cfg.uses_moe if moe is None else moe:
         p["moe"] = _moe_params(ks[1], cfg, stack)
     else:
         p["mlp"] = _mlp_params(ks[1], cfg, stack=stack)
@@ -173,8 +181,11 @@ def init_params(cfg: ModelConfig, rng: jax.Array) -> dict:
         params["encoder_norm"] = jnp.ones((D,), DTYPE)
         params["layers"] = _decoder_layer(ks[4], cfg, (Ld,), cross=True)
     else:  # dense / moe / vlm
-        L = cfg.num_layers
-        params["layers"] = _decoder_layer(ks[2], cfg, (L,))
+        L, K = cfg.num_layers, cfg.first_k_dense
+        if K:   # leading dense layers, then the MoE layers
+            params["dense_layers"] = _decoder_layer(ks[5], cfg, (K,),
+                                                    moe=False)
+        params["layers"] = _decoder_layer(ks[2], cfg, (L - K,))
     return params
 
 

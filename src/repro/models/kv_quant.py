@@ -122,17 +122,18 @@ def resolve_kv_dtype(setting: str, cfg: ModelConfig,
                      chunk_supported: bool) -> str:
     """Validate a ``kv_dtype`` setting against the model architecture.
 
-    Quantized pools cover the dense-GQA paged-attention paths (the same
-    family the chunked-prefill scatter serves); MLA / SSM / hybrid /
-    encoder-decoder caches keep full-precision pools and raise here, so
-    users hit one clear error at engine construction instead of a shape
-    error mid-serve. ``f32``/``bf16`` only re-type the pools and are
-    accepted everywhere.
+    Quantized pools cover the block-addressed GQA pool (``k_pool``/
+    ``v_pool``, which carry the per-slot scale arrays, on the paths the
+    chunked-prefill scatter serves); the latent pool of MLA has no scales,
+    and SSM / hybrid / encoder-decoder caches keep full-precision pools:
+    they raise here, so users hit one clear error at engine construction
+    instead of a shape error mid-serve. ``f32``/``bf16`` only re-type the
+    pools and are accepted everywhere.
     """
     if setting not in KV_DTYPES:
         raise ValueError(
             f"unknown kv_dtype {setting!r}; expected one of {KV_DTYPES}")
-    if is_quantized(setting) and not chunk_supported:
+    if is_quantized(setting) and (cfg.use_mla or not chunk_supported):
         raise NotImplementedError(
             f"kv_dtype={setting!r} is only supported for dense GQA "
             f"architectures (arch_type={cfg.arch_type!r}, "

@@ -11,6 +11,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.models import kv_quant as KQ
@@ -27,11 +28,56 @@ def rms_norm(x: jax.Array, weight: jax.Array, eps: float = 1e-6) -> jax.Array:
     return (out * weight.astype(jnp.float32)).astype(x.dtype)
 
 
-def rope_cos_sin(positions: jax.Array, dim: int, theta: float):
-    """positions [...]; returns cos/sin [..., dim/2] in fp32."""
-    inv_freq = 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+def rope_cos_sin(positions: jax.Array, dim: int, theta: float,
+                 yarn: Optional[ModelConfig] = None):
+    """positions [...]; returns cos/sin [..., dim/2] in fp32.
+
+    ``yarn`` (a config whose ``rope_scaling_factor`` exceeds 1) takes the
+    YaRN frequencies of :func:`yarn_inv_freq` and scales cos/sin by
+    mscale(factor, mscale) / mscale(factor, mscale_all_dim), as
+    DeepSeek-V2's rotary embedding does."""
+    if yarn is None or yarn.rope_scaling_factor <= 1.0:
+        inv_freq = 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32)
+                                    / dim))
+        angles = positions.astype(jnp.float32)[..., None] * inv_freq
+        return jnp.cos(angles), jnp.sin(angles)
+    inv_freq = jnp.asarray(yarn_inv_freq(yarn, dim), jnp.float32)
     angles = positions.astype(jnp.float32)[..., None] * inv_freq
-    return jnp.cos(angles), jnp.sin(angles)
+    f = yarn.rope_scaling_factor
+    attn = (yarn_mscale(f, yarn.yarn_mscale)
+            / yarn_mscale(f, yarn.yarn_mscale_all_dim))
+    return jnp.cos(angles) * attn, jnp.sin(angles) * attn
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature: 0.1 * mscale * ln(factor) + 1."""
+    if factor <= 1.0:
+        return 1.0
+    return 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(cfg: ModelConfig, dim: int) -> np.ndarray:
+    """YaRN inverse frequencies [dim/2]: the original ones (1/theta^(2i/d))
+    below the correction dim of ``yarn_beta_fast`` rotations over the
+    original context, the interpolated ones (divided by the factor) above
+    that of ``yarn_beta_slow``, and a linear ramp between."""
+    theta, f = cfg.rope_theta, cfg.rope_scaling_factor
+    orig = cfg.rope_original_max_positions
+    idx = np.arange(0, dim, 2, dtype=np.float64) / dim
+    extra = 1.0 / theta ** idx
+    inter = 1.0 / (f * theta ** idx)
+
+    def corr_dim(rotations):
+        return (dim * math.log(orig / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(corr_dim(cfg.yarn_beta_fast)), 0)
+    high = min(math.ceil(corr_dim(cfg.yarn_beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(dim // 2) - low) / (high - low), 0.0, 1.0)
+    keep = 1.0 - ramp            # 1: original frequency, 0: interpolated
+    return inter * (1.0 - keep) + extra * keep
 
 
 def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
@@ -624,43 +670,22 @@ def mla_attention_decode_contiguous(p: dict, cfg: ModelConfig, x: jax.Array,
                                     positions: jax.Array, kv_cache: jax.Array
                                     ) -> tuple:
     """Absorbed MLA decode over a contiguous latent cache [B, cap, L+rd]."""
-    B, _, D = x.shape
-    H = cfg.num_heads
-    nd, rd, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
-    L = cfg.kv_lora_rank
+    xt = x[:, 0]
     cap = kv_cache.shape[1]
-
-    q_lat = rms_norm(x @ p["wq_a"], p["q_a_norm"], cfg.norm_eps)
-    q = (q_lat @ p["wq_b"]).reshape(B, H, nd + rd)
-    q_nope, q_rope = q[..., :nd], q[..., nd:]
-
-    kv_a = (x @ p["wkv_a"]).reshape(B, L + rd)
-    c_kv = rms_norm(kv_a[..., :L], p["kv_a_norm"], cfg.norm_eps)
-    k_rope = kv_a[..., L:]
-    cos, sin = rope_cos_sin(positions, rd, cfg.rope_theta)
-    q_rope = apply_rope(q_rope, cos[:, None], sin[:, None])
-    k_rope = apply_rope(k_rope[:, None, :], cos[:, None], sin[:, None])[:, 0]
-
-    slot = positions % cap
-    entry = jnp.concatenate([c_kv, k_rope], axis=-1)
-    kv_cache = contiguous_kv_update(kv_cache, slot, entry)
+    cos, sin = _mla_rope(cfg, positions)
+    q_nope, q_rope = _mla_q(p, cfg, xt, cos, sin)
+    entry = _mla_entry(p, cfg, xt, cos, sin)
+    kv_cache = contiguous_kv_update(kv_cache, positions % cap, entry)
     lens = jnp.minimum(positions + 1, cap)
-
-    wk_b = p["wk_b"].reshape(L, H, nd)
-    q_abs = jnp.einsum("bhd,lhd->bhl", q_nope, wk_b)
-    c_seq, kr_seq = kv_cache[..., :L], kv_cache[..., L:]
-    scores = (jnp.einsum("bhl,bsl->bhs", q_abs, c_seq,
-                         preferred_element_type=jnp.float32)
-              + jnp.einsum("bhd,bsd->bhs", q_rope, kr_seq,
-                           preferred_element_type=jnp.float32))
-    scores *= 1.0 / math.sqrt(nd + rd)
+    q = _mla_absorbed_q(p, cfg, q_nope, q_rope)
+    scores = jnp.einsum("bhe,bse->bhs", q, kv_cache,
+                        preferred_element_type=jnp.float32)
+    scores *= mla_softmax_scale(cfg)
     valid = jnp.arange(cap)[None, :] < lens[:, None]
-    scores = jnp.where(valid[:, None, :], scores, -1e30)
-    probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
-    o_lat = jnp.einsum("bhs,bsl->bhl", probs, c_seq)
-    wv_b = p["wv_b"].reshape(L, H, vd)
-    out = jnp.einsum("bhl,lhd->bhd", o_lat, wv_b).reshape(B, 1, H * vd)
-    return out @ p["wo"], kv_cache
+    o_lat = _masked_softmax_pv(scores, valid[:, None, :],
+                               kv_cache[..., :cfg.kv_lora_rank],
+                               "bhs,bsl->bhl").astype(x.dtype)
+    return _mla_out(p, cfg, o_lat)[:, None], kv_cache
 
 
 # ---------------------------------------------------------------------------
@@ -695,38 +720,90 @@ def cross_kv(p: dict, cfg: ModelConfig, enc_out: jax.Array):
 # MLA (DeepSeek-V2 multi-head latent attention)
 # ---------------------------------------------------------------------------
 
+def mla_softmax_scale(cfg: ModelConfig) -> float:
+    """1/sqrt(nope + rope), times mscale(factor, mscale_all_dim)^2 under
+    YaRN (DeepSeek-V2 scales its softmax by the square)."""
+    scale = 1.0 / math.sqrt(cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)
+    if cfg.rope_scaling_factor > 1.0 and cfg.yarn_mscale_all_dim:
+        m = yarn_mscale(cfg.rope_scaling_factor, cfg.yarn_mscale_all_dim)
+        scale *= m * m
+    return scale
+
+
+def _mla_rope(cfg: ModelConfig, positions: jax.Array):
+    return rope_cos_sin(positions, cfg.qk_rope_head_dim, cfg.rope_theta,
+                        yarn=cfg)
+
+
+def _mla_q(p: dict, cfg: ModelConfig, x: jax.Array, cos, sin) -> tuple:
+    """(q_nope [..., H, nope], roped q_rope [..., H, rope]) of x [..., D]:
+    through the low-rank pair ``wq_a``/``wq_b`` with q-LoRA, one ``wq``
+    [D, H*(nope+rope)] without. cos/sin [..., rope/2]."""
+    H, nd, rd = cfg.num_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    if cfg.q_lora_rank:
+        q = rms_norm(x @ p["wq_a"], p["q_a_norm"], cfg.norm_eps) @ p["wq_b"]
+    else:
+        q = x @ p["wq"]
+    q = q.reshape(*x.shape[:-1], H, nd + rd)
+    q_rope = apply_rope(q[..., nd:], cos[..., None, :], sin[..., None, :])
+    return q[..., :nd], q_rope
+
+
+def _mla_entry(p: dict, cfg: ModelConfig, x: jax.Array, cos, sin):
+    """Latent cache entries [..., kv_lora + rope] of x [..., D]: the
+    normed compressed latent, then the roped key shared by every head."""
+    L = cfg.kv_lora_rank
+    kv_a = x @ p["wkv_a"]
+    c_kv = rms_norm(kv_a[..., :L], p["kv_a_norm"], cfg.norm_eps)
+    k_rope = apply_rope(kv_a[..., None, L:], cos[..., None, :],
+                        sin[..., None, :])[..., 0, :]
+    return jnp.concatenate([c_kv, k_rope], axis=-1)
+
+
+def _mla_absorbed_q(p: dict, cfg: ModelConfig, q_nope, q_rope):
+    """[q_nope W_uk | q_rope] [..., H, kv_lora + rope]: the query that
+    scores a whole latent entry in one dot."""
+    L, H, nd = cfg.kv_lora_rank, cfg.num_heads, cfg.qk_nope_head_dim
+    q_abs = jnp.einsum("...hd,lhd->...hl", q_nope,
+                       p["wk_b"].reshape(L, H, nd))
+    return jnp.concatenate([q_abs, q_rope.astype(q_abs.dtype)], axis=-1)
+
+
+def _mla_out(p: dict, cfg: ModelConfig, o_lat: jax.Array, act_spec=None):
+    """Latent attention output [..., H, kv_lora] -> [..., D] through
+    W_uv and wo."""
+    L, H, vd = cfg.kv_lora_rank, cfg.num_heads, cfg.v_head_dim
+    out = jnp.einsum("...hl,lhd->...hd", o_lat, p["wv_b"].reshape(L, H, vd))
+    out = out.reshape(*out.shape[:-2], H * vd)
+    if act_spec is not None:  # exact TP (see swiglu): gather heads first
+        out = jax.lax.with_sharding_constraint(out, act_spec)
+    return out @ p["wo"]
+
+
 def mla_attention_full(p: dict, cfg: ModelConfig, x: jax.Array,
                        positions: jax.Array, return_kv: bool = False,
                        act_spec=None):
-    """Full-sequence MLA (train / prefill).
+    """Full-sequence MLA (train / prefill), per-head keys and values.
 
-    p: {wq_a [D, q_lora], wq_b [q_lora, H*(nope+rope)],
-        wkv_a [D, kv_lora + rope], wk_b [kv_lora, H*nope],
-        wv_b [kv_lora, H*v], wo [H*v, D],
-        q_a_norm [q_lora], kv_a_norm [kv_lora]}
+    p: {wq [D, H*(nope+rope)] | wq_a [D, q_lora], wq_b [q_lora,
+        H*(nope+rope)], q_a_norm [q_lora]; wkv_a [D, kv_lora + rope],
+        kv_a_norm [kv_lora], wk_b [kv_lora, H*nope], wv_b [kv_lora, H*v],
+        wo [H*v, D]}
     """
     B, S, D = x.shape
     H = cfg.num_heads
     nd, rd, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
     if act_spec is not None:  # exact TP: see swiglu
         x = jax.lax.with_sharding_constraint(x, act_spec)
-    q_lat = rms_norm(x @ p["wq_a"], p["q_a_norm"], cfg.norm_eps)
-    q = (q_lat @ p["wq_b"]).reshape(B, S, H, nd + rd)
-    q_nope, q_rope = q[..., :nd], q[..., nd:]
-
-    kv_a = x @ p["wkv_a"]  # [B,S,kv_lora+rd]
-    c_kv = rms_norm(kv_a[..., :cfg.kv_lora_rank], p["kv_a_norm"], cfg.norm_eps)
-    k_rope = kv_a[..., cfg.kv_lora_rank:]  # [B,S,rd] shared across heads
-
-    cos, sin = rope_cos_sin(positions, rd, cfg.rope_theta)
-    q_rope = apply_rope(q_rope, cos[:, :, None], sin[:, :, None])
-    k_rope = apply_rope(k_rope[:, :, None, :], cos[:, :, None],
-                        sin[:, :, None])[:, :, 0]
+    cos, sin = _mla_rope(cfg, positions)
+    q_nope, q_rope = _mla_q(p, cfg, x, cos, sin)
+    entry = _mla_entry(p, cfg, x, cos, sin)            # [B,S,kv_lora+rd]
+    c_kv, k_rope = entry[..., :cfg.kv_lora_rank], entry[..., cfg.kv_lora_rank:]
 
     k_nope = (c_kv @ p["wk_b"]).reshape(B, S, H, nd)
     v = (c_kv @ p["wv_b"]).reshape(B, S, H, vd)
 
-    scale = 1.0 / math.sqrt(nd + rd)
+    scale = mla_softmax_scale(cfg)
     if S > CHUNKED_ATTN_THRESHOLD:
         # fold the shared roped key into per-head keys and run the
         # chunked online-softmax path (what lowers at 32k)
@@ -745,86 +822,141 @@ def mla_attention_full(p: dict, cfg: ModelConfig, x: jax.Array,
                             preferred_element_type=jnp.float32)
         scores = (s_nope + s_rope) * scale
         mask = _attn_mask(S, S, None)
-        scores = jnp.where(mask[None, None], scores, -1e30)
-        probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
-        out = jnp.einsum("bhqs,bshd->bqhd", probs, v).reshape(B, S, H * vd)
+        out = _masked_softmax_pv(scores, mask[None, None], v,
+                                 "bhqs,bshd->bqhd")
+        out = out.astype(x.dtype).reshape(B, S, H * vd)
     if act_spec is not None:  # exact TP (see swiglu): gather heads first
         out = jax.lax.with_sharding_constraint(out, act_spec)
     out = out @ p["wo"]
     if return_kv:
         # paged-cache entry = [compressed latent | roped shared key]
-        return out, jnp.concatenate([c_kv, k_rope], axis=-1)
+        return out, entry
     return out
 
 
-def mla_attention_decode(p: dict, cfg: ModelConfig, x: jax.Array,
-                         positions: jax.Array, cache: dict) -> tuple:
-    """Absorbed-weight MLA decode over the paged latent cache.
+def pad_last(x: jax.Array, width: int) -> jax.Array:
+    """x zero-padded on its last dimension to ``width``."""
+    extra = width - x.shape[-1]
+    if not extra:
+        return x
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, extra)])
 
-    Cache stores [latent (kv_lora) | roped k (rd)] per token:
-    kv_pool [N_blocks, bs, kv_lora + rd].
 
-    The absorption trick (beyond-paper TPU adaptation, also used by
-    DeepSeek's own inference): fold W_uk into q and W_uv into the output
-    so attention runs directly in the latent space — no per-head K/V
-    materialisation at 32k/500k context.
+def latent_attention(q: jax.Array, pool: jax.Array, layer, block_tables,
+                     positions: jax.Array, entry: jax.Array, *,
+                     scale: float, window_len: int, out_dim: int,
+                     use_kernel: bool = False) -> jax.Array:
+    """Absorbed decode attention over the latent pool plus each lane's own
+    entry.
+
+    q [B, H, E] (E = kv_lora + rope, ``_mla_absorbed_q``); pool [La, NB,
+    bs, W], the whole latent pool (W = ``cfg.latent_width`` >= E, the
+    entries zero-padded), read at ``layer``; it holds each lane's
+    earlier tokens at slot ``position % window_len`` (``positions`` [B]
+    is the current token's position); entry [B, E] is the current token's
+    own entry, attended exactly. With ``positions >= window_len`` the
+    window has rolled: the slot the current token will overwrite holds a
+    token that left the window and is masked. Returns the latent output
+    [B, H, out_dim] (the first ``out_dim`` columns of the probabilities
+    times the entries).
+
+    ``use_kernel`` runs the Pallas latent face
+    (``kernels.paged_latent_attention``): only live pages are read, several
+    per DMA; the dense path gathers each lane's whole window.
     """
-    B, _, D = x.shape
-    H = cfg.num_heads
-    nd, rd, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
-    L = cfg.kv_lora_rank
+    q, entry = pad_last(q, pool.shape[-1]), pad_last(entry, pool.shape[-1])
+    if use_kernel:
+        from repro.kernels import ops as kops
+        return kops.paged_latent_attention(
+            q, pool, layer, block_tables, positions, entry, scale=scale,
+            window=window_len, out_dim=out_dim)
+    B, H, E = q.shape
+    bs, bp = pool.shape[2], block_tables.shape[1]
+    seq = pool[layer][block_tables].reshape(B, bp * bs, E)
+    keys = jnp.concatenate([seq, entry[:, None].astype(seq.dtype)], axis=1)
+    slot = jnp.arange(bp * bs)[None, :]
+    n = jnp.minimum(positions, window_len)[:, None]
+    skip = jnp.where(positions >= window_len, positions % window_len,
+                     -1)[:, None]
+    valid = jnp.concatenate(
+        [(slot < n) & (slot != skip), jnp.ones((B, 1), bool)], axis=1)
+    scores = jnp.einsum("bhe,bse->bhs", q, keys,
+                        preferred_element_type=jnp.float32) * scale
+    out = _masked_softmax_pv(scores, valid[:, None, :], keys[..., :out_dim],
+                             "bhs,bsl->bhl")
+    return out.astype(q.dtype)
 
-    act_spec = cache.get("act_spec")
+
+def mla_attention_decode(p: dict, cfg: ModelConfig, x: jax.Array,
+                         positions: jax.Array, pool: jax.Array, layer,
+                         block_tables: jax.Array, window_len: int,
+                         use_kernel: bool = False, act_spec=None) -> tuple:
+    """Absorbed-weight MLA decode of one token per lane.
+
+    x [B, 1, D]; pool [La, NB, bs, kv_lora + rope], the whole latent pool
+    (read only, at ``layer``). Returns (out [B, 1, D], entry [B, kv_lora +
+    rope]): the caller writes the entry into the pool after the layer
+    scan, so no layer copies the pool.
+
+    The absorption trick (also used by DeepSeek's own inference): fold
+    W_uk into the query and W_uv into the output so attention runs in the
+    latent space, no per-head K/V materialised.
+    """
     if act_spec is not None:  # exact TP: see swiglu
         x = jax.lax.with_sharding_constraint(x, act_spec)
-    q_lat = rms_norm(x @ p["wq_a"], p["q_a_norm"], cfg.norm_eps)
-    q = (q_lat @ p["wq_b"]).reshape(B, H, nd + rd)
-    q_nope, q_rope = q[..., :nd], q[..., nd:]
+    xt = x[:, 0]
+    cos, sin = _mla_rope(cfg, positions)               # [B, rd/2]
+    q_nope, q_rope = _mla_q(p, cfg, xt, cos, sin)
+    entry = _mla_entry(p, cfg, xt, cos, sin)           # [B, L+rd]
+    q = _mla_absorbed_q(p, cfg, q_nope, q_rope)        # [B, H, L+rd]
+    o_lat = latent_attention(q, pool, layer, block_tables, positions, entry,
+                             scale=mla_softmax_scale(cfg),
+                             window_len=window_len, out_dim=cfg.kv_lora_rank,
+                             use_kernel=use_kernel)
+    return _mla_out(p, cfg, o_lat, act_spec)[:, None], entry
 
-    kv_a = (x @ p["wkv_a"]).reshape(B, L + rd)
-    c_kv = rms_norm(kv_a[..., :L], p["kv_a_norm"], cfg.norm_eps)
-    k_rope = kv_a[..., L:]
-    cos, sin = rope_cos_sin(positions, rd, cfg.rope_theta)
-    q_rope = apply_rope(q_rope, cos[:, None], sin[:, None])
-    k_rope = apply_rope(k_rope[:, None, :], cos[:, None], sin[:, None])[:, 0]
 
-    window_len = cache["window_len"]
-    slot = jnp.where(window_len > 0, positions % window_len, positions)
-    new_entry = jnp.concatenate([c_kv, k_rope], axis=-1)  # [B, L+rd]
-    pool = paged_kv_update(cache["kv_pool"][:, :, None, :],
-                           cache["block_tables"], slot,
-                           new_entry[:, None, :])[:, :, 0, :]
-    if cache.get("pool_spec") is not None:
-        pool = jax.lax.with_sharding_constraint(pool, cache["pool_spec"])
-    new_lens = jnp.minimum(positions + 1, window_len) if window_len > 0 \
-        else positions + 1
+def mla_attention_prefill_chunk(p: dict, cfg: ModelConfig, x: jax.Array,
+                                positions: jax.Array, valid: jax.Array,
+                                pool: jax.Array, layer,
+                                block_tables: jax.Array) -> tuple:
+    """Prefill one chunk of a prompt against the latent pool, absorbed.
 
-    # absorb W_uk: q_abs [B,H,L]
-    wk_b = p["wk_b"].reshape(L, H, nd)
-    q_abs = jnp.einsum("bhd,lhd->bhl", q_nope, wk_b)
-
-    bs = pool.shape[1]
-    bp = cache["block_tables"].shape[1]
-    entries = pool[cache["block_tables"]].reshape(B, bp * bs, L + rd)
-    c_seq, kr_seq = entries[..., :L], entries[..., L:]
-    scores = (jnp.einsum("bhl,bsl->bhs", q_abs, c_seq,
-                         preferred_element_type=jnp.float32)
-              + jnp.einsum("bhd,bsd->bhs", q_rope, kr_seq,
-                           preferred_element_type=jnp.float32))
-    scores *= 1.0 / math.sqrt(nd + rd)
-    valid = jnp.arange(bp * bs)[None, :] < new_lens[:, None]
-    scores = jnp.where(valid[:, None, :], scores, -1e30)
-    probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
-    o_lat = jnp.einsum("bhs,bsl->bhl", probs, c_seq)  # [B,H,L]
-    wv_b = p["wv_b"].reshape(L, H, vd)
-    out = jnp.einsum("bhl,lhd->bhd", o_lat, wv_b).reshape(B, 1, H * vd)
-    if act_spec is not None:  # exact TP (see swiglu): gather heads first
-        out = jax.lax.with_sharding_constraint(out, act_spec)
-    return out @ p["wo"], pool
+    x [B, C, D]; positions [B, C] (contiguous, the chunk right-padded,
+    ``valid`` [B, C] marking real tokens); pool [La, NB, bs, E] holds
+    the earlier chunks at slot == position (prefill never wraps). The
+    chunk's queries attend over the pooled prefix and, causally, over the
+    chunk's own exact entries. Returns (out [B, C, D], entries [B, C, E]),
+    which the caller writes into the pool after the layer scan."""
+    B, C, D = x.shape
+    L = cfg.kv_lora_rank
+    bs, bp = pool.shape[2], block_tables.shape[1]
+    cos, sin = _mla_rope(cfg, positions)               # [B, C, rd/2]
+    q_nope, q_rope = _mla_q(p, cfg, x, cos, sin)
+    entry = _mla_entry(p, cfg, x, cos, sin)            # [B, C, E]
+    E = pool.shape[-1]
+    q = pad_last(_mla_absorbed_q(p, cfg, q_nope, q_rope), E)   # [B,C,H,E]
+    seq = pool[layer][block_tables].reshape(B, bp * bs, E)
+    keys = jnp.concatenate([seq, pad_last(entry, E).astype(seq.dtype)],
+                           axis=1)
+    pool_pos = jnp.arange(bp * bs)[None, None, :]
+    pool_mask = pool_pos < positions[:, :1, None]      # earlier chunks
+    own_mask = ((positions[:, None, :] <= positions[:, :, None])
+                & valid[:, None, :])
+    mask = jnp.concatenate(
+        [jnp.broadcast_to(pool_mask, (B, C, bp * bs)), own_mask], axis=2)
+    mask &= valid[:, :, None]       # padded queries -> zeros
+    scores = jnp.einsum("bche,bse->bhcs", q, keys,
+                        preferred_element_type=jnp.float32)
+    scores *= mla_softmax_scale(cfg)
+    o_lat = _masked_softmax_pv(scores, mask[:, None], keys[..., :L],
+                               "bhcs,bsl->bchl").astype(x.dtype)
+    return _mla_out(p, cfg, o_lat), entry
 
 
 # ---------------------------------------------------------------------------
-# MoE (top-k router, capacity-based dispatch/combine)
+# MoE, capacity dispatch (the sharded launch path: GSPMD shards the
+# dispatch einsums over the expert axis)
 # ---------------------------------------------------------------------------
 
 MOE_CHUNK_TOKENS = 524288
@@ -957,6 +1089,130 @@ def moe_layer(p: dict, cfg: ModelConfig, x: jax.Array,
     if cfg.num_shared_experts:
         out = out + swiglu(p["shared"], xt)
     return out.reshape(B, S, D), aux_loss
+
+
+# ---------------------------------------------------------------------------
+# MoE, dropless, over this chip's share of the experts (serving path)
+# ---------------------------------------------------------------------------
+
+EXPERT_TILE = 16      # rows of (token, expert) pairs per expert-FFN tile
+
+
+def route(router_w: jax.Array, cfg: ModelConfig, xt: jax.Array) -> tuple:
+    """Top-k routing over all ``cfg.num_experts`` router outputs: softmax
+    in f32, greedy top-k, renormalised only with ``norm_topk_prob``, then
+    scaled by ``routed_scaling_factor``. xt [T, D] -> (gates [T, E],
+    weights [T, K] f32, experts [T, K])."""
+    gates = jax.nn.softmax((xt @ router_w).astype(jnp.float32), axis=-1)
+    weights, sel = jax.lax.top_k(gates, cfg.num_experts_per_tok)
+    if cfg.norm_topk_prob:
+        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-9)
+    return gates, weights * cfg.routed_scaling_factor, sel
+
+
+def _pair_layout(local: jax.Array, n_held: int, tile: int) -> tuple:
+    """Rows of the (token, expert) pairs that fall on held experts, grouped
+    by expert, each group starting on a multiple of ``tile``.
+
+    local [P] holds each pair's expert index among the held ones (-1 or
+    >= n_held: not held). Returns (dest [P]: the pair's row, or the row
+    count for a pair not held; counts [n_held]; tile_expert [n_tiles]: the
+    expert of each row tile, tiles past the last real one repeating its
+    expert; n_real [1]: tiles that hold a pair) for a static row count of
+    n_tiles * tile."""
+    P = local.shape[0]
+    held = (local >= 0) & (local < n_held)
+    onehot = (local[:, None] == jnp.arange(n_held)[None, :]) & held[:, None]
+    onehot = onehot.astype(jnp.int32)
+    counts = jnp.sum(onehot, axis=0)                         # [Eh]
+    rank = jnp.sum((jnp.cumsum(onehot, axis=0) - onehot) * onehot, axis=1)
+    tiles = -(-counts // tile)                               # per expert
+    tile_end = jnp.cumsum(tiles)
+    start = (tile_end - tiles) * tile
+    n_tiles = -(-(P + n_held * (tile - 1)) // tile)
+    rows = n_tiles * tile
+    e = jnp.clip(local, 0, n_held - 1)
+    dest = jnp.where(held, start[e] + rank, rows)
+    n_real = tile_end[-1]
+    t = jnp.minimum(jnp.arange(n_tiles), jnp.maximum(n_real - 1, 0))
+    tile_expert = jnp.minimum(
+        jnp.sum(tile_end[None, :] <= t[:, None], axis=1), n_held - 1)
+    return dest, counts, tile_expert.astype(jnp.int32), n_real[None]
+
+
+def expert_ffn_xla(xs: jax.Array, experts: dict, layer, counts: jax.Array
+                   ) -> jax.Array:
+    """SwiGLU of each held expert over its rows of ``xs`` [M, D] (grouped
+    by expert, ``counts`` rows each, rows past them zero), as ragged
+    matmuls; experts {w_gate/w_up [Lm, Eh, D, F], w_down [Lm, Eh, F, D]}
+    read at ``layer``."""
+    w = {k: jax.lax.dynamic_index_in_dim(v, layer, keepdims=False)
+         for k, v in experts.items()}
+    g = jax.lax.ragged_dot(xs, w["w_gate"], counts)
+    u = jax.lax.ragged_dot(xs, w["w_up"], counts)
+    act = jax.nn.silu(g.astype(jnp.float32)).astype(xs.dtype) * u
+    return jax.lax.ragged_dot(act, w["w_down"], counts)
+
+
+def moe_dropless(p: dict, cfg: ModelConfig, x: jax.Array, *,
+                 experts: Optional[dict] = None, layer=0,
+                 first_expert: int = 0, use_kernel: bool = False,
+                 tile: int = EXPERT_TILE) -> tuple:
+    """Dropless MoE over the experts this chip holds.
+
+    The router scores all ``cfg.num_experts`` experts (``route``); of the
+    top-k pairs, those that fall on the held experts
+    [first_expert, first_expert + num_experts_held) are computed, each
+    held expert's rows in one grouped matmul, and summed with their
+    router weights; the shared experts are added once. What the experts
+    held elsewhere would add is left out (on one chip the layer runs
+    without its all-to-all). No pair is dropped.
+
+    p: {router [D, E], experts {w_gate/w_up [Eh, D, F], w_down [Eh, F, D]}
+    (or, stacked over the MoE layers, passed as ``experts`` with the
+    layer's index ``layer``), (optional) shared {w_gate, w_up, w_down}}.
+    ``use_kernel`` runs the held experts' FFN as the Pallas ``expert_ffn``
+    kernel (each held expert's weights read once per call), else as XLA
+    ragged matmuls.
+
+    Returns (out [B, S, D], aux_loss, load [B*S, Eh]: each token's pairs
+    on each held expert).
+    """
+    B, S, D = x.shape
+    E, K, Eh = cfg.num_experts, cfg.num_experts_per_tok, cfg.num_experts_held
+    T = B * S
+    xt = x.reshape(T, D)
+    if experts is None:
+        experts = {k: v[None] for k, v in p["experts"].items()}
+    gates, weights, sel = route(p["router"], cfg, xt)
+    me = jnp.mean(gates, axis=0)
+    ce = jnp.mean(jnp.sum(jax.nn.one_hot(sel, E, dtype=jnp.float32), axis=1),
+                  axis=0)
+    aux_loss = E * jnp.sum(me * ce)
+
+    local = (sel - first_expert).reshape(T * K)
+    dest, counts, tile_expert, n_real = _pair_layout(
+        local, Eh, tile if use_kernel else 1)
+    rows = tile_expert.shape[0] * (tile if use_kernel else 1)
+    token = jnp.arange(T * K) // K
+    xs = jnp.zeros((rows, D), x.dtype).at[dest].set(xt[token], mode="drop")
+    if use_kernel:
+        from repro.kernels import ops as kops
+        ys = kops.expert_ffn(xs, experts["w_gate"], experts["w_up"],
+                             experts["w_down"], layer, tile_expert, n_real,
+                             tile=tile)
+    else:
+        ys = expert_ffn_xla(xs, experts, layer, counts)
+    y_pair = ys.at[dest].get(mode="fill", fill_value=0)          # [T*K, D]
+    w_pair = weights.reshape(T * K, 1)
+    out = jnp.sum((y_pair.astype(jnp.float32) * w_pair).reshape(T, K, D),
+                  axis=1).astype(x.dtype)
+    if cfg.num_shared_experts:
+        out = out + swiglu(p["shared"], xt)
+    held = (local >= 0) & (local < Eh)
+    load = jnp.sum(jax.nn.one_hot(jnp.where(held, local, Eh), Eh,
+                                  dtype=jnp.int32).reshape(T, K, Eh), axis=1)
+    return out.reshape(B, S, D), aux_loss, load
 
 
 # ---------------------------------------------------------------------------

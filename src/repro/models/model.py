@@ -12,6 +12,7 @@ Three entry points, shared by training, serving and the dry-run launcher:
 """
 from __future__ import annotations
 
+from functools import partial
 from typing import Optional
 
 import jax
@@ -33,6 +34,28 @@ def _wsc_kv(kv_specs, key, x):
     if kv_specs is None or key not in kv_specs or x is None:
         return x
     return jax.lax.with_sharding_constraint(x, kv_specs[key])
+
+
+def _stacks(params, cfg) -> list:
+    """The decoder's layer stacks in order, as (params stacked over the
+    stack's layers, index of its first layer, MoE?): the leading dense
+    layers (``cfg.first_k_dense``, a dense MLP of width ``d_ff``), then
+    the rest."""
+    out = []
+    if cfg.first_k_dense:
+        out.append((params["dense_layers"], 0, False))
+    out.append((params["layers"], cfg.first_k_dense, cfg.uses_moe))
+    return out
+
+
+def _split_experts(stack):
+    """A MoE stack's layer params without the routed experts, and the
+    experts stacked over its layers: the scans carry the first as xs and
+    hand the second whole, with a layer index, to the expert FFN, so no
+    layer's expert weights are sliced out (copied) to feed a kernel."""
+    moe = dict(stack["moe"])
+    experts = moe.pop("experts")
+    return {**stack, "moe": moe}, experts
 
 
 def _logits(params, cfg, h):
@@ -177,7 +200,7 @@ def forward_full(params: dict, cfg: ModelConfig, tokens: jax.Array,
             enc_out = encode(params, cfg, encoder_embeds,
                              remat=remat, act_spec=act_spec)
 
-        def body(carry, lp):
+        def body(carry, lp, is_moe=cfg.uses_moe):
             h, aux = carry
             a_in = L.rms_norm(h, lp["ln1"], cfg.norm_eps)
             if cfg.use_mla and return_kv:
@@ -210,20 +233,30 @@ def forward_full(params: dict, cfg: ModelConfig, tokens: jax.Array,
                     *L.cross_kv(lp["cross"], cfg, enc_out))
                 h = h + c
             m_in = L.rms_norm(h, lp["ln2"], cfg.norm_eps)
-            if cfg.uses_moe:
+            if is_moe and kv_specs is not None:
+                # the sharded launch: GSPMD shards the capacity dispatch
+                # over the expert axis
                 m, aux_l = L.moe_layer(
                     lp["moe"], cfg, m_in,
-                    expert_weight_spec=None if kv_specs is None
-                    else kv_specs.get("moe_experts"))
+                    expert_weight_spec=kv_specs.get("moe_experts"))
+                aux = aux + aux_l
+            elif is_moe:
+                m, aux_l, _ = L.moe_dropless(lp["moe"], cfg, m_in)
                 aux = aux + aux_l
             else:
                 m = L.swiglu(lp["mlp"], m_in, act_spec=tp_act_spec)
             return (wsc(h + m), aux), kv
 
-        if remat:
-            body = jax.checkpoint(body)
-        (h, aux_total), kvs = jax.lax.scan(
-            body, (h, aux_total), params["layers"])
+        kv_parts = []
+        for stack, _, is_moe in _stacks(params, cfg):
+            step = partial(body, is_moe=is_moe)
+            if remat:
+                step = jax.checkpoint(step)
+            (h, aux_total), kvs = jax.lax.scan(step, (h, aux_total), stack)
+            kv_parts.append(kvs)
+        if len(kv_parts) > 1 and return_kv:
+            kvs = jax.tree.map(lambda *xs: jnp.concatenate(xs, axis=0),
+                               *kv_parts)
 
     hidden = L.rms_norm(h, params["final_norm"], cfg.norm_eps)
     logits = _logits(params, cfg, hidden)
@@ -324,39 +357,41 @@ def decode_step(params: dict, cfg: ModelConfig, tokens: jax.Array,
         new_cache["conv_state"] = ncv.reshape(-1, *ncv.shape[2:])
         new_cache["k_pool"], new_cache["v_pool"] = nk, nv
 
+    elif cfg.use_mla:
+        def attend(p, a_in, i):
+            return L.mla_attention_decode(
+                p, cfg, a_in, positions, cache["kv_pool"], i,
+                cache["block_tables"], window_len, use_kernel=use_kernel,
+                act_spec=act)
+
+        h, entries, load = _latent_layers(params, cfg, h, attend,
+                                          use_kernel, act, wsc_h)
+        slot = positions % window_len if window_len > 0 else positions
+        new_cache["kv_pool"] = _write_latent(
+            cfg, cache["kv_pool"], cache["block_tables"], slot, entries)
+
     else:  # dense / moe / vlm / enc-dec decoder
+        if cfg.first_k_dense:
+            raise NotImplementedError(
+                "leading dense layers are served on the latent (MLA) path")
         has_cross = cfg.is_encoder_decoder
         quant = "k_scale" in cache  # quantized paged pool (int8/fp8)
 
         def body(h, xs):
-            if cfg.use_mla:
-                lp, kv_pool = xs[0], xs[1]
-                cross = xs[2:] if has_cross else None
-            else:
-                lp, k_pool, v_pool = xs[0], xs[1], xs[2]
-                ksc, vsc = (xs[3], xs[4]) if quant else (None, None)
-                cross = xs[3 + 2 * quant:] if has_cross else None
+            lp, k_pool, v_pool = xs[0], xs[1], xs[2]
+            ksc, vsc = (xs[3], xs[4]) if quant else (None, None)
+            cross = xs[3 + 2 * quant:] if has_cross else None
             a_in = L.rms_norm(h, lp["ln1"], cfg.norm_eps)
-            if cfg.use_mla:
-                a, new_pool = L.mla_attention_decode(
-                    lp["attn"], cfg, a_in, positions,
-                    {"kv_pool": kv_pool,
-                     "block_tables": cache["block_tables"],
-                     "window_len": window_len,
-                     "pool_spec": layer_pool.get("kv_pool"),
-                     "act_spec": act})
-                out_pools = (new_pool,)
-            else:
-                a, out_pools = L.gqa_attention_decode(
-                    lp["attn"], cfg, a_in, positions,
-                    {"k_pool": k_pool, "v_pool": v_pool,
-                     "k_scale": ksc, "v_scale": vsc,
-                     "block_tables": cache["block_tables"],
-                     "window_len": window_len, "use_kernel": use_kernel,
-                     "kernel_mesh": kmesh,
-                     "pool_spec": layer_pool.get("k_pool"),
-                     "scale_spec": layer_pool.get("k_scale"),
-                     "act_spec": act}, 0)
+            a, out_pools = L.gqa_attention_decode(
+                lp["attn"], cfg, a_in, positions,
+                {"k_pool": k_pool, "v_pool": v_pool,
+                 "k_scale": ksc, "v_scale": vsc,
+                 "block_tables": cache["block_tables"],
+                 "window_len": window_len, "use_kernel": use_kernel,
+                 "kernel_mesh": kmesh,
+                 "pool_spec": layer_pool.get("k_pool"),
+                 "scale_spec": layer_pool.get("k_scale"),
+                 "act_spec": act}, 0)
             h = h + a
             if has_cross:
                 ck, cv = cross
@@ -366,33 +401,92 @@ def decode_step(params: dict, cfg: ModelConfig, tokens: jax.Array,
                 h = h + c
             m_in = L.rms_norm(h, lp["ln2"], cfg.norm_eps)
             if cfg.uses_moe:
-                m, _ = L.moe_layer(lp["moe"], cfg, m_in)
+                m, _, load = L.moe_dropless(lp["moe"], cfg, m_in,
+                                            use_kernel=use_kernel)
+                out_pools = out_pools + (load,)
             else:
                 m = L.swiglu(lp["mlp"], m_in, act_spec=act)
             return wsc_h(h + m), out_pools
 
-        if cfg.use_mla:
-            xs = (params["layers"], cache["kv_pool"])
-        else:
-            xs = (params["layers"], cache["k_pool"], cache["v_pool"])
-            if quant:
-                xs = xs + (cache["k_scale"], cache["v_scale"])
+        xs = (params["layers"], cache["k_pool"], cache["v_pool"])
+        if quant:
+            xs = xs + (cache["k_scale"], cache["v_scale"])
         if has_cross:
             xs = xs + (cache["cross_k"], cache["cross_v"])
         h, out_pools = jax.lax.scan(body, h, xs)
-        if cfg.use_mla:
-            new_cache["kv_pool"] = out_pools[0]
-        else:
-            new_cache["k_pool"], new_cache["v_pool"] = out_pools[:2]
-            if quant:
-                new_cache["k_scale"], new_cache["v_scale"] = out_pools[2:4]
+        new_cache["k_pool"], new_cache["v_pool"] = out_pools[:2]
+        if quant:
+            new_cache["k_scale"], new_cache["v_scale"] = out_pools[2:4]
+        if cfg.uses_moe:
+            load = jnp.sum(out_pools[-1], axis=0)
 
     hidden = L.rms_norm(h[:, 0], params["final_norm"], cfg.norm_eps)  # [B,D]
     if shard_specs is not None:
         hidden = jax.lax.with_sharding_constraint(hidden,
                                                   shard_specs["hidden"])
     logits = _logits(params, cfg, hidden)
-    return {"logits": logits, "hidden": hidden, "cache": new_cache}
+    out = {"logits": logits, "hidden": hidden, "cache": new_cache}
+    if cfg.uses_moe:
+        out["expert_load"] = load
+    return out
+
+
+def _latent_layers(params, cfg, h, attend, use_kernel, act=None,
+                   wsc_h=None):
+    """The decoder's layers over the latent pool (read only by
+    ``attend(attn_params, a_in, layer) -> (a, entries)``), stack by stack.
+    Returns (h, entries stacked over all layers, expert load [T, Eh]
+    summed over the MoE layers, or None)."""
+    entries, loads = [], []
+    for stack, first, is_moe in _stacks(params, cfg):
+        n = jax.tree.leaves(stack)[0].shape[0]
+        experts = None
+        if is_moe:
+            stack, experts = _split_experts(stack)
+
+        def body(h, xs, is_moe=is_moe, first=first, experts=experts):
+            lp, i = xs
+            a, entry = attend(lp["attn"], L.rms_norm(h, lp["ln1"],
+                                                     cfg.norm_eps), i)
+            h = h + a
+            m_in = L.rms_norm(h, lp["ln2"], cfg.norm_eps)
+            if is_moe:
+                m, _, load = L.moe_dropless(
+                    lp["moe"], cfg, m_in, experts=experts, layer=i - first,
+                    use_kernel=use_kernel)
+            else:
+                m, load = L.swiglu(lp["mlp"], m_in, act_spec=act), None
+            h = h + m
+            return (h if wsc_h is None else wsc_h(h)), (entry, load)
+
+        h, (ent, load) = jax.lax.scan(
+            body, h, (stack, first + jnp.arange(n, dtype=jnp.int32)))
+        entries.append(ent)
+        if load is not None:
+            loads.append(jnp.sum(load, axis=0))
+    entries = jnp.concatenate(entries, axis=0) if len(entries) > 1 \
+        else entries[0]
+    return h, entries, (sum(loads) if loads else None)
+
+
+def _write_latent(cfg, pool, block_tables, slot, entries, valid=None):
+    """Write each layer's new latent entries into the pool in place:
+    pool [La, NB, bs, E]; slot [B] or [B, C] (cache slots); entries
+    [La, B, E] or [La, B, C, E]; entries not ``valid`` go to the scratch
+    block 0."""
+    bs = cfg.kv_block_size
+    idx = slot // bs
+    if idx.ndim == 1:
+        idx = idx[:, None]
+    bid = jnp.take_along_axis(block_tables, idx, axis=1).reshape(slot.shape)
+    if valid is not None:
+        bid = jnp.where(valid, bid, 0)
+    entries = L.pad_last(entries, pool.shape[-1]).astype(pool.dtype)
+    # one (layer, block, offset) index per entry, so each update is one
+    # row of the pool's minor dimension and the pool keeps its layout
+    la = entries.shape[0]
+    layer = jnp.arange(la, dtype=bid.dtype).reshape((la,) + (1,) * bid.ndim)
+    return pool.at[layer, bid[None], (slot % bs)[None]].set(entries)
 
 
 # ---------------------------------------------------------------------------
@@ -448,7 +542,9 @@ def multi_decode_step(params: dict, cfg: ModelConfig, tokens: jax.Array,
     positions [B], cache} where ``token_valid`` marks a contiguous
     emitted prefix per lane and ``score_valid`` the step-boundary subset.
     ``cache`` excludes ``block_tables`` (the in-scan copy is scratch-
-    masked and not meaningful to the caller).
+    masked and not meaningful to the caller). A MoE model adds
+    ``expert_load`` [Eh]: the running lanes' routed pairs that fell on
+    each held expert, summed over the MoE layers and the iterations.
 
     ``shard_specs`` (launch/shardings.serving_step_shardings) runs the
     scan over a device mesh: the scan carry (pools, per-lane state,
@@ -473,7 +569,10 @@ def multi_decode_step(params: dict, cfg: ModelConfig, tokens: jax.Array,
         key, k = xs
         c = dict(pools)
         c["block_tables"] = bt
-        out = decode_step(params, cfg, ct[:, None], pos, c,
+        # a frozen lane of the latent path decodes at position 0: its
+        # pooled prefix is empty, so the kernel reads none of its pages
+        pos_in = jnp.where(active, pos, 0) if cfg.use_mla else pos
+        out = decode_step(params, cfg, ct[:, None], pos_in, c,
                           window_len=window_len, use_kernel=use_kernel,
                           shard_specs=shard_specs)
         nt, conf = sample_fn(key, out["logits"])
@@ -499,18 +598,24 @@ def multi_decode_step(params: dict, cfg: ModelConfig, tokens: jax.Array,
                 k_: jax.lax.with_sharding_constraint(
                     v, shard_specs["pools"][k_])
                 for k_, v in new_pools.items()}
-        return ((new_pools, nt, new_pos, new_active, new_bt),
-                (nt, conf, scores, token_valid, score_valid))
+        ys = (nt, conf, scores, token_valid, score_valid)
+        if cfg.uses_moe:     # the running lanes' pairs per held expert
+            ys = ys + (jnp.sum(jnp.where(active[:, None],
+                                         out["expert_load"], 0), axis=0),)
+        return (new_pools, nt, new_pos, new_active, new_bt), ys
 
     carry0 = (pools, tokens, positions, active0, bt0)
     (pools, ct, pos, _, _), ys = jax.lax.scan(
         body, carry0, (rng_keys, jnp.arange(horizon)))
-    toks, confs, scores, tok_valid, score_valid = ys
-    return {
+    toks, confs, scores, tok_valid, score_valid = ys[:5]
+    out = {
         "tokens": toks.T, "confidences": confs.T, "scores": scores.T,
         "token_valid": tok_valid.T, "score_valid": score_valid.T,
         "final_tokens": ct, "positions": pos, "cache": pools,
     }
+    if cfg.uses_moe:
+        out["expert_load"] = jnp.sum(ys[5], axis=0)
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -519,11 +624,11 @@ def multi_decode_step(params: dict, cfg: ModelConfig, tokens: jax.Array,
 
 def supports_chunked_prefill(cfg: ModelConfig) -> bool:
     """Chunked prefill is implemented for the paged-attention dense/MoE
-    stack. Recurrent (SSM/hybrid) archs would need the mixer to accept an
-    initial state per chunk, MLA a latent-pool chunk path, and enc-dec the
-    cross cache — those fall back to one-shot prefill in the engine."""
+    stack, GQA and latent (MLA). Recurrent (SSM/hybrid) archs would need
+    the mixer to accept an initial state per chunk, and enc-dec the cross
+    cache — those fall back to one-shot prefill in the engine."""
     return (cfg.arch_type not in ("ssm", "hybrid")
-            and not cfg.use_mla and not cfg.is_encoder_decoder)
+            and not cfg.is_encoder_decoder)
 
 
 def prefill_chunk_step(params: dict, cfg: ModelConfig, tokens: jax.Array,
@@ -543,6 +648,9 @@ def prefill_chunk_step(params: dict, cfg: ModelConfig, tokens: jax.Array,
     [B, KVH, G, C, bp*bs + C] score tensor per layer.
     """
     assert supports_chunked_prefill(cfg), cfg.arch_type
+    if cfg.use_mla:
+        return _latent_chunk_step(params, cfg, tokens, positions, valid,
+                                  cache, window_len, use_kernel)
     new_cache = dict(cache)
     window = cfg.sliding_window
     quant = "k_scale" in cache  # quantized paged pool (int8/fp8)
@@ -577,11 +685,15 @@ def prefill_chunk_step(params: dict, cfg: ModelConfig, tokens: jax.Array,
         h = h + a
         m_in = L.rms_norm(h, lp["ln2"], cfg.norm_eps)
         if cfg.uses_moe:
-            m, _ = L.moe_layer(lp["moe"], cfg, m_in)
+            m, _, _ = L.moe_dropless(lp["moe"], cfg, m_in,
+                                     use_kernel=use_kernel)
         else:
             m = L.swiglu(lp["mlp"], m_in, act_spec=act)
         return wsc_h(h + m), pools
 
+    if cfg.first_k_dense:
+        raise NotImplementedError(
+            "leading dense layers are served on the latent (MLA) path")
     xs = (params["layers"], cache["k_pool"], cache["v_pool"])
     if quant:
         xs = xs + (cache["k_scale"], cache["v_scale"])
@@ -589,6 +701,29 @@ def prefill_chunk_step(params: dict, cfg: ModelConfig, tokens: jax.Array,
     new_cache["k_pool"], new_cache["v_pool"] = pools[:2]
     if quant:
         new_cache["k_scale"], new_cache["v_scale"] = pools[2:4]
+    hidden = L.rms_norm(h, params["final_norm"], cfg.norm_eps)
+    logits = _logits(params, cfg, hidden)
+    return {"logits": logits, "hidden": hidden, "cache": new_cache}
+
+
+def _latent_chunk_step(params, cfg, tokens, positions, valid, cache,
+                       window_len, use_kernel):
+    """``prefill_chunk_step`` over the latent pool: each layer attends
+    absorbed over the pooled prefix (read only) and the chunk's own
+    entries; the chunk's entries are written into the pool in place after
+    the layer scan. The attention runs in XLA; the routed experts take the
+    Pallas ``expert_ffn`` with ``use_kernel``."""
+    pool, bt = cache["kv_pool"], cache["block_tables"]
+
+    def attend(p, a_in, i):
+        return L.mla_attention_prefill_chunk(p, cfg, a_in, positions, valid,
+                                             pool, i, bt)
+
+    h, entries, _ = _latent_layers(params, cfg, _embed(params, cfg, tokens),
+                                   attend, use_kernel)
+    new_cache = dict(cache)
+    new_cache["kv_pool"] = _write_latent(cfg, pool, bt, positions % window_len,
+                                         entries, valid)
     hidden = L.rms_norm(h, params["final_norm"], cfg.norm_eps)
     logits = _logits(params, cfg, hidden)
     return {"logits": logits, "hidden": hidden, "cache": new_cache}
@@ -663,50 +798,57 @@ def serve_decode_step(params: dict, cfg: ModelConfig, tokens: jax.Array,
     else:  # dense / moe / vlm / enc-dec decoder
         has_cross = cfg.is_encoder_decoder
 
-        def body(h, xs):
-            if cfg.use_mla:
-                lp, kv_cache = xs[0], xs[1]
-                cross = xs[2:] if has_cross else None
-            else:
-                lp, kc, vc = xs[0], xs[1], xs[2]
-                cross = xs[3:] if has_cross else None
-            a_in = L.rms_norm(h, lp["ln1"], cfg.norm_eps)
-            if cfg.use_mla:
-                a, new_kv = L.mla_attention_decode_contiguous(
-                    lp["attn"], cfg, a_in, positions, kv_cache)
-                out_caches = (_wsc_kv(kv_specs, "mla", new_kv),)
-            else:
-                a, nk, nv = L.gqa_attention_decode_contiguous(
-                    lp["attn"], cfg, a_in, positions, kc, vc,
-                    window_len=kc.shape[1])
-                out_caches = (_wsc_kv(kv_specs, "kv", nk),
-                              _wsc_kv(kv_specs, "kv", nv))
-            h = h + a
-            if has_cross:
-                ck, cv = cross
-                c = L.cross_attention(
-                    lp["cross"], cfg,
-                    L.rms_norm(h, lp["ln_cross"], cfg.norm_eps), ck, cv)
-                h = h + c
-            m_in = L.rms_norm(h, lp["ln2"], cfg.norm_eps)
-            if cfg.uses_moe:
-                m, _ = L.moe_layer(
-                    lp["moe"], cfg, m_in,
-                    expert_weight_spec=None if kv_specs is None
-                    else kv_specs.get("moe_experts"),
-                    ex_in_spec=None if kv_specs is None
-                    else kv_specs.get("moe_ex_in"))
-            else:
-                m = L.swiglu(lp["mlp"], m_in)
-            return h + m, out_caches
+        def make_body(is_moe):
+            def body(h, xs):
+                if cfg.use_mla:
+                    lp, kv_cache = xs[0], xs[1]
+                    cross = xs[2:] if has_cross else None
+                else:
+                    lp, kc, vc = xs[0], xs[1], xs[2]
+                    cross = xs[3:] if has_cross else None
+                a_in = L.rms_norm(h, lp["ln1"], cfg.norm_eps)
+                if cfg.use_mla:
+                    a, new_kv = L.mla_attention_decode_contiguous(
+                        lp["attn"], cfg, a_in, positions, kv_cache)
+                    out_caches = (_wsc_kv(kv_specs, "mla", new_kv),)
+                else:
+                    a, nk, nv = L.gqa_attention_decode_contiguous(
+                        lp["attn"], cfg, a_in, positions, kc, vc,
+                        window_len=kc.shape[1])
+                    out_caches = (_wsc_kv(kv_specs, "kv", nk),
+                                  _wsc_kv(kv_specs, "kv", nv))
+                h = h + a
+                if has_cross:
+                    ck, cv = cross
+                    c = L.cross_attention(
+                        lp["cross"], cfg,
+                        L.rms_norm(h, lp["ln_cross"], cfg.norm_eps), ck, cv)
+                    h = h + c
+                m_in = L.rms_norm(h, lp["ln2"], cfg.norm_eps)
+                if is_moe and kv_specs is not None:
+                    m, _ = L.moe_layer(
+                        lp["moe"], cfg, m_in,
+                        expert_weight_spec=kv_specs.get("moe_experts"),
+                        ex_in_spec=kv_specs.get("moe_ex_in"))
+                elif is_moe:
+                    m, _, _ = L.moe_dropless(lp["moe"], cfg, m_in)
+                else:
+                    m = L.swiglu(lp["mlp"], m_in)
+                return h + m, out_caches
+            return body
 
-        if cfg.use_mla:
-            xs = (params["layers"], cache["kv_cache"])
-        else:
-            xs = (params["layers"], cache["k_cache"], cache["v_cache"])
+        keys = ("kv_cache",) if cfg.use_mla else ("k_cache", "v_cache")
         if has_cross:
-            xs = xs + (cache["cross_k"], cache["cross_v"])
-        h, out_caches = jax.lax.scan(body, h, xs)
+            keys = keys + ("cross_k", "cross_v")
+        outs = []
+        for stack, first, is_moe in _stacks(params, cfg):
+            n = jax.tree.leaves(stack)[0].shape[0]
+            xs = (stack,) + tuple(cache[k][first:first + n] for k in keys)
+            h, out_caches = jax.lax.scan(make_body(is_moe), h, xs)
+            outs.append(out_caches)
+        out_caches = jax.tree.map(
+            lambda *xs: jnp.concatenate(xs, axis=0), *outs) \
+            if len(outs) > 1 else outs[0]
         if cfg.use_mla:
             new_cache["kv_cache"] = out_caches[0]
         else:
@@ -745,7 +887,7 @@ def init_decode_cache(cfg: ModelConfig, batch: int, capacity: int,
             # MLA latent pool: f32/bf16 only (quantized dtypes are
             # rejected upstream by kv_quant.resolve_kv_dtype)
             cache["kv_pool"] = jnp.zeros(
-                (la, nb, bs, cfg.kv_lora_rank + cfg.qk_rope_head_dim),
+                (la, nb, bs, cfg.latent_width),
                 pool_dt if kv_dtype in ("f32", "bf16") else dt)
         else:
             cache["k_pool"] = jnp.zeros(
@@ -823,8 +965,11 @@ def write_prefill_kv(cfg: ModelConfig, cache: dict, kvs,
         cache["v_pool"] = scatter(cache["v_pool"], v)
         return cache
     if cfg.use_mla:
-        cache["kv_pool"] = scatter(cache["kv_pool"][:, :, :, None, :],
-                                   kvs[:, :, :, None, :])[:, :, :, 0, :]
+        # kvs [La, B, S, E]: every layer's entries of a position in one
+        # scatter, in place
+        Bn, S = kvs.shape[1], kvs.shape[2]
+        pos = jnp.broadcast_to(jnp.arange(S)[None], (Bn, S))
+        cache["kv_pool"] = _write_latent(cfg, cache["kv_pool"], bt, pos, kvs)
         return cache
     k, v = kvs
     if "k_scale" in cache:

@@ -33,9 +33,9 @@ long prompt no longer stalls the running decode batch; with an idle
 batch the prefill runs to completion immediately. A tick's combined
 prefill work is budgeted by ``EngineConfig.max_tokens_per_step``
 (prefill chunks and decode tokens share the tick's token budget).
-Chunking applies to the shared-prefix path of paged-attention archs;
-recurrent/MLA/enc-dec archs and per-trace prefills fall back to the
-one-shot path.
+Chunking applies to the shared-prefix path of paged-attention archs
+(GQA and latent); recurrent/enc-dec archs and per-trace prefills fall
+back to the one-shot path.
 
 Prefix sharing (``EngineConfig.share_prompt_prefix``, default on): all N
 traces of a request decode from the *same* prompt, so the prompt KV is
@@ -198,9 +198,10 @@ def resolve_use_kernel(setting, cfg: ModelConfig, mesh=None) -> bool:
         raise ValueError(
             f"use_kernel must be True, False or 'auto', got {setting!r}")
     # the paged kernels cover GQA paged attention (the dense/MoE/hybrid
-    # attention layers); MLA's absorbed latent decode has no kernel path
-    covered = not cfg.use_mla
-    why = "MLA's absorbed latent decode has no Pallas kernel path"
+    # attention layers) and, through the latent face, MLA's absorbed
+    # decode on one device
+    covered = not (cfg.use_mla and mesh is not None)
+    why = "the latent (MLA) kernel face has no mesh routing"
     if covered and mesh is not None:
         model_n = mesh.shape["model"]
         covered = (cfg.num_heads % model_n == 0
@@ -647,9 +648,12 @@ class Engine:
                     use_kernel=self.use_kernel, shard_specs=ss)
                 pools = out["cache"]
                 pools.pop("block_tables", None)
-                return (out["tokens"], out["confidences"], out["scores"],
+                outs = (out["tokens"], out["confidences"], out["scores"],
                         out["token_valid"], out["score_valid"],
                         out["final_tokens"], out["positions"], pools, rng)
+                if cfg.uses_moe:   # routed pairs per held expert
+                    outs = outs + (out["expert_load"],)
+                return outs
 
             return batched_decode
 

@@ -283,7 +283,17 @@ class ServeCounters:
 
     ``compiles``/``compile_s`` are the backend compiles that ran while
     the engine served, and ``compiles_by_phase`` maps the innermost span
-    open at each to ``[count, seconds]`` (``OUTSIDE`` for none)."""
+    open at each to ``[count, seconds]`` (``OUTSIDE`` for none).
+
+    MoE configurations only (they stay 0 otherwise), from the per-held-
+    expert count each decode call returns: ``moe_pairs_routed``, every
+    routed (token, expert) pair of the decode tokens, all MoE layers (the
+    tokens times top-k times the MoE layers); ``moe_pairs_held``, those
+    that fell on the experts this chip holds; ``moe_load_max``, summed
+    over decode calls, the pairs of the call's busiest held expert. So
+    ``moe_pairs_held / moe_pairs_routed`` is the held share (the held
+    experts' share of all, under uniform routing), and ``moe_load_max``
+    over ``moe_pairs_held / experts held`` the max/mean load."""
 
     rounds: int = 0
     lane_rounds: int = 0
@@ -304,6 +314,9 @@ class ServeCounters:
     decode_tokens: int = 0
     sampled_select: int = 0
     sampled_sort: int = 0
+    moe_pairs_routed: int = 0
+    moe_pairs_held: int = 0
+    moe_load_max: int = 0
     compiles: int = 0
     compile_s: float = 0.0
     compiles_by_phase: Dict[str, list] = dataclasses.field(
@@ -328,6 +341,13 @@ class ServeCounters:
         if path != "none":
             name = f"sampled_{path}"
             setattr(self, name, getattr(self, name) + rows)
+
+    def note_moe(self, load, routed: int) -> None:
+        """One decode call's routed pairs per held expert (``load``), and
+        all its routed pairs (``routed``)."""
+        self.moe_pairs_routed += routed
+        self.moe_pairs_held += sum(load)
+        self.moe_load_max += max(load)
 
     def note_compile(self, phase: str, secs: float) -> None:
         self.compiles += 1

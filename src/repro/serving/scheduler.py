@@ -1748,14 +1748,19 @@ class SchedulerCore:
         with span("sched.decode", lanes=len(self.running), horizon=K_tick):
             out = self._launch_decode(K_tick, limits)
         (toks_d, confs_d, scores_d, tv_d, sv_d, fin_tok, fin_pos,
-         self.cache, eng._rng) = out
+         self.cache, eng._rng) = out[:9]
+        load_d = out[9:]     # MoE: routed pairs per held expert
         # single host sync per round; .tolist() batches the per-trace
         # float()/int() conversions of the old per-token loop
         with span("sched.sync"):
-            toks_h, confs_h, scores_h, tv_h, sv_h, ft_h, fp_h = (
+            toks_h, confs_h, scores_h, tv_h, sv_h, ft_h, fp_h, *load_h = (
                 x.tolist() for x in jax.device_get(
                     (toks_d, confs_d, scores_d, tv_d, sv_d,
-                     fin_tok, fin_pos)))
+                     fin_tok, fin_pos) + load_d))
+        if load_h:
+            self.counters.note_moe(
+                load_h[0], sum(map(sum, tv_h)) * eng.cfg.num_experts_per_tok
+                * eng.cfg.num_moe_layers)
         if self.plan is not None:
             # NaN injection poisons the victim lane's HOST-synced
             # confidences only — device state is untouched, so the

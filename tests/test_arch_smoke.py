@@ -55,6 +55,7 @@ def test_full_config_matches_assignment(arch):
         "mamba2-2.7b": (64, 2560, 0, 0, 0, 50280),
         "starcoder2-3b": (30, 3072, 24, 2, 12288, 49152),
         "seamless-m4t-large-v2": (24, 1024, 16, 16, 8192, 256206),
+        "deepseek-v2-lite": (27, 2048, 16, 16, 1408, 102400),
     }
     if arch not in expected:
         pytest.skip("paper-model config, not an assigned arch")
@@ -67,6 +68,13 @@ def test_full_config_matches_assignment(arch):
         assert cfg.moe_d_ff == FF
         assert cfg.num_experts == 160 and cfg.num_experts_per_tok == 6
         assert cfg.kv_lora_rank == 512 and cfg.num_shared_experts == 2
+    elif arch == "deepseek-v2-lite":
+        assert cfg.moe_d_ff == FF and cfg.d_ff == 10944
+        assert cfg.num_experts == 64 and cfg.num_experts_per_tok == 6
+        assert cfg.num_experts_held == 64 and cfg.first_k_dense == 1
+        assert cfg.kv_lora_rank == 512 and cfg.q_lora_rank == 0
+        assert cfg.num_shared_experts == 2 and not cfg.norm_topk_prob
+        assert cfg.rope_scaling_factor == 40 and cfg.yarn_mscale_all_dim == 0.707
     elif arch == "mixtral-8x7b":
         assert cfg.moe_d_ff == FF
         assert cfg.num_experts == 8 and cfg.num_experts_per_tok == 2

@@ -11,7 +11,8 @@ online-vs-flat softmax reduction order — which the bf16 activation casts
 absorb at serving scale.
 
 Also covers ``use_kernel="auto"`` resolution (kernel on TPU, dense on
-CPU, dense fallback on uncovered meshes) and the ``REPRO_USE_KERNEL``
+CPU, dense fallback on uncovered meshes; MLA takes the latent face) and
+the ``REPRO_USE_KERNEL``
 env override the CI kernel lane uses.
 
 Under a quantized pool (``REPRO_KV_DTYPE=int8``/``fp8`` — the CI
@@ -26,11 +27,12 @@ softmax when the pooled prefix holds the row max — quantization noise
 can flip near-ties, surfacing reduction-order ulps in confidences.
 """
 import dataclasses
+import types
 
 import jax
 import pytest
 
-from repro.configs.registry import serving_config
+from repro.configs.registry import get_config, serving_config
 from repro.core.pruning import make_policy
 from repro.core.scorer import init_scorer
 from repro.data.tokenizer import get_tokenizer
@@ -154,10 +156,19 @@ def test_resolve_use_kernel_auto_cpu_is_dense():
 
 
 def test_resolve_use_kernel_rejects_mla():
-    cfg = dataclasses.replace(serving_config(), use_mla=True)
-    with pytest.raises(NotImplementedError, match="MLA"):
-        resolve_use_kernel(True, cfg)
+    """MLA is rejected only on a mesh, which has no latent routing (True
+    raises, "auto" falls back); on one device it resolves to the latent
+    kernel face like GQA resolves to the paged kernel: True forces it
+    (interpret mode here), "auto" keeps the dense path on a CPU host (and
+    takes the kernel on a TPU)."""
+    cfg = get_config("deepseek-v2-lite", smoke=True)
+    assert resolve_use_kernel(True, cfg) is True
     assert resolve_use_kernel("auto", cfg) is False
+    assert resolve_use_kernel(False, cfg) is False
+    mesh = types.SimpleNamespace(shape={"data": 1, "model": 1})
+    with pytest.raises(NotImplementedError, match="latent"):
+        resolve_use_kernel(True, cfg, mesh)
+    assert resolve_use_kernel("auto", cfg, mesh) is False
 
 
 def test_resolve_use_kernel_rejects_garbage():

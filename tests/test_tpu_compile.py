@@ -1,4 +1,5 @@
-"""The main path's Pallas kernels compile for a TPU v5e at Qwen3-4B widths.
+"""The main path's Pallas kernels compile for a TPU v5e at Qwen3-4B widths,
+and the latent face and the expert FFN at DeepSeek-V2-Lite's.
 
 Interpret mode (every other kernel test) cannot see what the chip's
 compiler refuses: unaligned slices, loads from HBM refs, more VMEM than a
@@ -12,6 +13,7 @@ The topology is described inside a module-scoped fixture, never at
 import: only one process may load the TPU library at a time, and a
 fixture keeps the load in the one worker that runs this file.
 """
+import dataclasses
 import os
 
 import jax
@@ -19,8 +21,10 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs import deepseek_v2_lite
 from repro.configs.qwen3_4b_thinking import full_config
 from repro.core.scorer import SCORER_HIDDEN
+from repro.kernels import expert_ffn as ef
 from repro.kernels import paged_attention as pa
 from repro.kernels import step_score as ss
 from repro.models.init import padded_vocab
@@ -92,14 +96,50 @@ def _step_score(sds, _):
                     sds((1,), jnp.float32))
 
 
+MLA = dataclasses.replace(deepseek_v2_lite.full_config(), experts_held=16)
+
+
+def _latent(sds, _):
+    """The latent face at 128 lanes of 4,608 tokens over a 27-layer pool
+    of 6,500 pages, entries of kv_lora 512 + rope 64 padded to 640."""
+    H, W = MLA.num_heads, MLA.latent_width
+    bp = 4608 // PAGE
+    fn = jax.jit(lambda q, pool, layer, bt, pos, own:
+                 pa.paged_latent_attention(
+                     q, pool, layer, bt, pos, own, scale=0.1, window=4608,
+                     out_dim=MLA.kv_lora_rank, interpret=False))
+    return fn.lower(sds((128, H, W), jnp.bfloat16),
+                    sds((MLA.num_layers, 6500, PAGE, W), jnp.bfloat16),
+                    sds((), jnp.int32), sds((128, bp), jnp.int32),
+                    sds((128,), jnp.int32), sds((128, W), jnp.bfloat16))
+
+
+def _experts(sds, _):
+    """The expert FFN over 16 held experts of 26 MoE layers, the row
+    tiles of 128 tokens' top-6 pairs."""
+    D, F, Eh = MLA.d_model, MLA.moe_d_ff, MLA.num_experts_held
+    Lm, tile = MLA.num_moe_layers, 16
+    n_tiles = -(-(128 * 6 + Eh * (tile - 1)) // tile)
+    fn = jax.jit(lambda x, wg, wu, wd, layer, te, nr: ef.expert_ffn(
+        x, wg, wu, wd, layer, te, nr, tile=tile, interpret=False))
+    return fn.lower(sds((n_tiles * tile, D), jnp.bfloat16),
+                    sds((Lm, Eh, D, F), jnp.bfloat16),
+                    sds((Lm, Eh, D, F), jnp.bfloat16),
+                    sds((Lm, Eh, F, D), jnp.bfloat16),
+                    sds((), jnp.int32), sds((n_tiles,), jnp.int32),
+                    sds((1,), jnp.int32))
+
+
 @pytest.mark.parametrize("lower, pool_dtype", [
     (_decode, jnp.bfloat16),
     (_decode, jnp.int8),
     (_prefill, jnp.bfloat16),
     (_prefill, jnp.int8),
     (_step_score, None),
+    (_latent, jnp.bfloat16),
+    (_experts, None),
 ], ids=["decode_bf16", "decode_int8", "prefill_bf16", "prefill_int8",
-        "step_score"])
+        "step_score", "latent_decode", "expert_ffn"])
 def test_kernel_compiles_for_v5e(one_chip, lower, pool_dtype):
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
