@@ -3,10 +3,11 @@
   flash_attention         — one-shot prefill attention (blockwise
                             online softmax, SWA)
   paged_attention         — decode attention over the paged KV pool
-                            (C=1 face of the multi-query kernel; what
-                            the fused decode-horizon scan calls)
+                            (several pages per DMA step; what the fused
+                            decode-horizon scan calls)
   paged_attention_prefill — chunked prefill over pooled prefix + exact
                             own-chunk KV (the multi-query face)
+  paged_latent_attention  — MLA's absorbed decode over the latent pool
   ssd_scan                — Mamba2 SSD chunked scan (mamba2/zamba2)
   step_score              — fused STEP scorer MLP over decode hiddens
 

@@ -11,7 +11,8 @@ from hypothesis import strategies as st
 
 from repro.kernels import ops, ref
 from repro.kernels.flash_attention import flash_attention
-from repro.kernels.paged_attention import (paged_attention,
+from repro.kernels.paged_attention import (decode_pages_per_step,
+                                           paged_attention,
                                            paged_attention_prefill)
 from repro.kernels.ssd_scan import ssd_scan
 from repro.kernels.step_score import step_score
@@ -196,6 +197,95 @@ def test_paged_decode_kernel_vs_dense_property(B, KVH, G, page, bp, seed):
     np.testing.assert_allclose(np.asarray(kern, np.float32),
                                np.asarray(dense, np.float32),
                                rtol=2e-5, atol=2e-5)
+
+
+def _wide_decode_case(B, KVH, G, page, bp, seed, dtype, lens):
+    H, hd = KVH * G, 32
+    NB = B * bp + 2
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    q = jax.random.normal(ks[0], (B, H, hd), dtype)
+    k_pool = jax.random.normal(ks[1], (NB, page, KVH, hd), dtype)
+    v_pool = jax.random.normal(ks[2], (NB, page, KVH, hd), dtype)
+    bt = jax.random.permutation(ks[3], NB)[:B * bp] \
+        .reshape(B, bp).astype(jnp.int32)
+    scale = 1.0 / math.sqrt(hd)
+    kern = paged_attention(q, k_pool, v_pool, bt, lens, scale=scale,
+                           interpret=True)
+    dense = paged_attention_decode(k_pool, v_pool, q, bt, lens, scale=scale)
+    want = ref.paged_attention_ref(
+        q.astype(jnp.float32), k_pool.astype(jnp.float32),
+        v_pool.astype(jnp.float32), bt, lens, scale=scale)
+    return [np.asarray(x, np.float32) for x in (kern, dense, want)]
+
+
+@pytest.mark.parametrize("page,bp,KVH,G,dtype", [
+    (16, 9, 1, 1, jnp.float32),
+    (16, 9, 8, 4, jnp.bfloat16),
+    (16, 20, 2, 4, jnp.float32),
+    (16, 20, 8, 1, jnp.bfloat16),
+    (8, 20, 1, 4, jnp.bfloat16),
+    (8, 20, 2, 1, jnp.float32),
+    (8, 20, 8, 4, jnp.float32),
+    (8, 13, 2, 4, jnp.bfloat16),
+])
+def test_paged_decode_wide_tables(page, bp, KVH, G, dtype):
+    """Block tables wider than one decode step (a step holds
+    ``decode_pages_per_step`` pages, 128 tokens here): lengths at 0, 1,
+    each step boundary and one either side of it, and a full table, all
+    against the dense fallback and the oracle, over MQA, MHA and GQA
+    (KVH 1, 2, 8; G 1, 4) and f32 and bf16 pools."""
+    rows = decode_pages_per_step(page, bp, 1) * page
+    full = page * bp
+    cuts = [n for k in range(1, full // rows + 1)
+            for n in (k * rows - 1, k * rows, k * rows + 1) if n <= full]
+    lens = jnp.asarray([0, 1, *cuts, full], jnp.int32)
+    kern, dense, want = _wide_decode_case(len(lens), KVH, G, page, bp, 5,
+                                          dtype, lens)
+    assert np.all(kern[0] == 0.0) and np.all(np.isfinite(kern))
+    np.testing.assert_allclose(kern, want, **_tol(dtype))
+    np.testing.assert_allclose(kern, dense, **_tol(dtype))
+
+
+@settings(max_examples=5, deadline=None)
+@given(st.integers(1, 3), st.sampled_from((1, 2, 8)),
+       st.sampled_from((1, 4)), st.sampled_from((8, 16)),
+       st.integers(9, 20), st.integers(0, 10 ** 6))
+def test_paged_decode_kernel_vs_dense_property_wide(B, KVH, G, page, bp,
+                                                    seed):
+    """The property above over block tables wider than one decode step,
+    ragged lengths from empty to full."""
+    lens = jnp.asarray(
+        np.random.RandomState(seed % 2 ** 31).randint(0, page * bp + 1, B),
+        jnp.int32)
+    kern, dense, _ = _wide_decode_case(B, KVH, G, page, bp,
+                                       seed % (2 ** 31), jnp.float32, lens)
+    np.testing.assert_allclose(kern, dense, rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("local_kvh", [1, 2])
+def test_paged_decode_head_slice_bit_identical(local_kvh):
+    """A call over a slice of the kv heads (and their query heads) gives
+    that slice of the whole call's output bit for bit, over a wide
+    table: what lets the mesh's shard-local call match the single-device
+    kernel exactly, checked here without a mesh."""
+    KVH, G, hd, page, bp = 2 * local_kvh, 4, 32, 8, 20
+    lens = jnp.asarray([0, 1, 127, 128, 129, 160, 77, 150], jnp.int32)
+    B, NB = lens.shape[0], lens.shape[0] * bp + 2
+    ks = jax.random.split(jax.random.PRNGKey(9), 4)
+    q = jax.random.normal(ks[0], (B, KVH * G, hd))
+    k_pool = jax.random.normal(ks[1], (NB, page, KVH, hd))
+    v_pool = jax.random.normal(ks[2], (NB, page, KVH, hd))
+    bt = jax.random.permutation(ks[3], NB)[:B * bp] \
+        .reshape(B, bp).astype(jnp.int32)
+    whole = np.asarray(paged_attention(q, k_pool, v_pool, bt, lens,
+                                       scale=0.2, interpret=True))
+    for s in range(2):
+        heads = slice(s * local_kvh, (s + 1) * local_kvh)
+        rows = slice(s * local_kvh * G, (s + 1) * local_kvh * G)
+        part = paged_attention(q[:, rows], k_pool[:, :, heads],
+                               v_pool[:, :, heads], bt, lens, scale=0.2,
+                               interpret=True)
+        np.testing.assert_array_equal(np.asarray(part), whole[:, rows])
 
 
 def test_decode_layer_kernel_matches_dense_after_wraparound():

@@ -158,17 +158,14 @@ def _quantized_pool(key, nb, page, kvh, hd, qdtype):
     return x, q, s
 
 
-def test_kernel_decode_matches_dense_dequant():
-    """The kernel's in-loop dequant reproduces the dense path's gathered
-    dequant: same codes * same scales -> same f32 operands, outputs
-    equal to reduction-order noise."""
+def _kernel_vs_dense_dequant(qdtype, nb, page, kvh, hd, H, bt, lens):
+    """Kernel output and the dense path's gathered dequant (the same codes
+    times the same scales) for one quantized pool."""
     k0, k1, k2 = jax.random.split(jax.random.PRNGKey(3), 3)
-    nb, page, kvh, hd, B, H = 8, 4, 2, 16, 3, 4
-    _, kq, ks = _quantized_pool(k0, nb, page, kvh, hd, jnp.int8)
-    _, vq, vs = _quantized_pool(k1, nb, page, kvh, hd, jnp.int8)
+    B = bt.shape[0]
+    _, kq, ks = _quantized_pool(k0, nb, page, kvh, hd, qdtype)
+    _, vq, vs = _quantized_pool(k1, nb, page, kvh, hd, qdtype)
     q = jax.random.normal(k2, (B, H, hd), jnp.float32)
-    bt = jnp.arange(B * 2, dtype=jnp.int32).reshape(B, 2)
-    lens = jnp.array([3, 8, 5], jnp.int32)
     scale = 1.0 / np.sqrt(hd)
 
     out = kops.paged_attention(q, kq, vq, bt, lens, scale=scale,
@@ -183,9 +180,35 @@ def test_kernel_decode_matches_dense_dequant():
     mask = jnp.arange(kf.shape[1])[None, :] < lens[:, None]
     s = jnp.where(mask[:, None, None], s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
+    p = jnp.where(mask[:, None, None], p, 0.0)   # empty rows emit zeros
     ref = jnp.einsum("bkgs,bskh->bkgh", p, vf).reshape(B, H, hd)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               atol=1e-6, rtol=1e-6)
+    return np.asarray(out), np.asarray(ref)
+
+
+def test_kernel_decode_matches_dense_dequant():
+    """The kernel's in-loop dequant reproduces the dense path's gathered
+    dequant: same codes * same scales -> same f32 operands, outputs
+    equal to reduction-order noise."""
+    bt = jnp.arange(3 * 2, dtype=jnp.int32).reshape(3, 2)
+    out, ref = _kernel_vs_dense_dequant(
+        jnp.int8, 8, 4, 2, 16, 4, bt, jnp.array([3, 8, 5], jnp.int32))
+    np.testing.assert_allclose(out, ref, atol=1e-6, rtol=1e-6)
+
+
+@pytest.mark.parametrize("qdtype", [jnp.int8, jnp.float8_e4m3fn],
+                         ids=["int8", "fp8"])
+def test_kernel_decode_matches_dense_dequant_wide(qdtype):
+    """The same over a block table wider than one decode step (16 pages
+    of 8 tokens a step, 20 pages a lane), lengths at 0, 1, the step
+    boundary and one either side of it, and a full table."""
+    lens = jnp.array([0, 1, 127, 128, 129, 160], jnp.int32)
+    B, bp = lens.shape[0], 20
+    bt = jax.random.permutation(jax.random.PRNGKey(5), B * bp + 2)[
+        :B * bp].reshape(B, bp).astype(jnp.int32)
+    out, ref = _kernel_vs_dense_dequant(qdtype, B * bp + 2, 8, 2, 16, 8,
+                                        bt, lens)
+    np.testing.assert_array_equal(out[0], 0.0)
+    np.testing.assert_allclose(out, ref, atol=1e-6, rtol=1e-6)
 
 
 def test_quantized_attention_drift_bounded():

@@ -71,6 +71,33 @@ def test_make_host_mesh_matches_device_count():
     assert mesh.shape["data"] * mesh.shape["model"] == jax.device_count()
 
 
+@pytest.mark.parametrize("local_kvh", [1, 2])
+def test_sharded_decode_kernel_equals_unsharded_on_wide_table(local_kvh):
+    """``kops.paged_attention_sharded`` is bit-identical to the unsharded
+    kernel over a block table wider than one decode step, with each
+    shard holding ``local_kvh`` KV heads: on a (2, 2) mesh where four
+    devices exist, else on (1, 1)."""
+    import jax.numpy as jnp
+
+    from repro.kernels import ops as kops
+
+    model = 2 if jax.device_count() >= 4 else 1
+    mesh = make_host_mesh(model, model)
+    KVH, G, hd, page, bp = model * local_kvh, 4, 32, 8, 20
+    lens = jnp.array([0, 1, 127, 128, 129, 160, 77, 150], jnp.int32)
+    B, NB = lens.shape[0], lens.shape[0] * bp + 2
+    ks = jax.random.split(jax.random.PRNGKey(9), 4)
+    q = jax.random.normal(ks[0], (B, KVH * G, hd))
+    k_pool = jax.random.normal(ks[1], (NB, page, KVH, hd))
+    v_pool = jax.random.normal(ks[2], (NB, page, KVH, hd))
+    bt = jax.random.permutation(ks[3], NB)[:B * bp] \
+        .reshape(B, bp).astype(jnp.int32)
+    args = (q, k_pool, v_pool, bt, lens)
+    mesh_out = kops.paged_attention_sharded(mesh, *args, scale=0.2)
+    one_out = kops.paged_attention(*args, scale=0.2)
+    np.testing.assert_array_equal(np.asarray(mesh_out), np.asarray(one_out))
+
+
 # ---------------------------------------------------------------------------
 # serving sharding rules (AbstractMesh / single-device)
 # ---------------------------------------------------------------------------

@@ -7,7 +7,9 @@ kernel may use. These tests compile each kernel ahead of time against a
 described ``v5e:2x2`` topology — no chip is needed, nothing runs — and
 check that the program holds the Mosaic kernel (``tpu_custom_call``)
 rather than a fallback. The decode program's sampler compiles the same
-way, to check that no sort over the whole vocabulary comes back.
+way, to check that no sort over the whole vocabulary comes back, and so
+does the Qwen3 cell's whole decode program, to check how it runs the
+paged-attention kernel.
 
 The topology is described inside a module-scoped fixture, never at
 import: only one process may load the TPU library at a time, and a
@@ -71,6 +73,22 @@ def _decode(sds, pool_dtype):
         q, kp, vp, bt, lens, scale=HD ** -0.5, interpret=False, **kw))
     return fn.lower(sds((B, H, HD), jnp.bfloat16), *pools, bt,
                     sds((B,), jnp.int32), **kw)
+
+
+def _decode_cell(sds, pool_dtype):
+    """The decode face at the Qwen3 cell's shapes: 128 lanes of 4,608
+    tokens (288 pages) over one layer's pool of 1,664 pages."""
+    cell_blocks = 1664
+    pools = [sds((cell_blocks, PAGE, KVH, HD), pool_dtype)] * 2
+    kw = {}
+    if pool_dtype == jnp.int8:
+        kw = dict(k_scale=sds((cell_blocks, PAGE, KVH), jnp.float32),
+                  v_scale=sds((cell_blocks, PAGE, KVH), jnp.float32))
+    fn = jax.jit(lambda q, kp, vp, bt, lens, **kw: pa.paged_attention(
+        q, kp, vp, bt, lens, scale=HD ** -0.5, interpret=False, **kw))
+    return fn.lower(sds((128, H, HD), jnp.bfloat16), *pools,
+                    sds((128, 4608 // PAGE), jnp.int32),
+                    sds((128,), jnp.int32), **kw)
 
 
 def _prefill(sds, pool_dtype):
@@ -138,8 +156,11 @@ def _experts(sds, _):
     (_step_score, None),
     (_latent, jnp.bfloat16),
     (_experts, None),
+    (_decode_cell, jnp.bfloat16),
+    (_decode_cell, jnp.int8),
 ], ids=["decode_bf16", "decode_int8", "prefill_bf16", "prefill_int8",
-        "step_score", "latent_decode", "expert_ffn"])
+        "step_score", "latent_decode", "expert_ffn", "decode_cell_bf16",
+        "decode_cell_int8"])
 def test_kernel_compiles_for_v5e(one_chip, lower, pool_dtype):
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -163,3 +184,79 @@ def test_sampler_sorts_no_vocabulary_row(one_chip):
     sorts = [ln for ln in text.splitlines() if " sort(" in ln]
     assert sorts, "the selection's small sorts are gone: check the pattern"
     assert not [ln for ln in sorts if f",{vp}]" in ln.split(" sort(")[0]]
+
+
+def test_decode_program_runs_one_kernel_per_layer(one_chip, monkeypatch):
+    """The Qwen3 cell's decode program (``jit_batched_decode``, 128
+    lanes, 1,664 pages, 36 layers) holds one Mosaic kernel whose op name
+    is ``paged_attention``, and it reads one layer's pools as the layer
+    scan's body updated them: no copy, transpose or relayout of a
+    layer's pool. The trace reduction times that op by its name, and
+    ``paged_decode_roofline`` divides its time by its run count."""
+    import collections
+    import json
+    import re
+
+    from bench import harness, trace_reduce
+    from bench.families.qwen3 import weights
+    from repro.core.pruning import make_policy
+    from repro.data.tokenizer import get_tokenizer
+    from repro.kernels import ops as kops
+    from repro.serving import Engine, EngineConfig, SamplingParams
+
+    monkeypatch.setattr(kops, "_interpret", lambda: False)
+    bench_dir = os.path.join(os.path.dirname(__file__), "..", "bench")
+
+    def load(*path):
+        with open(os.path.join(bench_dir, *path)) as f:
+            return json.load(f)
+
+    config = load("configs", "qwen3-4b-thinking.json")
+    mix = load("traffic", "step-pressure.json")
+    cfg, m = harness.model_config(config), config["model"]
+
+    def put(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    params, scorer = put(jax.eval_shape(
+        lambda k: weights.make_weights(tuple(sorted(m.items())), k,
+                                       get_tokenizer().step_id,
+                                       config["step_row_scale"]),
+        jax.random.PRNGKey(0)))
+    e = {**mix["engine"], "use_kernel": True}
+    samp = SamplingParams(**mix["sampling"], max_new_tokens=e["capacity"])
+    eng = Engine(params, cfg,
+                 EngineConfig(**e, num_blocks=config["num_blocks"],
+                              max_new_tokens=e["capacity"], sampling=samp,
+                              faults=None),
+                 make_policy(mix["policy"]), scorer_params=scorer)
+    cache = put(jax.eval_shape(eng._init_cache))
+    B, bps = eng.ecfg.max_batch, eng.blocks_per_seq
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    rng = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
+    text = eng.decode_fn(1).lower(
+        params, cache, i32(B), i32(B), i32(B), i32(B, bps), rng,
+        scorer).compile().as_text()
+    calls = [ln for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    names = [trace_reduce.op_base(ln.strip()) for ln in calls]
+    assert names.count("paged_attention") == 1, names
+    # its pool operands are one layer's slice: the kernel runs once per
+    # iteration of the layer scan
+    layer = f"[{config['num_blocks']},{PAGE},{KVH},{HD}]"
+    kernel = calls[names.index("paged_attention")]
+    assert kernel.split("operand_layout_constraints={")[1].count(
+        layer) == 2
+    # every op of a layer slice's size: the scan's slice of the stacked
+    # pools, the new token's scatter, and the bitcasts between them
+    ops = collections.Counter(
+        re.match(r"\s*(?:ROOT )?%\S+ = \S+ ([\w-]+)\(", ln).group(1)
+        for ln in text.splitlines()
+        if re.match(rf"\s*(?:ROOT )?%\S+ = bf16{re.escape(layer)}[{{ ]",
+                    ln))
+    assert ops == {"parameter": 4, "bitcast": 2, "scatter": 2,
+                   "fusion": 4}, ops
