@@ -3,8 +3,7 @@
     PYTHONPATH=src python -m benchmarks.run [--only table1_main,...]
 
 The serving benchmarks need the cached artifacts (built automatically on
-first use: `python -m benchmarks.common`). The roofline table needs the
-dry-run sweep (`python -m repro.launch.dryrun --all --both-meshes`).
+first use: `python -m benchmarks.common`).
 """
 from __future__ import annotations
 
@@ -22,7 +21,6 @@ BENCHES = [
     ("fig4_scaling", "Fig 4: latency scaling with trace budget"),
     ("fig5_rankacc", "Fig 5: scorer vs confidence RankAcc"),
     ("overhead", "Appendix D: scorer overhead"),
-    ("roofline", "Roofline table from the dry-run sweep"),
 ]
 
 

@@ -4,38 +4,11 @@ Every assigned architecture is described by a single frozen `ModelConfig`.
 `full_config()` in each ``configs/<arch>.py`` returns the exact published
 configuration; ``smoke_config()`` returns a reduced same-family variant
 (<=2 layers, d_model<=512, <=4 experts) used by CPU smoke tests.
-
-Input shapes are global; ``input_specs`` builds jax.ShapeDtypeStruct
-stand-ins so the launcher can lower/compile without allocating anything.
 """
 from __future__ import annotations
 
 import dataclasses
 from typing import Optional, Tuple
-
-import jax
-import jax.numpy as jnp
-
-# ---------------------------------------------------------------------------
-# Input shapes (assigned)
-# ---------------------------------------------------------------------------
-
-
-@dataclasses.dataclass(frozen=True)
-class ShapeSpec:
-    name: str
-    seq_len: int
-    global_batch: int
-    kind: str  # "train" | "prefill" | "decode"
-
-
-SHAPES = {
-    "train_4k": ShapeSpec("train_4k", 4096, 256, "train"),
-    "prefill_32k": ShapeSpec("prefill_32k", 32768, 32, "prefill"),
-    "decode_32k": ShapeSpec("decode_32k", 32768, 128, "decode"),
-    "long_500k": ShapeSpec("long_500k", 524288, 1, "decode"),
-}
-
 
 # ---------------------------------------------------------------------------
 # Model configuration
@@ -73,9 +46,6 @@ class ModelConfig:
     yarn_mscale: float = 1.0
     yarn_mscale_all_dim: float = 0.0
     sliding_window: Optional[int] = None  # native SWA (e.g. mixtral)
-    # Window applied only for the long_500k decode shape (beyond-paper
-    # rolling-buffer variant that makes dense archs sub-quadratic).
-    long_context_window: Optional[int] = 8192
 
     # --- MLA (DeepSeek-V2) --------------------------------------------------
     use_mla: bool = False
@@ -100,12 +70,6 @@ class ModelConfig:
     # routed experts held on this chip: experts [0, experts_held) of the
     # router's num_experts (the chip's expert-parallel share); 0 = all
     experts_held: int = 0
-    # capacity = cf * T * top_k / E of the capacity dispatch
-    # (``layers.moe_layer``), which only the sharded launch path runs
-    # (GSPMD shards its dispatch over the expert axis). Production configs
-    # use 1.25 (tokens may drop, Switch-style). Serving and single-device
-    # training run the dropless layer (``layers.moe_dropless``).
-    moe_capacity_factor: float = 1.25
 
     # --- SSM (Mamba2 / SSD) ---------------------------------------------------
     ssm_state_size: int = 0
@@ -188,115 +152,3 @@ class ModelConfig:
                 if (i + 1) % self.hybrid_attn_every == 0
             )
         return tuple(range(self.num_layers))
-
-    def effective_cache_len(self, shape: ShapeSpec) -> int:
-        """Sequence length actually held in KV cache for a shape."""
-        length = shape.seq_len
-        if self.sliding_window is not None:
-            length = min(length, self.sliding_window)
-        if shape.name == "long_500k" and self.long_context_window is not None:
-            length = min(length, self.long_context_window)
-        return length
-
-    def supports_shape(self, shape: ShapeSpec) -> bool:
-        if shape.name == "long_500k":
-            if self.arch_type in ("ssm", "hybrid"):
-                return True
-            # dense/moe/vlm/audio run long_500k only via the sliding-window
-            # variant (see DESIGN.md §long_500k applicability)
-            return (self.sliding_window is not None
-                    or self.long_context_window is not None)
-        return True
-
-
-# ---------------------------------------------------------------------------
-# Input specs (ShapeDtypeStruct stand-ins, no allocation)
-# ---------------------------------------------------------------------------
-
-
-def _sds(shape, dtype):
-    return jax.ShapeDtypeStruct(tuple(int(s) for s in shape), dtype)
-
-
-def input_specs(cfg: ModelConfig, shape_name: str) -> dict:
-    """ShapeDtypeStruct stand-ins for every model input of the step fn.
-
-    train  -> {tokens, labels[, encoder_embeds / modality_embeds]}
-    prefill-> {tokens[, ...frontend embeds]}
-    decode -> {tokens (1 new), positions, cache pytree, block_tables}
-    """
-    shape = SHAPES[shape_name]
-    b, s = shape.global_batch, shape.seq_len
-    specs: dict = {}
-    if shape.kind == "train":
-        specs["tokens"] = _sds((b, s), jnp.int32)
-        specs["labels"] = _sds((b, s), jnp.int32)
-    elif shape.kind == "prefill":
-        specs["tokens"] = _sds((b, s), jnp.int32)
-    else:  # decode
-        specs["tokens"] = _sds((b, 1), jnp.int32)
-        specs["positions"] = _sds((b,), jnp.int32)
-    if cfg.modality == "vision":
-        # precomputed ViT/projector patch embeddings (stub frontend)
-        n = cfg.num_modality_tokens or 256
-        if shape.kind in ("train", "prefill"):
-            specs["modality_embeds"] = _sds((b, n, cfg.d_model), jnp.bfloat16)
-    if cfg.is_encoder_decoder and shape.kind in ("train", "prefill"):
-        # precomputed mel/conv frame embeddings for the encoder (stub);
-        # at decode time the encoder output lives in the cross-attn cache.
-        enc_len = cfg.encoder_seq_len or 1024
-        specs["encoder_embeds"] = _sds((b, enc_len, cfg.d_model), jnp.bfloat16)
-    return specs
-
-
-def kv_cache_specs(cfg: ModelConfig, shape_name: str) -> dict:
-    """KV-cache ShapeDtypeStructs for a decode shape — the DISTRIBUTED
-    contiguous layout ``serve_decode_step`` consumes.
-
-    Each data shard owns its sequences' caches as a dense per-sequence
-    ring buffer (capacity block-quantised); paged block tables are a
-    host-side per-shard allocator concern (serving/kv_manager.py), so the
-    device-side step sees:
-      k/v_cache:  [num_layers_attn, batch, capacity, KVH, head_dim]
-      kv_cache:   [num_layers_attn, batch, capacity, kv_lora+rope]  (MLA)
-      ssm_state:  [num_ssm_layers, batch, heads, head_dim, state]
-      conv_state: [num_ssm_layers, batch, conv_width-1, d_conv_channels]
-    """
-    shape = SHAPES[shape_name]
-    assert shape.kind == "decode"
-    b = shape.global_batch
-    cache_len = cfg.effective_cache_len(shape)
-    bs = cfg.kv_block_size
-    capacity = -(-cache_len // bs) * bs  # block-quantised
-    specs: dict = {}
-    attn_layers = cfg.attention_layer_ids()
-    dt = jnp.bfloat16
-    if attn_layers:
-        la = len(attn_layers)
-        if cfg.use_mla:
-            kv_dims = cfg.kv_lora_rank + cfg.qk_rope_head_dim
-            specs["kv_cache"] = _sds((la, b, capacity, kv_dims), dt)
-        else:
-            specs["k_cache"] = _sds(
-                (la, b, capacity, cfg.num_kv_heads, cfg.head_dim), dt)
-            specs["v_cache"] = _sds(
-                (la, b, capacity, cfg.num_kv_heads, cfg.head_dim), dt)
-    if cfg.arch_type in ("ssm", "hybrid"):
-        # hybrid: ALL num_layers are mamba; shared attention blocks are
-        # interleaved *between* groups and counted by attention_layer_ids().
-        n_ssm = cfg.num_layers
-        specs["ssm_state"] = _sds(
-            (n_ssm, b, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state_size),
-            jnp.float32)
-        specs["conv_state"] = _sds(
-            (n_ssm, b, cfg.ssm_conv_width - 1,
-             cfg.d_inner + 2 * cfg.ssm_state_size),
-            dt)
-    if cfg.is_encoder_decoder:
-        enc_len = cfg.encoder_seq_len or 1024
-        # cross-attention K/V computed once at prefill from encoder output
-        specs["cross_k"] = _sds(
-            (len(attn_layers), b, enc_len, cfg.num_kv_heads, cfg.head_dim), dt)
-        specs["cross_v"] = _sds(
-            (len(attn_layers), b, enc_len, cfg.num_kv_heads, cfg.head_dim), dt)
-    return specs

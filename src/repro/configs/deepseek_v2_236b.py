@@ -67,5 +67,4 @@ def smoke_config() -> ModelConfig:
         num_experts_per_tok=2,
         num_shared_experts=1,
         moe_d_ff=128,
-        moe_capacity_factor=8.0,
 )

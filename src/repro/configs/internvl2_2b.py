@@ -3,9 +3,9 @@
 24L d_model=2048 16H (GQA kv=8) d_ff=8192 vocab=92553.
 
 Per the assignment carve-out, the vision frontend (InternViT + projector) is
-a STUB: ``input_specs()`` provides precomputed patch embeddings of shape
-(batch, 256, d_model); this config implements the InternLM2 language decoder
-that consumes them.
+a STUB: the caller passes precomputed patch embeddings
+(``modality_embeds``, shape (batch, 256, d_model)); this config
+implements the InternLM2 language decoder that consumes them.
 """
 from repro.configs.base import ModelConfig
 

@@ -41,5 +41,4 @@ def smoke_config() -> ModelConfig:
         num_experts_per_tok=2,
         moe_d_ff=512,
         sliding_window=128,
-        moe_capacity_factor=8.0,
 )

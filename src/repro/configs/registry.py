@@ -4,7 +4,7 @@ from __future__ import annotations
 import importlib
 from typing import Dict
 
-from repro.configs.base import ModelConfig, SHAPES, ShapeSpec, input_specs, kv_cache_specs
+from repro.configs.base import ModelConfig
 
 _ARCH_MODULES: Dict[str, str] = {
     "granite-20b": "repro.configs.granite_20b",
@@ -47,17 +47,9 @@ def serving_config(arch: str = "qwen3-4b-thinking") -> ModelConfig:
     return dataclasses.replace(cfg, vocab_size=get_tokenizer().vocab_size)
 
 
-def get_shape(name: str) -> ShapeSpec:
-    return SHAPES[name]
-
-
 __all__ = [
     "ASSIGNED_ARCHS",
     "ALL_ARCHS",
     "get_config",
     "serving_config",
-    "get_shape",
-    "input_specs",
-    "kv_cache_specs",
-    "SHAPES",
 ]

@@ -3,9 +3,10 @@
 24L d_model=1024 16H (kv=16) d_ff=8192 vocab=256206.
 
 Per the assignment carve-out, the audio frontend (mel-spectrogram +
-conformer conv feature extractor) is a STUB: ``input_specs()`` provides
-precomputed frame embeddings (batch, 1024, d_model) consumed by the text
-encoder-decoder transformer implemented here.
+conformer conv feature extractor) is a STUB: the caller passes
+precomputed frame embeddings (``encoder_embeds``, shape (batch, 1024,
+d_model)) consumed by the text encoder-decoder transformer implemented
+here.
 """
 from repro.configs.base import ModelConfig
 
@@ -26,7 +27,6 @@ def full_config() -> ModelConfig:
         is_encoder_decoder=True,
         encoder_seq_len=1024,
         modality="audio",
-        long_context_window=8192,
     )
 
 

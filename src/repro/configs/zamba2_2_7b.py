@@ -29,7 +29,6 @@ def full_config() -> ModelConfig:
         ssm_conv_width=4,
         ssm_chunk_size=256,
         hybrid_attn_every=6,   # 9 shared-attention invocations over 54 layers
-        long_context_window=8192,
     )
 
 

@@ -1,6 +1,2 @@
-"""Launchers: production mesh, sharding rules, step functions, dry-run.
-
-NOTE: repro.launch.dryrun sets XLA_FLAGS for 512 host devices as its very
-first statement — import it only in a dedicated process, never from tests
-or benchmarks that need the real single-device CPU backend.
-"""
+"""Launchers: serving and training entry points, the serving mesh and its
+sharding rules."""

@@ -1,10 +1,4 @@
-"""Production meshes (TPU v5e) and host meshes (CPU, simulated devices).
-
-Single pod: 256 chips as (data=16, model=16).
-Multi-pod:  2 pods = 512 chips as (pod=2, data=16, model=16); the "pod"
-axis carries only batch (data-parallel) sharding — gradients all-reduce
-over ("pod", "data") — so the slow inter-pod DCI links never see tensor-
-parallel collectives.
+"""The serving engine's ("data", "model") device mesh.
 
 Host meshes adapt to however many host devices exist —
 ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` simulates an
@@ -23,12 +17,6 @@ from typing import Optional, Tuple
 
 import jax
 from jax.sharding import AxisType
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def resolve_host_mesh_shape(data: Optional[int] = None,
@@ -71,11 +59,10 @@ def resolve_host_mesh_shape(data: Optional[int] = None,
 
 
 def make_host_mesh(data: Optional[int] = None, model: Optional[int] = None):
-    """Host-device mesh with the production axis names.
+    """Device mesh with the axes ("data", "model").
 
     With no arguments this adapts to ``jax.device_count()`` (all devices
-    on the data axis) — the old hard-coded ``(1, 1)`` only ever matched
-    a single-device process. Explicit sizes are validated against the
+    on the data axis). Explicit sizes are validated against the
     available devices; ``None`` axes are inferred (see
     ``resolve_host_mesh_shape``).
     """
@@ -83,13 +70,3 @@ def make_host_mesh(data: Optional[int] = None, model: Optional[int] = None):
     return jax.make_mesh((data, model), ("data", "model"),
                          axis_types=(AxisType.Auto,) * 2)
 
-
-def data_axes(mesh) -> tuple:
-    """The batch-sharding axes for this mesh."""
-    return ("pod", "data") if "pod" in mesh.axis_names else ("data",)
-
-
-def axis_size(mesh, name: str) -> int:
-    if name not in mesh.axis_names:
-        return 1
-    return mesh.shape[name]

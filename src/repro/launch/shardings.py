@@ -1,38 +1,31 @@
-"""Sharding rules: parameters, optimizer state, inputs, decode caches.
+"""Sharding rules for the serving engine over a ("data", "model") mesh
+(docs/ENGINE.md "Sharded serving"): parameters, the paged decode cache,
+prefill KV and the jitted steps' lane/activation layouts.
 
-Baseline layout (the paper-faithful production config):
-
-  * batch over ("pod", "data") — the pod axis carries ONLY data
-    parallelism, keeping tensor-parallel collectives on intra-pod ICI;
-  * tensor parallel over "model": column-parallel for up-projections
-    (wq/wk/wv/w_gate/w_up/router/...), row-parallel for down-projections
-    (wo/w_down/w_out) — the Megatron pairing, so each attention/ffn block
-    costs one all-reduce;
-  * FSDP over "data" on a second weight dim when the tensor-parallel
-    shard alone would not fit HBM (always on for training, where optimizer
-    state is 6x params; adaptive for serving).
+The layout trades a third of the tensor-parallel memory win for
+EXACTNESS: column-parallel weights shard over "model" as usual, but the
+row-parallel contraction set (wo / w_down / w_out) is replicated, so
+GSPMD's only cross-shard collectives are all-gathers of activations —
+pure data movement, never a floating-point reduction. A bf16 psum from
+a row-parallel contraction rounds partial sums differently than the
+single-device matmul and flips near-tie samples; with this layout the
+sharded engine's logits are bit-identical to the single-device
+engine's, which is what lets CI pin token-identity across device
+counts.
 
 Everything is expressed as PartitionSpec trees over jax.eval_shape
 pytrees — nothing here touches real devices.
 """
 from __future__ import annotations
 
-from typing import Optional, Tuple
-
 import jax
-import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.configs.base import ModelConfig, SHAPES
+from repro.configs.base import ModelConfig
 
-# weight-name -> which dim the "model" axis shards
+# column-parallel weights: the "model" axis shards their output dim
 _MODEL_LAST = {"wq", "wk", "wv", "w_gate", "w_up", "wq_a", "wq_b", "wkv_a",
                "wk_b", "wv_b", "router", "w_in", "lm_head", "conv_w"}
-_MODEL_CONTRACT = {"wo", "w_down", "w_out"}  # row-parallel (second-to-last)
-_REPLICATE = {"A_log", "D", "dt_bias", "b1", "b2"}
-
-# serving: add FSDP over "data" only when the TP shard would exceed this
-SERVE_FSDP_THRESHOLD_BYTES = 8 * 2 ** 30  # 8 GiB of the 16 GiB v5e HBM
 
 
 def _leaf_name(path) -> str:
@@ -42,307 +35,9 @@ def _leaf_name(path) -> str:
     return ""
 
 
-def _pick_dim(shape, axis_size, used, prefer=None) -> Optional[int]:
-    """First dim (preference order) divisible by axis_size and unused."""
-    order = list(prefer) if prefer else []
-    order += [d for d in range(len(shape)) if d not in order]
-    for d in order:
-        if d in used:
-            continue
-        if shape[d] % axis_size == 0 and shape[d] >= axis_size:
-            return d
-    return None
-
-
-def param_spec(path, shape, mesh, fsdp: bool) -> P:
-    """PartitionSpec for one parameter leaf."""
-    name = _leaf_name(path)
-    ndim = len(shape)
-    model_n = mesh.shape["model"]
-    data_n = mesh.shape["data"]
-    spec = [None] * ndim
-    used = set()
-
-    if name in _REPLICATE or ndim < 2:
-        return P(*spec) if ndim else P()
-
-    in_experts = any(getattr(e, "key", None) == "experts" for e in path)
-
-    if in_experts and ndim >= 3 and shape[-3] % model_n == 0:
-        # EXPERT PARALLELISM: when the expert count divides the model
-        # axis (deepseek: 160 experts / 16), shard experts across it —
-        # the per-token dispatch stays [tokens, E/16] local and the
-        # [G, E, C, D] dispatch buffers shard with the weights. MoE archs
-        # whose E is small (mixtral: 8) fall through to tensor parallel.
-        model_dim = ndim - 3  # the E dim of [L, E, D, F] / [L, E, F, D]
-    elif name == "embed":
-        model_dim = 0 if shape[0] % model_n == 0 else None
-    elif name in _MODEL_CONTRACT:
-        model_dim = _pick_dim(shape, model_n, used, prefer=[ndim - 2])
-    elif name in _MODEL_LAST:
-        model_dim = _pick_dim(shape, model_n, used, prefer=[ndim - 1])
-    else:  # generic 2D+ tensor: prefer last dim
-        model_dim = _pick_dim(shape, model_n, used, prefer=[ndim - 1])
-    if model_dim is not None:
-        spec[model_dim] = "model"
-        used.add(model_dim)
-
-    if fsdp:
-        # never FSDP the layer-stack dim 0 of stacked layers (it scans);
-        # prefer the largest remaining divisible dim
-        sizes = [(shape[d], d) for d in range(1 if ndim > 2 else 0, ndim)
-                 if d not in used]
-        sizes.sort(reverse=True)
-        for _, d in sizes:
-            if shape[d] % data_n == 0 and shape[d] >= data_n:
-                spec[d] = "data"
-                break
-    return P(*spec)
-
-
-def param_bytes(shapes_tree) -> int:
-    leaves = jax.tree_util.tree_leaves(shapes_tree)
-    return sum(int(np.prod(l.shape)) * l.dtype.itemsize for l in leaves)
-
-
-def sharded_bytes_per_device(shapes_tree, spec_tree, mesh,
-                             dtype_filter=None) -> int:
-    """Per-device bytes of a pytree under its PartitionSpec tree."""
-    total = 0
-    leaves, _ = jax.tree_util.tree_flatten(shapes_tree)
-    specs, _ = jax.tree_util.tree_flatten(
-        spec_tree, is_leaf=lambda x: isinstance(x, P))
-    for leaf, spec in zip(leaves, specs):
-        if dtype_filter is not None and leaf.dtype != dtype_filter:
-            continue
-        factor = 1
-        for entry in spec:
-            if entry is None:
-                continue
-            axes = entry if isinstance(entry, tuple) else (entry,)
-            for a in axes:
-                factor *= mesh.shape[a]
-        total += int(np.prod(leaf.shape)) * leaf.dtype.itemsize // factor
-    return total
-
-
-def partition_params(cfg: ModelConfig, mesh, shapes_tree,
-                     fsdp: Optional[bool] = None):
-    """PartitionSpec tree for a param (or optimizer-moment) pytree."""
-    if fsdp is None:
-        model_n = mesh.shape["model"]
-        fsdp = param_bytes(shapes_tree) / model_n > SERVE_FSDP_THRESHOLD_BYTES
-
-    def one(path, leaf):
-        if leaf.ndim == 0:
-            return P()
-        return param_spec(path, leaf.shape, mesh, fsdp)
-
-    return jax.tree_util.tree_map_with_path(one, shapes_tree)
-
-
-def partition_opt_state(cfg: ModelConfig, mesh, opt_shapes, param_specs):
-    """Optimizer state mirrors the param sharding (mu/nu per leaf)."""
-    def one(path, leaf):
-        if leaf.ndim == 0:
-            return P()
-        # paths look like (.mu, <param path...>) — reuse param rules
-        return param_spec(path, leaf.shape, mesh, fsdp=True)
-
-    return jax.tree_util.tree_map_with_path(one, opt_shapes)
-
-
-def _dp(mesh) -> Tuple[str, ...]:
-    return ("pod", "data") if "pod" in mesh.axis_names else ("data",)
-
-
-def _batch_axes(mesh, batch: int):
-    dp = _dp(mesh)
-    total = 1
-    for a in dp:
-        total *= mesh.shape[a]
-    return dp if batch % total == 0 else None
-
-
-def partition_inputs(cfg: ModelConfig, mesh, shape_name: str) -> dict:
-    """PartitionSpecs matching input_specs(cfg, shape_name) keys."""
-    shape = SHAPES[shape_name]
-    b = shape.global_batch
-    dp = _batch_axes(mesh, b)
-    specs: dict = {}
-    if shape.kind == "train":
-        specs["tokens"] = P(dp, None)
-        specs["labels"] = P(dp, None)
-    elif shape.kind == "prefill":
-        specs["tokens"] = P(dp, None)
-    else:
-        specs["tokens"] = P(dp, None)
-        specs["positions"] = P(dp)
-    if cfg.modality == "vision" and shape.kind in ("train", "prefill"):
-        specs["modality_embeds"] = P(dp, None, None)
-    if cfg.is_encoder_decoder and shape.kind in ("train", "prefill"):
-        specs["encoder_embeds"] = P(dp, None, None)
-    return specs
-
-
-def _model_dim_for_cache(shape, mesh, candidates):
-    model_n = mesh.shape["model"]
-    for d in candidates:
-        if shape[d] % model_n == 0 and shape[d] >= model_n:
-            return d
-    return None
-
-
-def partition_cache(cfg: ModelConfig, mesh, shape_name: str) -> dict:
-    """PartitionSpecs matching kv_cache_specs keys (contiguous layout).
-
-    Batch over data axes; heads (or the latent/head_dim when heads don't
-    divide) over model. The per-sequence contiguous layout means no
-    cross-shard gathers: each data shard's sequences live entirely on it.
-    """
-    from repro.configs.base import kv_cache_specs
-    specs = kv_cache_specs(cfg, shape_name)
-    b = SHAPES[shape_name].global_batch
-    dp = _batch_axes(mesh, b)
-    out: dict = {}
-    for key, sds in specs.items():
-        nd = len(sds.shape)
-        spec = [None] * nd
-        if key in ("k_cache", "v_cache"):
-            spec[1] = dp
-            # heads when they divide; otherwise sequence-shard the cache
-            # length (see kv_partition_specs) — never the head_dim
-            md = _model_dim_for_cache(sds.shape, mesh, (3, 2))
-            if md is not None:
-                spec[md] = "model"
-        elif key == "kv_cache":
-            spec[1] = dp
-            md = _model_dim_for_cache(sds.shape, mesh, (2,))  # cap
-            if md is not None:
-                spec[md] = "model"
-        elif key == "ssm_state":
-            spec[1] = dp
-            md = _model_dim_for_cache(sds.shape, mesh, (2, 4))  # H, N
-            if md is not None:
-                spec[md] = "model"
-        elif key == "conv_state":
-            spec[1] = dp
-            md = _model_dim_for_cache(sds.shape, mesh, (3,))
-            if md is not None:
-                spec[md] = "model"
-        elif key in ("cross_k", "cross_v"):
-            spec[1] = dp
-            md = _model_dim_for_cache(sds.shape, mesh, (3, 4))
-            if md is not None:
-                spec[md] = "model"
-        out[key] = P(*spec)
-    return out
-
-
-def kv_partition_specs(cfg: ModelConfig, mesh, batch: int) -> dict:
-    """PartitionSpecs for the PER-LAYER (unstacked) KV/state tensors the
-    model emits at prefill and carries at decode:
-
-      kv   [B, S|cap, KVH, hd]     mla  [B, S|cap, lora+rope]
-      ssm  [B, H, P, N]            conv [B, W-1, C]
-
-    Batch over data axes; heads (falling back to head_dim / latent dims /
-    state when heads don't divide) over model. Threaded into forward_full
-    and serve_decode_step as with_sharding_constraints so GSPMD never
-    replicates the caches (the dominant serving bytes) over model.
-    """
-    model_n = mesh.shape["model"]
-    dp = _batch_axes(mesh, batch)
-
-    def div(n):
-        return n % model_n == 0 and n >= model_n
-
-    out = {}
-    if cfg.num_kv_heads and cfg.head_dim:
-        if div(cfg.num_kv_heads):
-            out["kv"] = P(dp, None, "model", None)
-        else:
-            # SEQUENCE-SHARDED cache (flash-decoding style). Sharding the
-            # head_dim instead forces GSPMD to all-gather the whole cache
-            # every step (scores contract hd): measured 30.6 GB/device/
-            # step for qwen3 decode_32k. Sharding the cache-length dim
-            # keeps all reads local; the softmax renormalisation costs
-            # only tiny [B,KVH,G] all-reduces.
-            out["kv"] = P(dp, "model", None, None)
-    if cfg.use_mla:
-        # same reasoning: scores contract the latent dim — shard cap
-        out["mla"] = P(dp, "model", None)
-    if cfg.arch_type in ("ssm", "hybrid"):
-        if div(cfg.ssm_heads):
-            out["ssm"] = P(dp, "model", None, None)
-        elif div(cfg.ssm_state_size):
-            out["ssm"] = P(dp, None, None, "model")
-        else:
-            out["ssm"] = P(dp, None, None, None)
-        C = cfg.d_inner + 2 * cfg.ssm_state_size
-        out["conv"] = P(dp, None, "model" if div(C) else None)
-    return out
-
-
-def moe_expert_specs(cfg: ModelConfig, mesh) -> Optional[dict]:
-    """FSDP-free PartitionSpecs for the UNSTACKED per-layer expert weights
-    ([E, D, F] / [E, F, D]). Constraining the weights to these before the
-    MoE group-chunk scan hoists the FSDP all-gather out of the loop
-    (otherwise it repeats per chunk — the dominant collective term for
-    mixtral train_4k)."""
-    if not cfg.uses_moe:
-        return None
-    model_n = mesh.shape["model"]
-    E, D, F = cfg.num_experts, cfg.d_model, cfg.moe_d_ff
-
-    def div(n):
-        return n % model_n == 0 and n >= model_n
-
-    if div(E):  # expert parallel
-        return {"w_gate": P("model", None, None),
-                "w_up": P("model", None, None),
-                "w_down": P("model", None, None)}
-    if div(F):  # tensor parallel on the ffn dim
-        return {"w_gate": P(None, None, "model"),
-                "w_up": P(None, None, "model"),
-                "w_down": P(None, "model", None)}
-    return {"w_gate": P(None, None, None),
-            "w_up": P(None, None, None),
-            "w_down": P(None, None, None)}
-
-
-def moe_ex_in_spec(cfg: ModelConfig, mesh) -> Optional[P]:
-    """Decode-time layout for the dispatched expert inputs [G, E, C, D]:
-    E over model (matching expert-parallel weights), D over data
-    (matching the weights' FSDP dim) — forces activation movement
-    instead of per-step weight all-gathers."""
-    if not cfg.uses_moe:
-        return None
-    model_n = mesh.shape["model"]
-    data_n = mesh.shape["data"]
-    e = "model" if cfg.num_experts % model_n == 0 else None
-    d = "data" if cfg.d_model % data_n == 0 else None
-    return P(None, e, None, d)
-
-
 def to_named(mesh, spec_tree):
     return jax.tree.map(lambda s: NamedSharding(mesh, s), spec_tree,
                         is_leaf=lambda x: isinstance(x, P))
-
-
-# ---------------------------------------------------------------------------
-# serving: Engine-over-a-mesh layout (docs/ENGINE.md "Sharded serving")
-# ---------------------------------------------------------------------------
-# The serving engine's mesh layout trades a third of the tensor-parallel
-# memory win for EXACTNESS: column-parallel weights shard over "model"
-# as usual, but the row-parallel contraction set (wo / w_down / w_out)
-# is replicated, so GSPMD's only cross-shard collectives are
-# all-gathers of activations — pure data movement, never a
-# floating-point reduction. A bf16 psum from a row-parallel contraction
-# rounds partial sums differently than the single-device matmul and
-# flips near-tie samples; with this layout the sharded engine's logits
-# are bit-identical to the single-device engine's, which is what lets
-# CI pin token-identity across device counts.
 
 
 def _div(n: int, axis_n: int) -> bool:
@@ -357,12 +52,11 @@ def serving_param_specs(cfg: ModelConfig, mesh, shapes_tree):
     when that exact dim divides the axis. EVERYTHING else is
     replicated: the row-parallel contraction weights (a sharded
     contraction psums), the stacked per-layer norm scales ``[L, D]``
-    (which the training layout's generic 2-D rule would shard on D,
-    turning every downstream QKV/MLP contraction into a partial-sum),
-    and — unlike ``param_spec`` — there is no fallback to *other* dims:
-    ``_pick_dim``'s fallback could land the "model" axis on a
-    contraction or layer-stack dim when the output dim doesn't divide,
-    silently breaking the bit-identity contract.
+    (sharded on D, they would turn every downstream QKV/MLP contraction
+    into a partial-sum), and there is no fallback to *other* dims: one
+    could land the "model" axis on a contraction or layer-stack dim when
+    the output dim doesn't divide, silently breaking the bit-identity
+    contract.
 
     No FSDP: serving carries no optimizer state, and the decode path
     re-reads every weight each step — "data" is reserved for the

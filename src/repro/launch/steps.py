@@ -1,8 +1,5 @@
-"""Step functions the launcher lowers: train / prefill / serve-decode.
-
-These are the production entry points — the same model code paths the
-engine and trainer exercise, wrapped for pjit lowering on the big meshes.
-"""
+"""The training step the training launcher runs: forward with remat,
+backward, AdamW update, optional gradient accumulation."""
 from __future__ import annotations
 
 from typing import Callable, Tuple
@@ -11,31 +8,28 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.models.model import forward_full, lm_loss, serve_decode_step
+from repro.models.model import lm_loss
 from repro.training.optimizer import AdamW
 
 
 def make_train_step(cfg: ModelConfig, lr: float = 1e-4,
-                    act_spec=None,
                     microbatches: int = 1,
                     moment_dtype: str = "float32",
-                    accum_dtype: str = "float32",
-                    kv_specs=None
+                    accum_dtype: str = "float32"
                     ) -> Tuple[Callable, AdamW]:
-    """Full training step: fwd (remat + sharded residual stream) + bwd +
-    AdamW update.
+    """Full training step: fwd (remat) + bwd + AdamW update.
 
     ``microbatches > 1`` enables gradient accumulation: the global batch
     is split into k sequential microbatches whose fp32 gradients
-    accumulate before one optimizer update — the lever that bounds peak
-    activation memory for the largest train_4k configs.
+    accumulate before one optimizer update, which bounds peak activation
+    memory.
     """
     opt = AdamW(learning_rate=lr, weight_decay=0.01,
                 moment_dtype=moment_dtype)
 
     def loss_fn(p, batch):
         return lm_loss(p, cfg, batch["tokens"], batch["labels"],
-                       remat=True, act_spec=act_spec, kv_specs=kv_specs,
+                       remat=True,
                        modality_embeds=batch.get("modality_embeds"),
                        encoder_embeds=batch.get("encoder_embeds"))
 
@@ -70,33 +64,3 @@ def make_train_step(cfg: ModelConfig, lr: float = 1e-4,
         return params, opt_state, loss
 
     return train_step, opt
-
-
-def make_prefill_step(cfg: ModelConfig, act_spec=None,
-                      kv_specs=None) -> Callable:
-    """Prefill: full forward over the prompt, returning the last-position
-    logits (to sample the first token) and the KV/state to seed decode."""
-
-    def prefill_step(params, batch):
-        out = forward_full(params, cfg, batch["tokens"], return_kv=True,
-                           act_spec=act_spec, kv_specs=kv_specs,
-                           modality_embeds=batch.get("modality_embeds"),
-                           encoder_embeds=batch.get("encoder_embeds"))
-        return {"next_logits": out["logits"][:, -1], "kvs": out["kvs"]}
-
-    return prefill_step
-
-
-def make_decode_step(cfg: ModelConfig, kv_specs=None) -> Callable:
-    """One decode step over the distributed contiguous cache; returns the
-    greedy next token, the STEP-scorer hidden state, and the new cache."""
-
-    def decode_fn(params, batch, cache):
-        out = serve_decode_step(params, cfg, batch["tokens"],
-                                batch["positions"], cache,
-                                kv_specs=kv_specs)
-        next_tok = jnp.argmax(out["logits"], axis=-1).astype(jnp.int32)
-        return {"next_token": next_tok, "hidden": out["hidden"],
-                "cache": out["cache"]}
-
-    return decode_fn

@@ -3,9 +3,6 @@
 Smoke scale (this container, executes for real):
     PYTHONPATH=src python -m repro.launch.train --arch qwen3-1.7b \
         --smoke --steps 50 --batch 8 --seq 128
-
-Production scale lowers through the same make_train_step; use
-``repro.launch.dryrun`` for the no-hardware 256/512-chip compile.
 """
 from __future__ import annotations
 

@@ -122,9 +122,8 @@ def swiglu(p: dict, x: jax.Array, act_spec=None) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 # Above this sequence length the naive S^2 score tensor cannot be
-# materialised (824 TB for granite-20b at train_4k); attention switches to
-# the chunked online-softmax path (flash semantics in plain XLA) which is
-# what actually lowers for the 32k/500k dry-run shapes.
+# materialised at long sequence lengths; attention switches to the
+# chunked online-softmax path (flash semantics in plain XLA).
 CHUNKED_ATTN_THRESHOLD = 1024
 ATTN_CHUNK = 512
 
@@ -609,86 +608,6 @@ def gqa_attention_prefill_chunk(p: dict, cfg: ModelConfig, x: jax.Array,
 
 
 # ---------------------------------------------------------------------------
-# contiguous-cache decode attention — the DISTRIBUTED serving layout
-# ---------------------------------------------------------------------------
-# On the production mesh each data shard owns its sequences' caches as a
-# dense [B_local, capacity, ...] ring buffer: block tables are a host-side
-# per-shard allocator concern (exactly what the engine's BlockManager is),
-# while the device-side step sees a contiguous buffer. This avoids the
-# cross-shard gather a flat global pool would force GSPMD to emit.
-# Semantics (rolling window via slot = pos % capacity) are identical to
-# the flat-pool path — tests assert both against forward_full.
-
-
-def contiguous_kv_update(cache: jax.Array, slot: jax.Array,
-                         new: jax.Array) -> jax.Array:
-    """cache [B, cap, ...]; slot [B]; new [B, ...] -> updated cache."""
-    B = cache.shape[0]
-    return cache.at[jnp.arange(B), slot].set(new)
-
-
-def gqa_attention_decode_contiguous(p: dict, cfg: ModelConfig, x: jax.Array,
-                                    positions: jax.Array, k_cache: jax.Array,
-                                    v_cache: jax.Array, window_len: int
-                                    ) -> tuple:
-    """One-token decode with contiguous per-sequence caches.
-
-    x [B,1,D]; k/v_cache [B, cap, KVH, hd]. Returns (out, new_k, new_v).
-    """
-    B, _, D = x.shape
-    H, KVH, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-    cap = k_cache.shape[1]
-    q = (x @ p["wq"]).reshape(B, H, hd)
-    k = (x @ p["wk"]).reshape(B, KVH, hd)
-    v = (x @ p["wv"]).reshape(B, KVH, hd)
-    if cfg.qk_norm:
-        q = rms_norm(q, p["q_norm"], cfg.norm_eps)
-        k = rms_norm(k, p["k_norm"], cfg.norm_eps)
-    cos, sin = rope_cos_sin(positions, hd, cfg.rope_theta)
-    cos, sin = cos[:, None, :], sin[:, None, :]
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
-
-    slot = positions % cap
-    k_cache = contiguous_kv_update(k_cache, slot, k)
-    v_cache = contiguous_kv_update(v_cache, slot, v)
-    lens = jnp.minimum(positions + 1, cap)
-
-    group = H // KVH
-    qg = q.reshape(B, KVH, group, hd)
-    scores = jnp.einsum("bkgh,bskh->bkgs", qg, k_cache,
-                        preferred_element_type=jnp.float32)
-    scores *= 1.0 / math.sqrt(hd)
-    valid = jnp.arange(cap)[None, :] < lens[:, None]
-    out = _masked_softmax_pv(scores, valid[:, None, None, :], v_cache,
-                             "bkgs,bskh->bkgh")
-    out = out.astype(x.dtype).reshape(B, 1, H * hd) @ p["wo"]
-    return out, k_cache, v_cache
-
-
-def mla_attention_decode_contiguous(p: dict, cfg: ModelConfig, x: jax.Array,
-                                    positions: jax.Array, kv_cache: jax.Array
-                                    ) -> tuple:
-    """Absorbed MLA decode over a contiguous latent cache [B, cap, L+rd]."""
-    xt = x[:, 0]
-    cap = kv_cache.shape[1]
-    cos, sin = _mla_rope(cfg, positions)
-    q_nope, q_rope = _mla_q(p, cfg, xt, cos, sin)
-    entry = _mla_entry(p, cfg, xt, cos, sin)
-    kv_cache = contiguous_kv_update(kv_cache, positions % cap, entry)
-    lens = jnp.minimum(positions + 1, cap)
-    q = _mla_absorbed_q(p, cfg, q_nope, q_rope)
-    scores = jnp.einsum("bhe,bse->bhs", q, kv_cache,
-                        preferred_element_type=jnp.float32)
-    scores *= mla_softmax_scale(cfg)
-    valid = jnp.arange(cap)[None, :] < lens[:, None]
-    o_lat = _masked_softmax_pv(scores, valid[:, None, :],
-                               kv_cache[..., :cfg.kv_lora_rank],
-                               "bhs,bsl->bhl").astype(x.dtype)
-    return _mla_out(p, cfg, o_lat)[:, None], kv_cache
-
-
-# ---------------------------------------------------------------------------
 # cross attention (enc-dec)
 # ---------------------------------------------------------------------------
 
@@ -952,143 +871,6 @@ def mla_attention_prefill_chunk(p: dict, cfg: ModelConfig, x: jax.Array,
     o_lat = _masked_softmax_pv(scores, mask[:, None], keys[..., :L],
                                "bhcs,bsl->bchl").astype(x.dtype)
     return _mla_out(p, cfg, o_lat), entry
-
-
-# ---------------------------------------------------------------------------
-# MoE, capacity dispatch (the sharded launch path: GSPMD shards the
-# dispatch einsums over the expert axis)
-# ---------------------------------------------------------------------------
-
-MOE_CHUNK_TOKENS = 524288
-
-
-def _moe_group_size(T: int, E: int) -> int:
-    """GShard-style dispatch groups: the [G, Tg, E, C] one-hot dispatch
-    tensor is quadratic in group size, so production configs use many
-    small groups. Tg ~ 256 keeps the tensor O(GB) even at E=160,
-    T=1M (train_4k); tiny inputs use a single group."""
-    target = 256 if E >= 32 else 1024
-    gs = min(T, target)
-    while T % gs:
-        gs -= 1
-    return gs
-
-
-def moe_layer(p: dict, cfg: ModelConfig, x: jax.Array,
-              capacity_factor: float = None,
-              expert_weight_spec=None,
-              ex_in_spec=None) -> tuple:
-    """Top-k MoE with shared experts (DeepSeek-style when configured),
-    group-wise capacity dispatch (GShard/Switch semantics).
-
-    p: {router [D, E],
-        experts {w_gate [E, D, F], w_up [E, D, F], w_down [E, F, D]},
-        (optional) shared {w_gate [D, F*n_sh], w_up, w_down}}
-    Returns (out, aux_loss).
-
-    ``expert_weight_spec``: optional PartitionSpec the expert weights are
-    constrained to BEFORE the group-chunk scan. Under FSDP the weights
-    arrive data-sharded; without this hoist, GSPMD re-all-gathers them on
-    EVERY chunk iteration (measured: 4.9 TB/device/step for mixtral
-    train_4k — the dominant collective term). Constraining to the
-    fsdp-free spec materialises one gathered copy per layer instead.
-    """
-    B, S, D = x.shape
-    E, K = cfg.num_experts, cfg.num_experts_per_tok
-    T = B * S
-    xt = x.reshape(T, D)
-    if capacity_factor is None:
-        capacity_factor = cfg.moe_capacity_factor
-    if expert_weight_spec is not None:
-        p = dict(p)
-        p["experts"] = {
-            k: jax.lax.with_sharding_constraint(v, expert_weight_spec[k])
-            for k, v in p["experts"].items()
-        }
-
-    gates = jax.nn.softmax((xt @ p["router"]).astype(jnp.float32), axis=-1)
-    weights, sel = jax.lax.top_k(gates, K)  # [T,K]
-    weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-9)
-
-    # load-balancing aux loss (Switch-style)
-    me = jnp.mean(gates, axis=0)
-    ce = jnp.mean(
-        jnp.sum(jax.nn.one_hot(sel, E, dtype=jnp.float32), axis=1), axis=0)
-    aux_loss = E * jnp.sum(me * ce)
-
-    gs = _moe_group_size(T, E)
-    G = T // gs
-    capacity = max(1, int(capacity_factor * gs * K / E))
-    xg = xt.reshape(G, gs, D)
-    sel_g = sel.reshape(G, gs, K)
-    w_g = weights.reshape(G, gs, K)
-    pe = p["experts"]
-
-    def groups_block(xg_c, sel_c, w_c):
-        """Dispatch+expert-ffn+combine for a slice of groups.
-
-        Bounds the live [Gc, E, C, *] dispatch buffers — at 1M tokens the
-        full-G expert intermediates are tens of GB per layer.
-        """
-        # position of each (token, k) within its expert queue, per group
-        sel_onehot = jax.nn.one_hot(sel_c, E, dtype=jnp.int32)  # [Gc,gs,K,E]
-        Gc = sel_c.shape[0]
-        flat = sel_onehot.reshape(Gc, gs * K, E)
-        pos_in_expert = (jnp.cumsum(flat, axis=1) - flat) \
-            .reshape(Gc, gs, K, E)
-        pos = jnp.sum(pos_in_expert * sel_onehot, axis=-1)  # [Gc,gs,K]
-        keep = pos < capacity
-
-        disp = (sel_onehot.astype(jnp.bool_)
-                & keep[..., None]).astype(xt.dtype)  # [Gc,gs,K,E]
-        pos_oh = jax.nn.one_hot(jnp.where(keep, pos, capacity),
-                                capacity + 1,
-                                dtype=xt.dtype)[..., :capacity]
-        dispatch = jnp.einsum("gtke,gtkc->gtec", disp, pos_oh)
-        combine = jnp.einsum("gtke,gtkc,gtk->gtec", disp, pos_oh,
-                             w_c.astype(xt.dtype))
-
-        ex_in = jnp.einsum("gtec,gtd->gecd", dispatch, xg_c)  # [Gc,E,C,D]
-        if ex_in_spec is not None:
-            # DECODE expert parallelism: dispatched activations are tiny
-            # (tokens*topk*D ~ MB) while FSDP-sharded expert weights are
-            # tens of GB; resharding ex_in to the weights' (E-model,
-            # D-data) layout makes GSPMD move activations and leave the
-            # weights stationary (partial-sum matmul + small all-reduce)
-            # instead of all-gathering the weights every step.
-            ex_in = jax.lax.with_sharding_constraint(ex_in, ex_in_spec)
-        g_ = jnp.einsum("gecd,edf->gecf", ex_in, pe["w_gate"])
-        u = jnp.einsum("gecd,edf->gecf", ex_in, pe["w_up"])
-        act = jax.nn.silu(g_.astype(jnp.float32)).astype(xt.dtype) * u
-        ex_out = jnp.einsum("gecf,efd->gecd", act, pe["w_down"])
-        return jnp.einsum("gtec,gecd->gtd", combine, ex_out)
-
-    # tokens of expert compute live at once; larger chunks amortise the
-    # FSDP weight all-gather inside the chunk scan (iteration 2 of the
-    # mixtral train_4k hillclimb: 64k -> 256k cut collective time 2.4x)
-    chunk_groups = max(1, (MOE_CHUNK_TOKENS + gs - 1) // gs)
-    if G > chunk_groups:
-        while G % chunk_groups:
-            chunk_groups -= 1
-        nc = G // chunk_groups
-
-        @jax.checkpoint
-        def body(_, inp):
-            xg_c, sel_c, w_c = inp
-            return None, groups_block(xg_c, sel_c, w_c)
-
-        _, out = jax.lax.scan(
-            body, None,
-            (xg.reshape(nc, chunk_groups, gs, D),
-             sel_g.reshape(nc, chunk_groups, gs, K),
-             w_g.reshape(nc, chunk_groups, gs, K)))
-        out = out.reshape(T, D)
-    else:
-        out = groups_block(xg, sel_g, w_g).reshape(T, D)
-
-    if cfg.num_shared_experts:
-        out = out + swiglu(p["shared"], xt)
-    return out.reshape(B, S, D), aux_loss
 
 
 # ---------------------------------------------------------------------------

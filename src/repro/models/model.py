@@ -1,6 +1,6 @@
 """Architecture-dispatching forward passes.
 
-Three entry points, shared by training, serving and the dry-run launcher:
+Three entry points, shared by training and serving:
 
   forward_full(...)  — full-sequence forward (train / prefill), scan over
                        stacked layer params.
@@ -29,8 +29,8 @@ def _embed(params, cfg, tokens):
 
 
 def _wsc_kv(kv_specs, key, x):
-    # Constrain a per-layer KV/state tensor to its launcher-provided
-    # PartitionSpec (no-op outside the distributed launch path).
+    # Constrain a per-layer KV/state tensor to the serving mesh's
+    # sharding (``serving_prefill_kv_specs``; no-op without a mesh).
     if kv_specs is None or key not in kv_specs or x is None:
         return x
     return jax.lax.with_sharding_constraint(x, kv_specs[key])
@@ -114,13 +114,10 @@ def forward_full(params: dict, cfg: ModelConfig, tokens: jax.Array,
     never partial-sums (see ``layers.swiglu``).
 
     ``remat=True`` checkpoints each layer body (save only the residual
-    stream per layer; recompute attention/ffn intermediates in backward) —
-    required for the train_4k activations to fit HBM at full scale.
+    stream per layer; recompute attention/ffn intermediates in backward).
 
-    ``act_spec`` (PartitionSpec for [B, S, D]) pins the residual-stream
-    sharding between layers: the rematerialised per-layer carries are the
-    dominant training activation term, and without an explicit constraint
-    GSPMD leaves them replicated over the model axis (16x the bytes)."""
+    ``act_spec`` (sharding for [B, S, D]) pins the residual-stream
+    sharding between layers (serving mesh prefill)."""
     B, S = tokens.shape
 
     def wsc(x):
@@ -233,14 +230,7 @@ def forward_full(params: dict, cfg: ModelConfig, tokens: jax.Array,
                     *L.cross_kv(lp["cross"], cfg, enc_out))
                 h = h + c
             m_in = L.rms_norm(h, lp["ln2"], cfg.norm_eps)
-            if is_moe and kv_specs is not None:
-                # the sharded launch: GSPMD shards the capacity dispatch
-                # over the expert axis
-                m, aux_l = L.moe_layer(
-                    lp["moe"], cfg, m_in,
-                    expert_weight_spec=kv_specs.get("moe_experts"))
-                aux = aux + aux_l
-            elif is_moe:
+            if is_moe:
                 m, aux_l, _ = L.moe_dropless(lp["moe"], cfg, m_in)
                 aux = aux + aux_l
             else:
@@ -273,7 +263,7 @@ def forward_full(params: dict, cfg: ModelConfig, tokens: jax.Array,
 def decode_step(params: dict, cfg: ModelConfig, tokens: jax.Array,
                 positions: jax.Array, cache: dict, window_len: int,
                 use_kernel: bool = False, shard_specs=None) -> dict:
-    """tokens [B,1]; positions [B]; cache per kv_cache_specs.
+    """tokens [B,1]; positions [B]; cache per ``init_decode_cache``.
 
     window_len: static cache capacity in tokens (rolling buffer when the
     sequence outgrows it). Returns {logits [B,V], hidden [B,D], cache}.
@@ -730,136 +720,6 @@ def _latent_chunk_step(params, cfg, tokens, positions, valid, cache,
 
 
 # ---------------------------------------------------------------------------
-# distributed serve step — contiguous per-sequence caches (see layers.py:
-# "contiguous-cache decode attention"); this is the step the multi-pod
-# dry-run lowers for the decode shapes.
-# ---------------------------------------------------------------------------
-
-def serve_decode_step(params: dict, cfg: ModelConfig, tokens: jax.Array,
-                      positions: jax.Array, cache: dict,
-                      kv_specs=None) -> dict:
-    """tokens [B,1]; positions [B]; cache per kv_cache_specs (contiguous):
-      k_cache/v_cache [L*, B, cap, KVH, hd]  (or kv_cache for MLA)
-      ssm_state/conv_state as in decode_step; cross_k/cross_v for enc-dec.
-    Returns {logits [B,V], hidden [B,D], cache}.
-    """
-    B = tokens.shape[0]
-    h = _embed(params, cfg, tokens)
-    new_cache = dict(cache)
-
-    if cfg.arch_type == "ssm":
-        def body(h, xs):
-            lp, sstate, cstate = xs
-            out, ns, nc = L.mamba2_mixer_decode(
-                lp["mixer"], cfg,
-                L.rms_norm(h, lp["norm"], cfg.norm_eps), sstate, cstate)
-            return h + out, (_wsc_kv(kv_specs, "ssm", ns),
-                             _wsc_kv(kv_specs, "conv", nc))
-        h, (ns, ncv) = jax.lax.scan(
-            body, h, (params["layers"], cache["ssm_state"],
-                      cache["conv_state"]))
-        new_cache["ssm_state"], new_cache["conv_state"] = ns, ncv
-
-    elif cfg.arch_type == "hybrid":
-        sa = params["shared_attn"]
-
-        def group_body(h, xs):
-            gp, sstate, cstate, kc, vc = xs
-
-            def layer_body(h, lxs):
-                lp, ss, cs = lxs
-                out, ns, nc = L.mamba2_mixer_decode(
-                    lp["mixer"], cfg,
-                    L.rms_norm(h, lp["norm"], cfg.norm_eps), ss, cs)
-                return h + out, (_wsc_kv(kv_specs, "ssm", ns),
-                                 _wsc_kv(kv_specs, "conv", nc))
-            h, (ns, ncv) = jax.lax.scan(layer_body, h, (gp, sstate, cstate))
-            a_in = L.rms_norm(h, sa["ln1"], cfg.norm_eps)
-            a, nk, nv = L.gqa_attention_decode_contiguous(
-                sa["attn"], cfg, a_in, positions, kc, vc,
-                window_len=kc.shape[1])
-            nk = _wsc_kv(kv_specs, "kv", nk)
-            nv = _wsc_kv(kv_specs, "kv", nv)
-            h = h + a
-            h = h + L.swiglu(sa["mlp"], L.rms_norm(h, sa["ln2"], cfg.norm_eps))
-            return h, (ns, ncv, nk, nv)
-
-        G = cfg.num_layers // cfg.hybrid_attn_every
-        per = cfg.hybrid_attn_every
-        ss = cache["ssm_state"].reshape(G, per, *cache["ssm_state"].shape[1:])
-        cs = cache["conv_state"].reshape(G, per, *cache["conv_state"].shape[1:])
-        h, (ns, ncv, nk, nv) = jax.lax.scan(
-            group_body, h,
-            (params["layers"], ss, cs, cache["k_cache"], cache["v_cache"]))
-        new_cache["ssm_state"] = ns.reshape(-1, *ns.shape[2:])
-        new_cache["conv_state"] = ncv.reshape(-1, *ncv.shape[2:])
-        new_cache["k_cache"], new_cache["v_cache"] = nk, nv
-
-    else:  # dense / moe / vlm / enc-dec decoder
-        has_cross = cfg.is_encoder_decoder
-
-        def make_body(is_moe):
-            def body(h, xs):
-                if cfg.use_mla:
-                    lp, kv_cache = xs[0], xs[1]
-                    cross = xs[2:] if has_cross else None
-                else:
-                    lp, kc, vc = xs[0], xs[1], xs[2]
-                    cross = xs[3:] if has_cross else None
-                a_in = L.rms_norm(h, lp["ln1"], cfg.norm_eps)
-                if cfg.use_mla:
-                    a, new_kv = L.mla_attention_decode_contiguous(
-                        lp["attn"], cfg, a_in, positions, kv_cache)
-                    out_caches = (_wsc_kv(kv_specs, "mla", new_kv),)
-                else:
-                    a, nk, nv = L.gqa_attention_decode_contiguous(
-                        lp["attn"], cfg, a_in, positions, kc, vc,
-                        window_len=kc.shape[1])
-                    out_caches = (_wsc_kv(kv_specs, "kv", nk),
-                                  _wsc_kv(kv_specs, "kv", nv))
-                h = h + a
-                if has_cross:
-                    ck, cv = cross
-                    c = L.cross_attention(
-                        lp["cross"], cfg,
-                        L.rms_norm(h, lp["ln_cross"], cfg.norm_eps), ck, cv)
-                    h = h + c
-                m_in = L.rms_norm(h, lp["ln2"], cfg.norm_eps)
-                if is_moe and kv_specs is not None:
-                    m, _ = L.moe_layer(
-                        lp["moe"], cfg, m_in,
-                        expert_weight_spec=kv_specs.get("moe_experts"),
-                        ex_in_spec=kv_specs.get("moe_ex_in"))
-                elif is_moe:
-                    m, _, _ = L.moe_dropless(lp["moe"], cfg, m_in)
-                else:
-                    m = L.swiglu(lp["mlp"], m_in)
-                return h + m, out_caches
-            return body
-
-        keys = ("kv_cache",) if cfg.use_mla else ("k_cache", "v_cache")
-        if has_cross:
-            keys = keys + ("cross_k", "cross_v")
-        outs = []
-        for stack, first, is_moe in _stacks(params, cfg):
-            n = jax.tree.leaves(stack)[0].shape[0]
-            xs = (stack,) + tuple(cache[k][first:first + n] for k in keys)
-            h, out_caches = jax.lax.scan(make_body(is_moe), h, xs)
-            outs.append(out_caches)
-        out_caches = jax.tree.map(
-            lambda *xs: jnp.concatenate(xs, axis=0), *outs) \
-            if len(outs) > 1 else outs[0]
-        if cfg.use_mla:
-            new_cache["kv_cache"] = out_caches[0]
-        else:
-            new_cache["k_cache"], new_cache["v_cache"] = out_caches
-
-    hidden = L.rms_norm(h[:, 0], params["final_norm"], cfg.norm_eps)
-    logits = _logits(params, cfg, hidden)
-    return {"logits": logits, "hidden": hidden, "cache": new_cache}
-
-
-# ---------------------------------------------------------------------------
 # cache construction / prefill population (serving engine path)
 # ---------------------------------------------------------------------------
 
@@ -1054,12 +914,10 @@ def lm_loss(params: dict, cfg: ModelConfig, tokens: jax.Array,
             use_kernel: bool = False,
             modality_embeds: Optional[jax.Array] = None,
             encoder_embeds: Optional[jax.Array] = None,
-            remat: bool = False, act_spec=None,
-            kv_specs=None) -> jax.Array:
+            remat: bool = False) -> jax.Array:
     out = forward_full(params, cfg, tokens, use_kernel=use_kernel,
                        modality_embeds=modality_embeds,
-                       encoder_embeds=encoder_embeds, remat=remat,
-                       act_spec=act_spec, kv_specs=kv_specs)
+                       encoder_embeds=encoder_embeds, remat=remat)
     valid = (labels >= 0) & (labels < cfg.vocab_size)
     S = tokens.shape[1]
     if S > CHUNKED_CE_THRESHOLD:

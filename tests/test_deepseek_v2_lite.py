@@ -8,6 +8,8 @@
   engine chose.
 - The expert shares: four chips' routed parts, plus the shared experts
   once, sum to the uncut layer.
+- The dropless layer's output per token does not depend on the batch
+  shape it arrives in.
 - YaRN against a direct transcription of the published formula.
 - The latent kernel against the dense latent decode; the expert FFN
   kernel against the XLA ragged matmuls.
@@ -23,6 +25,7 @@ import numpy as np
 import pytest
 
 from repro.configs.deepseek_v2_lite import full_config, smoke_config
+from repro.configs.registry import get_config
 from repro.core.pruning import make_policy
 from repro.data.tokenizer import get_tokenizer
 from repro.kernels import paged_attention as pa
@@ -161,6 +164,25 @@ def test_expert_shares_sum_to_uncut_layer():
     # values near 1 (bf16 ulp 2^-7)
     np.testing.assert_allclose(np.asarray(total), np.asarray(whole, f32),
                                atol=4e-2, rtol=2e-2)
+
+
+def test_dropless_moe_batch_shape_invariance():
+    """No pair is dropped, so a token's output and its load do not
+    depend on which tokens share its call: the same 128 tokens as
+    [2, 64] and as [1, 128] give the same bits (Mixtral: renormalised
+    top-2 of 4 experts, no shared experts)."""
+    cfg = get_config("mixtral-8x7b", smoke=True)
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    p = jax.tree.map(lambda x: x[0], params["layers"]["moe"])
+    x = jax.random.normal(jax.random.PRNGKey(2), (2, 64, cfg.d_model)
+                          ).astype(jnp.bfloat16)
+    a, _, la = L.moe_dropless(p, cfg, x)
+    b, _, lb = L.moe_dropless(p, cfg, x.reshape(1, 128, cfg.d_model))
+    assert int(jnp.sum(la)) == 128 * cfg.num_experts_per_tok
+    np.testing.assert_array_equal(np.asarray(la), np.asarray(lb))
+    np.testing.assert_array_equal(
+        np.asarray(a, np.float32).reshape(-1, cfg.d_model),
+        np.asarray(b, np.float32).reshape(-1, cfg.d_model))
 
 
 def _published_yarn(dim, base, factor, original, beta_fast, beta_slow):

@@ -46,9 +46,21 @@ def test_sc_completes_all_traces(setup):
     eng.block_mgr.check_invariants()
 
 
-def test_sc_preempts_under_memory_pressure(setup):
+@pytest.fixture(scope="module")
+def tight_blocks(setup):
+    """A pool about half the size the 8 traces hold at their peak when
+    nothing stops them, plus the allocator's scratch block: pressure
+    follows from what the traces need, whatever lengths the weights
+    give them."""
+    _, res = _run(setup, "sc", num_blocks=40, max_new=100)
+    assert all(t.status == TraceStatus.FINISHED for t in res.traces)
+    assert res.num_preemptions == 0
+    return res.peak_blocks_used // 2 + 1
+
+
+def test_sc_preempts_under_memory_pressure(setup, tight_blocks):
     """The paper's Fig. 2c bottleneck: tight pool => preemption + waiting."""
-    eng, res = _run(setup, "sc", num_blocks=12, max_new=100)
+    eng, res = _run(setup, "sc", num_blocks=tight_blocks, max_new=100)
     assert res.num_preemptions > 0
     assert res.wait_s > 0
     # discard-and-recompute: preempted traces prefill more than once
@@ -58,9 +70,9 @@ def test_sc_preempts_under_memory_pressure(setup):
     assert eng.pool_drained()
 
 
-def test_step_never_waits(setup):
+def test_step_never_waits(setup, tight_blocks):
     """STEP's claim (Table 3): memory-aware pruning => zero waiting."""
-    eng, res = _run(setup, "step", num_blocks=12, max_new=100)
+    eng, res = _run(setup, "step", num_blocks=tight_blocks, max_new=100)
     assert res.wait_s == 0.0
     assert res.num_preemptions == 0
     assert res.num_pruned > 0
@@ -70,8 +82,8 @@ def test_step_never_waits(setup):
     assert eng.pool_drained()
 
 
-def test_step_prunes_lowest_scored(setup):
-    eng, res = _run(setup, "step", num_blocks=12, max_new=100)
+def test_step_prunes_lowest_scored(setup, tight_blocks):
+    eng, res = _run(setup, "step", num_blocks=tight_blocks, max_new=100)
     pruned = [t for t in res.traces if t.status == TraceStatus.PRUNED]
     assert pruned
     # every pruned trace recorded step scores or was at the uninformative
@@ -80,9 +92,9 @@ def test_step_prunes_lowest_scored(setup):
         assert 0.0 <= t.score <= 1.0
 
 
-def test_step_faster_than_sc_under_pressure(setup):
-    _, res_sc = _run(setup, "sc", num_blocks=12, max_new=100)
-    _, res_step = _run(setup, "step", num_blocks=12, max_new=100)
+def test_step_faster_than_sc_under_pressure(setup, tight_blocks):
+    _, res_sc = _run(setup, "sc", num_blocks=tight_blocks, max_new=100)
+    _, res_step = _run(setup, "step", num_blocks=tight_blocks, max_new=100)
     assert res_step.latency_s < res_sc.latency_s
     # STEP does zero recompute; SC's preemptions force re-prefills
     assert res_step.num_preemptions == 0 and res_sc.num_preemptions > 0
